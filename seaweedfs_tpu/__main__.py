@@ -327,8 +327,11 @@ def cmd_ec_encode(args):
     if not locs:
         print(f"volume {args.volume} not found", file=sys.stderr)
         sys.exit(1)
+    from .shell.commands import bulk_rpc_timeout
+
     r = http_json(
-        "POST", f"http://{locs[0]['url']}/admin/ec/generate?volume={args.volume}"
+        "POST", f"http://{locs[0]['url']}/admin/ec/generate?volume={args.volume}",
+        timeout=bulk_rpc_timeout(),
     )
     print(r)
 

@@ -14,11 +14,14 @@ int8 MXU matmul, reduced mod 2, and repacked. See gf.gf_matrix_to_bit_matrix.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Sequence
 
 import numpy as np
 
+from ..util import jaxenv
+from ..util.locks import make_lock
 from . import gf
 from .constants import DATA_SHARDS, PARITY_SHARDS
 
@@ -117,6 +120,21 @@ class Codec:
         expect = self.encode(np.asarray(shards[: self.data_shards]))
         return bool(np.array_equal(expect, shards[self.data_shards :]))
 
+    backend = ""  # the get_codec name; set by each backend class
+    kernel = "host"
+
+    def describe(self) -> dict:
+        """What this codec runs on — the ``ec_codec`` object of a volume
+        server's /status. Host codecs hold no device."""
+        return {
+            "backend": self.backend,
+            "platform": "cpu",
+            "device_kind": "host",
+            "device_count": 0,
+            "mesh": None,
+            "kernel": self.kernel,
+        }
+
 
 class NumpyCodec(Codec):
     """Pure-numpy GF matmul: low/high-nibble product tables gathered with
@@ -130,6 +148,7 @@ class NumpyCodec(Codec):
 
     _BLOCK = 1 << 16  # per-row block bytes; (k+R)·block stays L2-resident
     supports_out = True
+    backend = kernel = "numpy"
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
@@ -174,8 +193,10 @@ class CpuCodec(Codec):
     """C++ native kernel (seaweedfs_tpu/native). The kernel's per-matrix
     coefficient prep (GFNI affine qwords / PSHUFB nibble tables, depending
     on the build) is derived once and cached here — encode calls the same
-    parity matrix forever, and rederiving the tables per call was the
-    cold-start cliff in BENCH_r05's cpu_encode_runs_gbps."""
+    parity matrix forever, and rederiving the tables per call is a
+    cold-start cliff on every first chunk."""
+
+    backend = "cpu"
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
@@ -183,6 +204,7 @@ class CpuCodec(Codec):
 
         self._lib = lib
         self._prep_cache: dict[bytes, np.ndarray] = {}
+        self.kernel = f"native-{lib.kernel_variant()}"
 
     def _prep(self, matrix: np.ndarray) -> np.ndarray:
         key = matrix.tobytes()
@@ -202,6 +224,24 @@ class CpuCodec(Codec):
     ) -> np.ndarray:
         matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
         return self._lib.rs_matmul(matrix, data, prep=self._prep(matrix), out=out)
+
+
+class LaunchCounter:
+    """Device launches by kernel name. Launches come from the encode
+    pipeline's dispatch thread and from request threads doing degraded
+    reads at once, so the count is kept under a lock."""
+
+    def __init__(self):
+        self._lock = make_lock("LaunchCounter._lock")
+        self._n = {"pallas": 0, "xla": 0}
+
+    def add(self, kernel: str) -> None:
+        with self._lock:
+            self._n[kernel] += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._n)
 
 
 def build_pallas_gf_matmul(jax, n_out_rows: int, k: int, n_cols: int,
@@ -253,16 +293,25 @@ def build_pallas_gf_matmul(jax, n_out_rows: int, k: int, n_cols: int,
         out_specs=pl.BlockSpec((R, T), lambda i: (0, i), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((R, n_cols), jnp.uint8),
         interpret=interpret,
+        # stable name: a profiler trace finds the kernel by it
+        name=f"gf_matmul_r{R}_k{K}",
     )
 
 
 class TpuCodec(Codec):
-    """JAX bit-matmul kernel; runs on TPU (or any jax backend).
+    """JAX bit-matmul kernel on the process's default JAX device.
+
+    On a TPU the fused Pallas kernel (Mosaic) is the only kernel; on any
+    other platform the XLA formulation runs (CPU tests), or the Pallas
+    kernel in interpret mode when asked. Which one a codec got is part of
+    :meth:`describe` — it is never chosen by swallowing an error.
 
     Data is processed in fixed-size column chunks so the jit traces once;
     the tail chunk is zero-padded to the chunk width (zeros encode to zeros
     and are sliced off, so output bytes are unaffected).
     """
+
+    backend = "tpu"
 
     def __init__(
         self,
@@ -275,33 +324,38 @@ class TpuCodec(Codec):
         **kwargs,
     ):
         super().__init__(*args, **kwargs)
-        import jax  # deferred so numpy/cpu paths never require jax
-
-        self._jax = jax
+        # deferred so numpy/cpu paths never require jax
+        self._jax = jax = jaxenv.import_jax()
         if chunk_bytes % tile_bytes:
             raise ValueError("chunk_bytes must be a multiple of tile_bytes")
         self.chunk_bytes = chunk_bytes
         self.tile_bytes = tile_bytes
+        # a backend that cannot start (chip held by another process, no
+        # runtime) raises here, at construction — never a quiet XLA fallback
+        self.device = jax.devices()[0]
+        self.devices = [self.device]
         if use_pallas is None:
-            # Mosaic (the Pallas TPU compiler) needs a real TPU; everywhere
-            # else (CPU CI mesh) the XLA bit-matmul path is used.
-            try:
-                use_pallas = jax.devices()[0].platform == "tpu"
-            except Exception:
-                use_pallas = False
+            # Mosaic (the Pallas TPU compiler) needs a real TPU
+            use_pallas = self.device.platform == "tpu"
         self.use_pallas = use_pallas
         self.pallas_tile = pallas_tile
         self._pallas_interpret = pallas_interpret
         self._jit_cache: dict = {}
         self._bitmat_cache: dict = {}
+        # device launches by kernel, so /status shows that no launch took a
+        # path the operator did not ask for
+        self.launches = LaunchCounter()
+        self.kernel = _jax_kernel_name(use_pallas, pallas_interpret)
+
+    def describe(self) -> dict:
+        return _describe_jax_codec(self, None)
 
     def _kernel(self, n_out_rows: int, k: int):
         """Jitted tiled bit-matmul for a (n_out_rows × k) matrix shape.
 
-        One launch covers a whole chunk (amortizing dispatch latency, which
-        dominates on tunneled single-chip setups), while a fori_loop over
-        column tiles keeps the 8× bit-expansion intermediate at tile size
-        instead of chunk size in HBM.
+        One launch covers a whole chunk (amortizing dispatch latency),
+        while a fori_loop over column tiles keeps the 8× bit-expansion
+        intermediate at tile size instead of chunk size in HBM.
         """
         key = (n_out_rows, k)
         fn = self._jit_cache.get(key)
@@ -394,14 +448,11 @@ class TpuCodec(Codec):
         return self._jax.device_put(data)
 
     def device_memory_free(self) -> Optional[int]:
-        """Free HBM bytes on the codec's device, or None when the runtime
-        doesn't expose allocator stats (CPU, some backends). The chip may be
-        shared, so this is a snapshot — callers budget with headroom."""
-        try:
-            stats = self._jax.local_devices()[0].memory_stats()
-            return max(0, stats["bytes_limit"] - stats["bytes_in_use"])
-        except Exception:
-            return None
+        """Free HBM bytes on the codec's device: a snapshot, so callers
+        budget with headroom. None only where the platform keeps no
+        allocator stats (the CPU backend); a TPU that reports none is an
+        error, not a licence to skip the budget."""
+        return _device_memory_free(self.device)
 
     def matmul_device(self, matrix: np.ndarray, data_dev):
         """Device-resident matmul: data_dev is a jax array (k, N) already in
@@ -420,14 +471,20 @@ class TpuCodec(Codec):
                 outs.append(self.matmul_device(matrix, data_dev[:, pos:end]))
                 pos = end
             return self._jax.numpy.concatenate(outs, axis=1)
-        if self.use_pallas and data_dev.shape[1] % min(
-            self.pallas_tile, data_dev.shape[1]
-        ) == 0:
-            fn = self._pallas_fused(
-                matrix.shape[0], matrix.shape[1], data_dev.shape[1]
-            )
+        if self.use_pallas:
+            if n % min(self.pallas_tile, n):
+                # every caller pads to alignment(); a ragged width is a
+                # caller bug, not a reason to leave the fused kernel for
+                # the XLA formulation behind the operator's back
+                raise ValueError(
+                    f"width {n} is not a multiple of the kernel tile "
+                    f"{self.pallas_tile}: pad to alignment() first"
+                )
+            fn = self._pallas_fused(matrix.shape[0], matrix.shape[1], n)
+            self.launches.add("pallas")
             return fn(self._bitmat(matrix, planewise=True), data_dev)
         kernel = self._kernel(*matrix.shape)
+        self.launches.add("xla")
         return kernel(self._bitmat(matrix), data_dev)
 
     def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
@@ -456,7 +513,74 @@ class TpuCodec(Codec):
         return out
 
 
-_BACKENDS = {"numpy": NumpyCodec, "cpu": CpuCodec, "tpu": TpuCodec}
+def _device_memory_free(device) -> Optional[int]:
+    stats = device.memory_stats()
+    if stats is None:
+        if device.platform == "tpu":
+            raise RuntimeError(
+                f"{device} reports no memory_stats(): cannot budget HBM "
+                "for the encode pipeline"
+            )
+        return None
+    return max(0, stats["bytes_limit"] - stats["bytes_in_use"])
+
+
+def _jax_kernel_name(use_pallas: bool, interpret: bool) -> str:
+    if not use_pallas:
+        return "xla"
+    return "pallas-interpret" if interpret else "pallas"
+
+
+def _describe_jax_codec(codec, mesh_shape) -> dict:
+    """The ``ec_codec`` object of a volume server's /status for a
+    JAX-backed codec: enough for an operator (or chip_smoke.py) to assert
+    the device, the kernel and the compile cache through the normal entry
+    point, without importing JAX beside the daemon."""
+    jax = codec._jax
+    devices = codec.devices
+    first = devices[0]
+    return {
+        "backend": codec.backend,
+        "platform": first.platform,
+        "device_kind": first.device_kind,
+        "device_count": jax.device_count(),
+        "mesh": mesh_shape,
+        "kernel": codec.kernel,
+        "pallas_tile": codec.pallas_tile,
+        "launches": codec.launches.snapshot(),
+        "devices": [
+            {
+                "id": d.id,
+                "peak_bytes_in_use": (d.memory_stats() or {}).get(
+                    "peak_bytes_in_use"
+                ),
+            }
+            for d in devices
+        ],
+        "compile_cache_dir": jaxenv.compile_cache_dir(),
+        "compiles": jaxenv.compile_counts(),
+        "x64": bool(jax.config.jax_enable_x64),
+        "versions": {
+            "jax": jax.__version__,
+            "jaxlib": _dist_version("jaxlib"),
+            "libtpu": _dist_version("libtpu"),
+            "runtime": first.client.platform_version,
+        },
+    }
+
+
+@functools.lru_cache(maxsize=None)  # /status is polled; two names, ever
+def _dist_version(name: str) -> Optional[str]:
+    from importlib import metadata
+
+    try:
+        return metadata.version(name)
+    except metadata.PackageNotFoundError:
+        return None
+
+
+_BACKENDS = {c.backend: c for c in (NumpyCodec, CpuCodec, TpuCodec)}
+_CHIP_BACKENDS = ("tpu", "mesh")  # names that promise TPU devices
 
 
 def get_codec(
@@ -465,29 +589,56 @@ def get_codec(
     parity_shards: int = PARITY_SHARDS,
     **kwargs,
 ) -> Codec:
-    """Codec factory. Default backend: $SWEED_EC_BACKEND or 'tpu' with jax,
-    falling back to 'cpu'. 'mesh' runs SPMD over all visible devices
-    (sharded.MeshCodec)."""
+    """Codec factory.
+
+    A backend NAMED by the caller or by $SWEED_EC_BACKEND is the backend
+    you get, or an error: 'tpu' (one chip, TpuCodec) and 'mesh' (SPMD over
+    all visible devices, sharded.MeshCodec) mean TPU devices and refuse any
+    other platform; a named backend that cannot be built raises instead of
+    becoming another one. Unnamed, the default is TpuCodec on whatever
+    device JAX gives (the CPU codecs where JAX is absent), and a fallback
+    is logged."""
     if backend is None:
         backend = os.environ.get("SWEED_EC_BACKEND", "")
-    if not backend:
+    named = bool(backend)
+    if not named:
         try:
-            import jax  # noqa: F401
-
+            jaxenv.import_jax()
             backend = "tpu"
         except ImportError:
             backend = "cpu"
     if backend == "mesh":
         from .sharded import MeshCodec  # deferred: sharded imports this module
 
-        return MeshCodec(data_shards, parity_shards, **kwargs)
+        cls = MeshCodec
+    else:
+        try:
+            cls = _BACKENDS[backend]
+        except KeyError:
+            raise ValueError(
+                f"unknown ec backend {backend!r} (want tpu|cpu|numpy|mesh)"
+            ) from None
     try:
-        cls = _BACKENDS[backend]
-    except KeyError:
-        raise ValueError(f"unknown ec backend {backend!r} (want tpu|cpu|numpy|mesh)")
-    try:
-        return cls(data_shards, parity_shards, **kwargs)
-    except ImportError:
-        if backend != "numpy":
-            return NumpyCodec(data_shards, parity_shards)
-        raise
+        codec = cls(data_shards, parity_shards, **kwargs)
+    except ImportError as e:
+        if named:
+            raise
+        from ..util import glog
+
+        glog.warning("ec backend %s unavailable (%s); using numpy", backend, e)
+        return NumpyCodec(data_shards, parity_shards)
+    if named and backend in _CHIP_BACKENDS:
+        first = codec.devices[0]
+        if {d.platform for d in codec.devices} != {"tpu"}:
+            held = (
+                " (this process is held to the cpu platform: JAX_PLATFORMS "
+                "or an earlier host-only use of JAX)"
+                if jaxenv.platforms() == "cpu" else ""
+            )
+            raise RuntimeError(
+                f"ec backend {backend!r} was asked for but JAX offers "
+                f"platform {first.platform!r} ({first.device_kind}){held}; "
+                "name cpu or numpy, or leave the backend unset, to run "
+                "without a chip"
+            )
+    return codec

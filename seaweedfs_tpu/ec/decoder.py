@@ -16,10 +16,18 @@ from __future__ import annotations
 import os
 import struct
 
+import numpy as np
+
 from ..storage import idx as idx_mod
 from ..storage.needle import get_actual_size
 from ..storage.super_block import SUPER_BLOCK_SIZE, SuperBlock
-from ..storage.types import OFFSET_SIZE, TOMBSTONE_FILE_SIZE, size_is_valid
+from ..storage.types import (
+    NEEDLE_ID_SIZE,
+    OFFSET_SIZE,
+    TOMBSTONE_FILE_SIZE,
+    needle_map_entry_size,
+    size_is_valid,
+)
 from .constants import DATA_SHARDS, LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, shard_ext
 from .encoder import rebuild_ec_files
 
@@ -55,15 +63,25 @@ def find_dat_file_size(
 def write_idx_file_from_ec_index(
     base_file_name: str, offset_size: int = OFFSET_SIZE
 ) -> None:
-    """.ecx (+ .ecj tombstones) → .idx (WriteIdxFileFromEcIndex)."""
-    with open(base_file_name + ".ecx", "rb") as src, open(
-        base_file_name + ".idx", "wb"
-    ) as dst:
-        while True:
-            buf = src.read(1 << 20)
-            if not buf:
-                break
-            dst.write(buf)
+    """.ecx (+ .ecj tombstones) → .idx (WriteIdxFileFromEcIndex).
+
+    The .ecx is key-sorted; the .idx is written in OFFSET order — append
+    order. A Volume's load-time check trusts the last .idx entry to name
+    the last record and cuts the .dat after it (storage/volume.py), and
+    keys are handed out before the upload, so whenever writers raced the
+    highest key is not the last record: a key-ordered .idx made the
+    reload after `ec.decode` truncate live needles away."""
+    entry = needle_map_entry_size(offset_size)
+    raw = np.fromfile(base_file_name + ".ecx", dtype=np.uint8)
+    rows = raw[: len(raw) - len(raw) % entry].reshape(-1, entry)
+    offsets = (
+        rows[:, NEEDLE_ID_SIZE : NEEDLE_ID_SIZE + 4].copy().view(">u4")
+        .ravel().astype(np.uint64)
+    )
+    if offset_size > 4:  # 5-byte offsets keep the high byte last
+        offsets |= rows[:, NEEDLE_ID_SIZE + 4].astype(np.uint64) << np.uint64(32)
+    with open(base_file_name + ".idx", "wb") as dst:
+        dst.write(rows[np.argsort(offsets, kind="stable")].tobytes())
         ecj = base_file_name + ".ecj"
         if os.path.exists(ecj):
             with open(ecj, "rb") as jf:

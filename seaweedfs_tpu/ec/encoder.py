@@ -219,12 +219,15 @@ def _budgeted_chunk(codec, chunk: int, device_streams: int) -> int:
     The overlap pipeline keeps ≤3 chunks device-resident (one in compute,
     one in the fetch queue, one mid-fetch), each holding
     ~device_streams×chunk bytes in HBM (k input rows staged + output rows
-    produced). The chip may be shared, so only a
-    quarter of the reported free pool is budgeted; oversized chunks are
-    split rather than dying with RESOURCE_EXHAUSTED (VERDICT r3 weak #1).
-    Codecs without allocator stats (CPU) keep the requested chunk."""
-    free = getattr(codec, "device_memory_free", lambda: None)()
-    if free is None:  # no allocator stats (CPU codecs): keep the request
+    produced). Only a quarter of the reported free pool is budgeted;
+    oversized chunks are split rather than dying with RESOURCE_EXHAUSTED
+    (VERDICT r3 weak #1). Host codecs, and JAX on the CPU platform, keep no
+    allocator stats and keep the requested chunk; a TPU that reports none
+    raises in device_memory_free."""
+    if not hasattr(codec, "device_memory_free"):  # host codec
+        return chunk
+    free = codec.device_memory_free()
+    if free is None:  # JAX on the CPU platform
         return chunk
     cap = free // (4 * 3 * max(1, device_streams))
     align = codec.alignment() if hasattr(codec, "alignment") else 1

@@ -19,8 +19,15 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..util.jaxenv import import_jax
 from . import gf
-from .codec import Codec
+from .codec import (
+    Codec,
+    LaunchCounter,
+    _describe_jax_codec,
+    _device_memory_free,
+    _jax_kernel_name,
+)
 from .constants import DATA_SHARDS, PARITY_SHARDS
 
 
@@ -47,10 +54,9 @@ def factor_mesh(n_devices: int, tp: int = 1) -> tuple[int, int, int]:
 
 
 def build_mesh(n_devices: int | None = None, tp: int = 1):
-    import jax
+    devices = import_jax().devices()
     from jax.sharding import Mesh
 
-    devices = jax.devices()
     if n_devices is None:
         n_devices = len(devices)
     devices = np.array(devices[:n_devices])
@@ -59,20 +65,10 @@ def build_mesh(n_devices: int | None = None, tp: int = 1):
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions: jax.shard_map (≥0.8, check_vma) with
-    fallback to jax.experimental.shard_map (check_rep). Both checks are
-    disabled — the body uses axis_index, which the replication checker
-    can't see through."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """jax.shard_map with the replication check off — the body uses
+    axis_index, which the checker can't see through."""
+    return import_jax().shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
@@ -90,7 +86,7 @@ def make_sharded_encode(mesh, matrix: np.ndarray, process_local: bool = False):
     global array whose addressable shards are this process's dp rows —
     the multi-host layout where dp rides DCN and sp/tp ride ICI
     (docs/SCALING.md)."""
-    import jax
+    jax = import_jax()
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -108,8 +104,6 @@ def make_sharded_encode(mesh, matrix: np.ndarray, process_local: bool = False):
         # simplest correct formulation: every rank holds full k rows of data
         # (they're replicated over 'tp'), unpacks all bits, and contracts only
         # its slice of the bit matrix against its slice of the bits.
-        import jax
-
         tp_idx = jax.lax.axis_index("tp")
         bitmat_part = bitmat_slices[0]  # local slice after sharding over tp
         b, k, n = data.shape
@@ -194,6 +188,8 @@ class MeshCodec(Codec):
     GF(2) counts psum'd over ICI.
     """
 
+    backend = "mesh"
+
     def __init__(
         self,
         data_shards: int = DATA_SHARDS,
@@ -206,29 +202,42 @@ class MeshCodec(Codec):
         pallas_interpret: bool = False,
     ):
         super().__init__(data_shards, parity_shards)
-        import jax
-
-        self._jax = jax
+        self._jax = import_jax()
         self.mesh = mesh if mesh is not None else build_mesh(n_devices)
         self.chunk_bytes = chunk_bytes
         # columns shard over dp×sp together; tp splits the contraction
         self._col_axes = ("dp", "sp")
         self._n_cols_shards = self.mesh.shape["dp"] * self.mesh.shape["sp"]
         self._tp = self.mesh.shape["tp"]
+        self.devices = list(self.mesh.devices.flat)
         if use_pallas is None:
-            try:
-                use_pallas = all(
-                    d.platform == "tpu" for d in self.mesh.devices.flat
-                )
-            except Exception:
-                use_pallas = False
+            use_pallas = all(d.platform == "tpu" for d in self.devices)
         # the fused kernel computes whole GF bytes per tile; a tp split needs
         # int partial sums across devices, which only the XLA body expresses
         self.use_pallas = use_pallas and self._tp == 1
         self.pallas_tile = pallas_tile
         self._pallas_interpret = pallas_interpret
+        self.kernel = _jax_kernel_name(self.use_pallas, pallas_interpret)
+        self.launches = LaunchCounter()
+        # the newest result, kept so that /status can show which devices
+        # hold its pieces: whether a launch really spread over the mesh
+        self._last_out = None
         self._jit_cache: dict = {}
         self._bitmat_cache: dict = {}
+
+    def describe(self) -> dict:
+        out = _describe_jax_codec(self, dict(self.mesh.shape))
+        last = self._last_out
+        out["last_output_devices"] = sorted(
+            {s.device.id for s in last.addressable_shards}
+        ) if last is not None else []
+        return out
+
+    def device_memory_free(self):
+        """The tightest device's free HBM: every device holds the same
+        share of each chunk, so the fullest one bounds the chunk."""
+        free = [_device_memory_free(d) for d in self.devices]
+        return None if None in free else min(free)
 
     # -- device placement (the streaming encoder's overlap pipeline) ---------
     def alignment(self) -> int:
@@ -352,7 +361,10 @@ class MeshCodec(Codec):
 
     def matmul_device(self, matrix: np.ndarray, data_dev):
         """(R×k) @ (k×N) on mesh-resident data; N % alignment() == 0."""
-        return self._spmd_fn(*matrix.shape)(self._stacked_bitmat(matrix), data_dev)
+        out = self._spmd_fn(*matrix.shape)(self._stacked_bitmat(matrix), data_dev)
+        self.launches.add("pallas" if self.use_pallas else "xla")
+        self._last_out = out
+        return out
 
     def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
         out_rows, _ = matrix.shape

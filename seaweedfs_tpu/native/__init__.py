@@ -1,41 +1,99 @@
-"""Loader for the native C++ kernel library (_sweed_native.so).
+"""Loader for the native C++ libraries (_sweed_native.so, _sweed_turbo.so).
 
-Builds lazily with g++ on first import if the shared object is missing or
-older than the source, then exposes ctypes wrappers. All callers must
-tolerate ImportError and fall back to pure-Python/numpy paths.
+A library is (re)built with g++ whenever the one in ``build/`` was not made
+from THIS source, with THESE flags, for THIS host's CPU — a stamp file
+beside each ``.so`` records what it was made from. ``build/`` is untracked
+and compiled with ``-march=native``, so a copy of the tree can carry a
+``.so`` from a machine with other instructions (AVX-512/GFNI vs AVX2); an
+mtime comparison cannot see that, and loading it dies with SIGILL inside the
+codec every identity check uses as its reference. All callers must tolerate
+ImportError and fall back to pure-Python/numpy paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "sweed_native.cpp")
-_SO = os.path.join(_DIR, "build", "_sweed_native.so")
+_BUILD = os.path.join(_DIR, "build")
 
 
-def _ensure_built() -> str:
-    if (not os.path.exists(_SO)) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-        try:
-            subprocess.run(
-                ["make", "-C", _DIR, "-s"],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
-            out = getattr(e, "stderr", b"") or b""
-            raise ImportError(f"native build failed: {out.decode(errors='replace')}")
-    return _SO
+def _host_cpu_flags() -> str:
+    """The host's instruction-set flags: what ``-march=native`` keys on."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    import platform
+
+    return platform.machine()
+
+
+def _build_key(source: str) -> str:
+    """What a .so must have been made from to be loadable here."""
+    h = hashlib.sha256()
+    for path in (source, os.path.join(_DIR, "Makefile")):
+        with open(path, "rb") as f:
+            h.update(f.read())
+    for var in ("CXX", "CXXFLAGS"):
+        h.update(f"{var}={os.environ.get(var, '')}\n".encode())
+    h.update(_host_cpu_flags().encode())
+    return h.hexdigest()
+
+
+def ensure_built(so_name: str, source_name: str, timeout: int = 180) -> str:
+    """Path of ``build/<so_name>``, rebuilt first unless its stamp says it
+    was made from the current source and flags on a host with this CPU.
+    A deployment that ships a .so without the source loads it as is.
+    Raises ImportError when the build fails."""
+    so = os.path.join(_BUILD, so_name)
+    source = os.path.join(_DIR, source_name)
+    if not os.path.exists(source) and os.path.exists(so):
+        return so
+    stamp = so + ".stamp"
+    key = _build_key(source)
+    try:
+        with open(stamp) as f:
+            fresh = os.path.exists(so) and f.read() == key
+    except OSError:
+        fresh = False
+    if fresh:
+        return so
+    # build aside and rename into place: daemons start concurrently, and a
+    # loader must never dlopen a half-written library
+    tmp = f"build/.tmp-{os.getpid()}"
+    try:
+        subprocess.run(
+            ["make", "-C", _DIR, "-s", f"B={tmp}", f"{tmp}/{so_name}"],
+            check=True, capture_output=True, timeout=timeout,
+        )
+        os.makedirs(_BUILD, exist_ok=True)
+        os.replace(os.path.join(_DIR, tmp, so_name), so)
+        with open(f"{stamp}.{os.getpid()}", "w") as f:
+            f.write(key)
+        os.replace(f"{stamp}.{os.getpid()}", stamp)  # stamp after the .so
+    except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
+        out = getattr(e, "stderr", b"") or b""
+        raise ImportError(
+            f"native build failed: {out.decode(errors='replace')}"
+        ) from e
+    finally:
+        shutil.rmtree(os.path.join(_DIR, tmp), ignore_errors=True)
+    return so
 
 
 class _Lib:
     def __init__(self) -> None:
-        self._c = ctypes.CDLL(_ensure_built())
+        self._c = ctypes.CDLL(ensure_built("_sweed_native.so", "sweed_native.cpp"))
         self._c.sweed_crc32c_update.restype = ctypes.c_uint32
         self._c.sweed_crc32c_update.argtypes = [
             ctypes.c_uint32,

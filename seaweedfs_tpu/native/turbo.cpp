@@ -701,6 +701,20 @@ static bool reply_json(Worker* w, Conn* c, int code, const std::string& js,
                head_only);
 }
 
+// Why a .dat append failed: the errno, or a short count (the file met a
+// size limit, or the disk filled, part-way through the record).
+static std::string dat_append_error(ssize_t wrote, int64_t rec_len) {
+  char js[160];
+  if (wrote < 0)
+    snprintf(js, sizeof(js), "{\"error\": \"dat append failed: %s\"}",
+             strerror(errno));
+  else
+    snprintf(js, sizeof(js),
+             "{\"error\": \"dat append failed: wrote %lld of %lld bytes\"}",
+             (long long)wrote, (long long)rec_len);
+  return js;
+}
+
 // ---------------------------------------------------------------------------
 // Request model.
 
@@ -1402,8 +1416,9 @@ static int handle_post(Worker* w, Conn* c, const Req& r, const Fid& f) {
       }
     }
     uint64_t off = vol->append_off;
-    if (pwrite(vol->dat_fd, rec.data(), rec_len, off) != rec_len)
-      return reply_json(w, c, 500, "{\"error\": \"dat append failed\"}") ? 0 : -1;
+    ssize_t wrote = pwrite(vol->dat_fd, rec.data(), rec_len, off);
+    if (wrote != rec_len)
+      return reply_json(w, c, 500, dat_append_error(wrote, rec_len)) ? 0 : -1;
     vol->append_off += rec_len;
     if (vol->write_idx_entry(f.key, off, (int32_t)size) != 0)
       return reply_json(w, c, 500, "{\"error\": \"idx append failed\"}") ? 0 : -1;
@@ -1483,8 +1498,9 @@ static int handle_delete(Worker* w, Conn* c, const Req& r, const Fid& f) {
     }
     old_size = s->size;
     uint64_t off = vol->append_off;
-    if (pwrite(vol->dat_fd, rec.data(), rec_len, off) != rec_len)
-      return reply_json(w, c, 500, "{\"error\": \"dat append failed\"}") ? 0 : -1;
+    ssize_t wrote = pwrite(vol->dat_fd, rec.data(), rec_len, off);
+    if (wrote != rec_len)
+      return reply_json(w, c, 500, dat_append_error(wrote, rec_len)) ? 0 : -1;
     vol->append_off += rec_len;
     if (vol->write_idx_entry(f.key, off, TOMBSTONE) != 0)
       return reply_json(w, c, 500, "{\"error\": \"idx append failed\"}") ? 0 : -1;

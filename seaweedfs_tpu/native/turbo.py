@@ -15,16 +15,12 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 from typing import Optional
 
 from ..storage.needle_map import NeedleMapper, NeedleValue
 from ..storage.types import OFFSET_SIZE, TOMBSTONE_FILE_SIZE
 from ..util import glog
-
-_DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "turbo.cpp")
-_SO = os.path.join(_DIR, "build", "_sweed_turbo.so")
+from . import ensure_built
 
 _lib = None
 _load_failed = False
@@ -35,15 +31,7 @@ def _load():
     if _lib is not None or _load_failed:
         return _lib
     try:
-        if (not os.path.exists(_SO)) or (
-            os.path.exists(_SRC)  # prebuilt-.so-only deployments load as-is
-            and os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-        ):
-            subprocess.run(
-                ["make", "-C", _DIR, "-s", "build/_sweed_turbo.so"],
-                check=True, capture_output=True, timeout=180,
-            )
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(ensure_built("_sweed_turbo.so", "turbo.cpp"))
         lib.turbo_start.restype = ctypes.c_longlong
         lib.turbo_start.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_int,

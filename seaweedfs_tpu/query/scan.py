@@ -20,8 +20,8 @@ Pipeline per CSV batch (the columnar format; the one the kernels cover):
 3. **Fused predicate evaluation**: the whole WHERE tree — numeric
    compares, equality, lexicographic ordering, contains / starts_with —
    is one compiled function per plan.  The jax backend jit-compiles it
-   (XLA; CPU or TPU per ``JAX_PLATFORMS``), the numpy fallback runs the
-   identical expression graph eagerly.  Backend selection mirrors
+   (XLA, on the host CPU device — see ``scan_device``), the numpy
+   fallback runs the identical expression graph eagerly.  Backend selection mirrors
    ``ec/codec.get_codec``: ``$SWEED_QUERY_BACKEND`` overrides, else jax
    if importable, else numpy.
 
@@ -57,6 +57,7 @@ Exactness notes (why the kernel domain is what it is):
 
 from __future__ import annotations
 
+import contextlib
 import csv
 import io
 import json
@@ -413,24 +414,40 @@ class NumpyKernels:
 
 
 class JaxKernels:
-    """jit-compiled fused predicate kernels (XLA; CPU or TPU per
-    JAX_PLATFORMS).  x64 is required: the numeric-compare kernel's
-    exactness proof lives in float64 mantissa arithmetic."""
+    """jit-compiled fused predicate kernels (XLA).  x64 is required — the
+    numeric-compare kernel's exactness proof lives in float64 mantissa
+    arithmetic — but it is scoped to this class's own calls: the flag is
+    part of every jit cache key, and turning it on for the whole process
+    makes the EC Pallas kernel's index maps i64, which Mosaic rejects, so
+    one /_query would take the daemon's EC path down."""
 
     pads_batches = True  # pow2 row buckets bound the jit retrace count
 
     def __init__(self):
-        import jax  # noqa: F401 — ImportError → numpy fallback upstream
+        from ..util.jaxenv import import_jax  # ImportError → numpy upstream
 
-        jax.config.update("jax_enable_x64", True)
-        import jax.numpy as jnp
+        # host_only: a process that has not been given the chip must not
+        # open it for a query (jax.devices("cpu") opens every backend)
+        self._jax = jax = import_jax(host_only=True)
+        self.xp = jax.numpy
+        self.device = scan_device(jax)
+        self.name = f"jax-{self.device.platform}"
 
-        self._jax = jax
-        self.xp = jnp
-        self.name = f"jax-{jax.default_backend()}"
+    @contextlib.contextmanager
+    def _scope(self):
+        """x64 on and the scan's device as default, for this thread and
+        this call only."""
+        with self._jax.enable_x64(True), self._jax.default_device(self.device):
+            yield
 
     def compile(self, fn, static_argnums=()):
-        return self._jax.jit(fn, static_argnums=static_argnums)
+        jitted = self._jax.jit(fn, static_argnums=static_argnums)
+
+        def call(*args):
+            with self._scope():
+                return jitted(*args)
+
+        return call
 
     def cond(self, pred, tfn, ffn):
         """Runtime branch inside a traced kernel — lets a plan skip the
@@ -446,22 +463,33 @@ class JaxKernels:
             grown = np.zeros(cap, dtype=np.uint8)
             grown[: len(buf)] = buf
             buf = grown
-        return self._jax.device_put(buf)
+        return self._jax.device_put(buf, self.device)
 
     def to_host(self, x):
         return np.asarray(x)
 
 
+def scan_device(jax):
+    """The device scan kernels run on: the host CPU, whatever else the
+    process holds.  The numeric kernel's byte-identity with
+    ``engine.run_query`` needs correctly rounded float64 division
+    (module docstring), and a TPU emulates f64: on a v5e 102,807 of
+    199,401 simple decimals came out different from ``float(s)``
+    ("191.6722" → 191.67219999999998; chip run of PR 21, CHANGES.md).
+    So a query never computes on the chip, and says so in its backend
+    label (``jax-cpu``)."""
+    return jax.devices("cpu")[0]
+
+
 _BACKENDS = {
     "numpy": NumpyKernels,
     "jax": JaxKernels,
-    "cpu": JaxKernels,
-    "tpu": JaxKernels,
+    "cpu": JaxKernels,  # old spelling of jax
 }
 
 
 def get_kernels(backend: Optional[str] = None):
-    """SWEED_QUERY_BACKEND=numpy|jax(|cpu|tpu) overrides; default is jax
+    """SWEED_QUERY_BACKEND=numpy|jax(|cpu) overrides; default is jax
     when importable, numpy otherwise — the ec/codec.get_codec shape."""
     if backend is None:
         backend = os.environ.get("SWEED_QUERY_BACKEND", "")

@@ -518,13 +518,16 @@ class AioHTTPServer:
         lag.cancel()
         reaper.cancel()
         server.close()
-        await server.wait_closed()
         # sever live keep-alive connections, same contract as the
-        # threads core: a stopped server must not keep answering
+        # threads core: a stopped server must not keep answering. This
+        # comes BEFORE wait_closed(): since Python 3.12 that waits for
+        # every connection to end, so with one idle keep-alive peer it
+        # never returned and shutdown() sat out its whole join timeout
         for task in list(self._conn_tasks):
             task.cancel()
         if self._conn_tasks:
             await asyncio.gather(*self._conn_tasks, return_exceptions=True)
+        await server.wait_closed()
 
     async def _reaper(self) -> None:
         """Deadline-aware connection reaper: kills slow-loris peers (an

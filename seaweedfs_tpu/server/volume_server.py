@@ -1347,6 +1347,7 @@ class VolumeServer:
             "put": self._req_hist.summary(op="put"),
         }
         hb["trace"] = trace.trace_stats()
+        hb["ec_codec"] = self.store.ec_codec_status()
         return 200, hb
 
     def _h_ncache(self, h, path, q, body):
@@ -1617,9 +1618,8 @@ class VolumeServer:
             SWEED_MESH_PROCESS_ID     this server's process index
             SWEED_MESH_NUM_PROCESSES  fleet size
 
-        Failure is survivable: the server still serves, reports
-        initialized=false in heartbeats, and the master's fleet scheduler
-        simply stops preferring it for mesh work.
+        Asked-for and failed is fatal: the exception leaves start(), so a
+        server that was told to join a mesh never serves outside it.
         """
         coordinator = os.environ.get("SWEED_MESH_COORDINATOR", "")
         num = tolerant_uint(os.environ.get("SWEED_MESH_NUM_PROCESSES"), 1) or 1
@@ -1630,28 +1630,31 @@ class VolumeServer:
             "num_processes": num,
             "initialized": False,
         }
-        try:
-            if coordinator and num > 1:
-                import jax
+        if coordinator and num > 1:
+            from ..util.jaxenv import import_jax
 
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=num,
-                    process_id=pid,
-                )
-                self.mesh_info["local_devices"] = jax.local_device_count()
-            self.mesh_info["initialized"] = True
-            glog.info(
-                "mesh member up: process %d/%d (coordinator %s)",
-                pid, num, coordinator or "<self>",
+            jax = import_jax()
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num,
+                process_id=pid,
             )
-        except Exception as e:  # noqa: BLE001 — degraded, not dead
-            glog.warning("jax.distributed.initialize failed: %s", e)
+            self.mesh_info["local_devices"] = jax.local_device_count()
+        self.mesh_info["initialized"] = True
+        glog.info(
+            "mesh member up: process %d/%d (coordinator %s)",
+            pid, num, coordinator or "<self>",
+        )
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
         if os.environ.get("SWEED_MESH") == "1" and self.mesh_info is None:
             self._init_mesh()
+        if self.store.ec_backend_named():
+            # resolving here (the property logs the device it got) means a
+            # daemon that cannot have its named backend stops before it
+            # opens a port, not at the first seal
+            glog.info("ec backend %r ready", self.store.ec_codec.backend)
         vs = self
 
         from ..stats import trace as _trace

@@ -222,12 +222,30 @@ def volume_mark(env: CommandEnv, vid: int, writable: bool,
 
 
 # -- EC commands (the north-star workload) ------------------------------------
-def _volume_collection(env: CommandEnv, vid: int) -> str:
-    """Resolve a volume's collection from the servers' status reports."""
+_FULL_VOLUME_BYTES = 30 << 30  # the reference's -volumeSizeLimitMB default
+
+
+def bulk_rpc_timeout(nbytes: int = _FULL_VOLUME_BYTES) -> float:
+    """Deadline for an RPC that moves a whole volume (seal, rebuild, shard
+    copy). The server reads, codes, writes and hashes every byte before it
+    answers — behind a cold backend start and a cold kernel compile — so
+    http_json's 30 s default only ever fitted test-size volumes. 600 s (the
+    fleet path's allowance) plus a second per 16 MiB: ~11 min for 10 GiB,
+    ~42 min for a full 30 GiB volume, which is also the default when the
+    caller cannot know the size."""
+    return 600.0 + nbytes / (16 << 20)
+
+
+def _volume_info(env: CommandEnv, vid: int) -> dict:
+    """A volume's entry from the servers' status reports ({} if absent)."""
     for v in volume_list(env):
         if v["id"] == vid:
-            return v.get("collection", "")
-    return ""
+            return v
+    return {}
+
+
+def _volume_collection(env: CommandEnv, vid: int) -> str:
+    return _volume_info(env, vid).get("collection", "")
 
 
 def ec_encode(
@@ -242,11 +260,15 @@ def ec_encode(
     locations = env.volume_locations(vid)
     if not locations:
         raise RuntimeError(f"volume {vid} not found")
+    info = _volume_info(env, vid)
     if collection is None or collection == "":
-        collection = _volume_collection(env, vid)
+        collection = info.get("collection", "")
     source = locations[0]
     volume_mark_readonly(env, vid)
-    r = http_json("POST", f"http://{source}/admin/ec/generate?volume={vid}")
+    r = http_json(
+        "POST", f"http://{source}/admin/ec/generate?volume={vid}",
+        timeout=bulk_rpc_timeout(info.get("size", _FULL_VOLUME_BYTES)),
+    )
     if r.get("error"):
         raise RuntimeError(f"generate: {r['error']}")
     return _spread_and_finish(env, vid, collection, source, locations,
@@ -272,6 +294,7 @@ def _spread_and_finish(
             "POST",
             f"http://{target}/admin/ec/copy?volume={vid}&collection={collection}"
             f"&source={source}&shards={shards}",
+            timeout=bulk_rpc_timeout(),
         )
         if r.get("error"):
             raise RuntimeError(f"copy to {target}: {r['error']}")
@@ -393,12 +416,16 @@ def ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
             "POST",
             f"http://{rebuilder}/admin/ec/copy?volume={vid}&collection={collection}"
             f"&source={src}&shards={sid}&copy_ecx=false&copy_vif=false",
+            timeout=bulk_rpc_timeout(),
         )
         if r.get("error"):
             raise RuntimeError(f"copy shard {sid}: {r['error']}")
         copied_in.append(sid)
 
-    r = http_json("POST", f"http://{rebuilder}/admin/ec/rebuild?volume={vid}")
+    r = http_json(
+        "POST", f"http://{rebuilder}/admin/ec/rebuild?volume={vid}",
+        timeout=bulk_rpc_timeout(),
+    )
     if r.get("error"):
         raise RuntimeError(f"rebuild: {r['error']}")
     rebuilt = r.get("rebuilt_shards", [])
@@ -445,6 +472,7 @@ def ec_decode(env: CommandEnv, vid: int, collection: str = "") -> dict:
             f"http://{target}/admin/ec/copy?volume={vid}"
             f"&collection={collection}&shards={sid}&source={urls[0]}"
             f"&copy_ecx=false&copy_vif=false",
+            timeout=bulk_rpc_timeout(),
         )
         if r.get("error"):
             raise RuntimeError(f"collect shard {sid}: {r['error']}")
@@ -453,6 +481,7 @@ def ec_decode(env: CommandEnv, vid: int, collection: str = "") -> dict:
         "POST",
         f"http://{target}/admin/ec/to_volume?volume={vid}"
         f"&collection={collection}",
+        timeout=bulk_rpc_timeout(),
     )
     if r.get("error"):
         raise RuntimeError(f"decode on {target}: {r['error']}")

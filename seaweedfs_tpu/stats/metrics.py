@@ -362,7 +362,7 @@ def register_query_metrics(
     """Counters for the vectorized scan engine (query/scan.py): rows and
     bytes pushed through scan plans, and the kernel-vs-exact-lane split
     that tells an operator whether their data shape actually vectorizes.
-    Scans are labeled by backend (jax-cpu / jax-tpu / numpy)."""
+    Scans are labeled by backend (jax-cpu / numpy)."""
     reg = registry if registry is not None else default_registry
     return {
         "rows": reg.counter(

@@ -23,14 +23,14 @@ from ..ec.constants import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, TOTAL_SHARDS, sha
 from ..ec.ec_volume import EcVolume, NeedsShardError
 from ..ec.ec_volume import NotFoundError as EcNotFoundError
 from ..stats import heat
-from ..util import faultpoints, glog
+from ..util import faultpoints, glog, jaxenv
 from .commit import StagedCommit
 from .disk_location import DiskLocation
 from .needle import Needle
 from .replica_placement import ReplicaPlacement
 from .ttl import EMPTY_TTL, TTL, read_ttl
 from .volume import NotFoundError, Volume
-from ..util.locks import make_rlock
+from ..util.locks import make_lock, make_rlock
 
 # remote_reader(vid, shard_id, offset, size) -> bytes | None
 RemoteShardReader = Callable[[int, int, int, int], Optional[bytes]]
@@ -66,6 +66,7 @@ class Store:
         for loc in self.locations:
             loc.load_existing_volumes()
         self._ec_codec: Optional[Codec] = None
+        self._codec_lock = make_lock("Store._codec_lock")
         self._ec_backend = ec_backend
         self.remote_shard_reader: Optional[RemoteShardReader] = None
         # native turbo data plane (native/turbo.py); set by the volume
@@ -97,9 +98,41 @@ class Store:
 
     @property
     def ec_codec(self) -> Codec:
-        if self._ec_codec is None:
-            self._ec_codec = get_codec(self._ec_backend)
-        return self._ec_codec
+        # every degraded-read interval comes through here: once set the
+        # reference never changes, so it is read without the lock
+        codec = self._ec_codec  # sweedlint: ok lock-discipline GIL-atomic reference read; written once, under the lock below
+        if codec is not None:
+            return codec
+        # its own lock, held across the first construction: a device codec
+        # takes seconds to build, concurrent first requests must share one
+        # (two would race for the chip), and Store._lock stays free for the
+        # heartbeat meanwhile
+        with self._codec_lock:
+            if self._ec_codec is None:
+                self._ec_codec = get_codec(self._ec_backend)
+                glog.info("ec codec: %s", self._ec_codec.describe())
+            return self._ec_codec
+
+    def ec_backend_named(self) -> Optional[str]:
+        """The backend the operator chose (-ec.backend or
+        $SWEED_EC_BACKEND), if any. A named backend is resolved when the
+        server starts, so a daemon that cannot get its chip fails there
+        instead of looking healthy until the first seal."""
+        return self._ec_backend or os.environ.get("SWEED_EC_BACKEND") or None
+
+    def ec_codec_status(self) -> dict:
+        """The ``ec_codec`` object of /status. Never resolves the codec
+        itself: /status is polled, and the unnamed default backend stays
+        lazy so chipless daemons that never seal never import JAX."""
+        codec = self._ec_codec  # sweedlint: ok lock-discipline GIL-atomic reference read; /status must not wait out a codec being built
+        if codec is None:
+            status = {"resolved": False, "backend": self.ec_backend_named()}
+        else:
+            status = {"resolved": True, **codec.describe()}
+        # what JAX is held to in this process: "cpu" means it cannot open
+        # the chip, None that JAX was never imported
+        status["jax_platforms"] = jaxenv.platforms()
+        return status
 
     # -- volume management (store.go:120-200) --------------------------------
     def add_volume(

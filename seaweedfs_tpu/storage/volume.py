@@ -1068,8 +1068,15 @@ class Volume:
                 raise VolumeError(f"volume {self.id} is compacting")
             self.close()
             base = self.file_name()
-            for ext in (".dat", ".idx", ".vif", ".sdx", ".cpd", ".cpx",
-                        ".note", ".ldb", ".mdx", ".mdx.meta"):
+            exts = [".dat", ".idx", ".vif", ".sdx", ".cpd", ".cpx",
+                    ".note", ".ldb", ".mdx", ".mdx.meta"]
+            if os.path.exists(base + ".ecx"):
+                # sealed: the .vif now belongs to the EC shard set beside
+                # it (it carries the per-shard sums scrub and rebuild
+                # checks rely on) — ec.encode drops the plain volume right
+                # after the seal, and used to take the sums with it
+                exts.remove(".vif")
+            for ext in exts:
                 try:
                     # sweedlint: ok durability destroy path; deletion is the goal, FileNotFoundError makes re-runs idempotent
                     os.remove(base + ext)

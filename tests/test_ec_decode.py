@@ -65,6 +65,39 @@ def test_decode_roundtrip_bytes_identical(tmp_path):
     v2.close()
 
 
+def test_decode_keeps_needles_written_out_of_key_order(tmp_path):
+    """Keys are handed out before the upload, so racing writers append
+    them out of order. The .ecx is key-sorted; an .idx copied from it in
+    that order made the reload's integrity check take the highest key's
+    record for the last one and truncate every needle behind it."""
+    v = Volume(str(tmp_path), collection="", vid=8)
+    rng = np.random.default_rng(8)
+    keys = [int(k) for k in rng.permutation(np.arange(1, 31))]
+    assert keys[-1] != max(keys)  # the highest key is NOT appended last
+    for k in keys:
+        v.write_needle(Needle(cookie=1, id=k, data=rng.bytes(3000 + 17 * k)))
+    v.sync()
+    base = v.file_name()
+    original_dat = open(base + ".dat", "rb").read()
+    v.close()
+    ec_encoder.write_ec_files(base)
+    ec_encoder.write_sorted_file_from_idx(base)
+    os.unlink(base + ".dat")
+    os.unlink(base + ".idx")
+
+    ec_decoder.decode_to_volume(base)
+    v2 = Volume(str(tmp_path), collection="", vid=8)  # runs the load check
+    try:
+        assert os.path.getsize(base + ".dat") == len(original_dat)
+        for k in keys:
+            n = Needle(id=k)
+            v2.read_needle(n)
+            assert len(n.data) == 3000 + 17 * k
+    finally:
+        v2.close()
+    assert open(base + ".dat", "rb").read() == original_dat
+
+
 def test_decode_with_missing_data_shards(tmp_path):
     """Missing data shards regenerate from parity before the re-interleave."""
     v = Volume(str(tmp_path), collection="", vid=6)
