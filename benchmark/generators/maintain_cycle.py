@@ -1,0 +1,144 @@
+"""Traffic kind ``maintain-cycle``: whole cycles of seal + rebuild on one
+sealed-size volume, for as long as the window lasts.
+
+One cycle: ``ec.encode`` keeping the plain volume (timed) -> the mix's lost
+shards deleted -> ``ec.rebuild`` (timed) -> rebuilt shards hashed -> all
+shards, ``.ecx`` and ``.vif`` dropped -> ``os.sync()``, so that one cycle's
+write-back does not land in the next cycle's clock. A cycle that has begun
+when the window runs out is finished and counted. The run's rates are taken
+over ALL of the window's seals and ALL of its rebuilds: bytes sealed over the
+seconds spent sealing, bytes rebuilt over the seconds spent rebuilding, so a
+stall in any operation shows. The medians of the per-operation readings, and
+the share of the window outside both clocks, stand beside them as per-layer
+metrics (``client.seal_rate_p50``, ``client.rebuild_rate_p50``,
+``client.untimed_share``).
+"""
+
+from __future__ import annotations
+
+import os
+import time
+
+from ..harness import Run, say
+
+MB = 1e6
+
+
+def cycle(run: Run, lost: list[int], log: list[dict]) -> None:
+    from seaweedfs_tpu.shell import commands
+
+    vid, total = run.loaded.vid, run.total
+    rec: dict = {"ok": False}
+    log.append(rec)
+    t = time.monotonic()
+    commands.ec_encode(run.env, vid, delete_original=False)
+    rec["seal_s"] = time.monotonic() - t
+    rec["vif_sums"] = run.vif_sums()
+    rec["size_faults"] = run.shard_size_faults(range(total))
+    run.delete_shards(lost)
+    run.wait_shard_count(total - len(lost))
+    t = time.monotonic()
+    out = commands.ec_rebuild(run.env, vid)
+    rec["rebuild_s"] = time.monotonic() - t
+    rec["rebuilt"] = out["rebuilt"]
+    rec["rebuilt_sums"] = run.hash_shards(lost)
+    rec["size_faults"] += run.shard_size_faults(range(total))
+    # back to a plain, sealed-size volume
+    run.unmount()
+    run.delete_shards(range(total))
+    os.remove(run.base + ".vif")
+    run.wait_shard_count(0)
+    os.sync()
+    rec["ok"] = True
+
+
+def rates(dat_bytes: int, seal_s: list[float], rebuild_s: list[float]) -> dict:
+    """MB/s over all of a window's seals and over all of its rebuilds: the
+    bytes moved over the seconds the operations took together."""
+    if not seal_s:
+        return {"seal_rate": None, "rebuild_rate": None}
+    return {"seal_rate": dat_bytes * len(seal_s) / MB / sum(seal_s),
+            "rebuild_rate": dat_bytes * len(rebuild_s) / MB / sum(rebuild_s)}
+
+
+def run_cell(run: Run) -> dict:
+    mix = run.mix
+    lost = list(mix["lost_shards"])
+    run.require_room()
+    d = run.start_daemon()
+    run.require_device()
+    run.load()
+    warm: list[dict] = []
+    for _ in range(mix["warm_cycles"]):
+        cycle(run, lost, warm)
+    run.require_device()
+    before = d.codec()
+    setup_s = run.setup_seconds()
+    say(f"[setup] {setup_s:.3f} s")
+
+    log: list[dict] = []
+    failed = 0
+    traced = False
+    t_end = time.monotonic() + run.args.seconds
+    window_t0 = time.monotonic()
+    while time.monotonic() < t_end:
+        tracing = run.trace and not traced
+        if tracing:
+            d.profiler("start")
+        try:
+            cycle(run, lost, log)
+        except Exception as e:  # counted, reported, and the run is not correct
+            say(f"[cycle {len(log)}] FAILED: {e!r}\n{d.log_tail(12)}")
+            failed += 1
+            break
+        finally:
+            if tracing:
+                d.profiler("stop")
+                traced = True
+        r = log[-1]
+        say(f"[cycle {len(log)}] seal {r['seal_s']:.4f} s, "
+            f"rebuild {r['rebuild_s']:.4f} s")
+    window_s = time.monotonic() - window_t0
+    after = d.codec()
+    run.stop_daemon()
+
+    done = [r for r in log if r["ok"]]
+    ref = run.reference_sums()
+    check = run.check
+    every = warm + done
+    check.count("seals_whose_vif_sums_differ_from_reference",
+                sum(r["vif_sums"] != ref["sums"] for r in every))
+    check.count("rebuilt_shards_differing_from_reference", sum(
+        r["rebuilt_sums"][s] != ref["sums"][s] for r in every for s in lost
+    ))
+    check.count("rebuilds_of_other_shards_than_lost",
+                sum(sorted(r["rebuilt"]) != sorted(lost) for r in every))
+    check.count("shard_files_of_unplanned_size",
+                sum(r["size_faults"] for r in every))
+    check.count("failed_operations", failed)
+    check.count("window_without_a_whole_cycle", int(not done))
+    run.status_check(before, after)
+
+    seal_s = [r["seal_s"] for r in done]
+    rebuild_s = [r["rebuild_s"] for r in done]
+    end_to_end = rates(run.dat_bytes, seal_s, rebuild_s)
+    if done and not run.rehearsal:  # a rehearsal prints no rate
+        say(f"[readings] seal_rate {end_to_end['seal_rate']:.2f} MB/s over "
+            f"{len(done)} seals in {sum(seal_s):.3f} s; rebuild_rate "
+            f"{end_to_end['rebuild_rate']:.2f} MB/s over {len(done)} rebuilds "
+            f"in {sum(rebuild_s):.3f} s; window {window_s:.3f} s")
+    return {
+        "attempted": 2 * len(done) + failed,
+        "failed": failed,
+        "setup_s": setup_s,
+        "window_s": window_s,
+        "end_to_end": end_to_end,
+        "counts": {"seals": len(done), "rebuilds": len(done)},
+        "readings": {"seal_s": seal_s, "rebuild_s": rebuild_s,
+                     "dat_bytes": run.dat_bytes},
+        "status": {"before": before, "after": after},
+        "client": {"dat_bytes": run.dat_bytes, "seals": len(done),
+                   "rebuilds": len(done), "traced_cycles": int(traced),
+                   "seal_s": seal_s, "rebuild_s": rebuild_s,
+                   "window_s": window_s},
+    }

@@ -1,0 +1,22 @@
+"""Share of a seal (``Store.ec_encode_volume``) spent after
+``write_ec_files`` has returned: re-read + SHA-256 of 14 shards, fsync,
+manifest, renames."""
+LAYER = "store / commit"
+UNIT = "%"
+MOVES = "seal_rate"
+SOURCE = "program_span"
+
+
+def read(ctx):
+    from benchmark.trace_reduce import inside, spans_named
+
+    trace = ctx["trace"]
+    if not trace:
+        return None
+    shares = []
+    for seal in spans_named(trace, "ec_encode_volume"):
+        writes = [w for w in spans_named(trace, "write_ec_files") if inside(w, seal)]
+        if writes:
+            tail = seal["end"] - max(w["end"] for w in writes)
+            shares.append(100.0 * tail / (seal["end"] - seal["start"]))
+    return sum(shares) / len(shares) if shares else None
