@@ -2275,36 +2275,36 @@ def probe_e2e(dat_mb: int, sink: str = "disk") -> None:
                 codec.parity_rows,
                 codec.device_put(np.ones((k, pw), dtype=np.uint8)),
             ).block_until_ready()
-        stats: dict = {}
+        from seaweedfs_tpu.stats import trace
+
+        before = trace.STAGES.snapshot()
         t0 = time.perf_counter()
         if sink == "null":
             # same items + pipeline as write_ec_files, shard bytes discarded
             outputs = [_NullSink() for _ in range(codec.total_shards)]
-            encoder._encode_pipelined(
-                base + ".dat", items, codec, outputs, n, stats=stats
-            )
+            encoder._encode_pipelined(base + ".dat", items, codec, outputs, n)
         else:
             # the exact plan the warm loop used — the timed run must launch
             # only warmed kernel shapes, so no internal re-derivation
-            encoder.write_ec_files(
-                base, codec, plan=(chunk, items), pipeline_stats=stats
-            )
+            encoder.write_ec_files(base, codec, plan=(chunk, items))
         dt = time.perf_counter() - t0
-        log(
-            f"overlap pipeline [{sink}]: wall={stats['wall_s']:.2f}s "
-            f"read={stats['read_busy_s']:.2f}s "
-            f"compute={stats['compute_busy_s']:.2f}s "
-            f"fetch={stats['fetch_busy_s']:.2f}s "
-            f"write={stats['write_busy_s']:.2f}s "
-            f"efficiency={stats['efficiency']:.2f} "
-            f"(1.0 = wall==max(stage); serial loop would be "
-            f"{(stats['read_busy_s'] + stats['compute_busy_s'] + stats['fetch_busy_s'] + stats['write_busy_s']) / stats['wall_s']:.2f}x slower)"
+        # this run's stages: the tracer's table after, less before
+        after = trace.STAGES.snapshot()
+        legs = ("read", "dispatch", "fetch", "write")
+        wall, *busy = (
+            after[name]["busy_s"] - before.get(name, {}).get("busy_s", 0.0)
+            for name in ("ec.seal.pipeline", *(f"ec.seal.{l}" for l in legs))
         )
-    print(
-        f"{n / dt / 1e9:.4f} {stats['efficiency']:.3f} "
-        f"{stats['read_busy_s']:.3f} {stats['compute_busy_s']:.3f} "
-        f"{stats['fetch_busy_s']:.3f} {stats['write_busy_s']:.3f}"
-    )
+        efficiency = max(busy) / wall
+        log(
+            f"overlap pipeline [{sink}]: wall={wall:.2f}s "
+            + " ".join(f"{l}={b:.2f}s" for l, b in zip(legs, busy))
+            + f" efficiency={efficiency:.2f} "
+            f"(1.0 = wall==max(stage); serial loop would be "
+            f"{sum(busy) / wall:.2f}x slower)"
+        )
+    print(f"{n / dt / 1e9:.4f} {efficiency:.3f} "
+          + " ".join(f"{b:.3f}" for b in busy))
 
 
 def probe_extras(sweep_guard_s: float = 240.0) -> None:

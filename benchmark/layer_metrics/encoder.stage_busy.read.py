@@ -1,0 +1,15 @@
+"""Share of a seal's pipeline wall (``ec.seal.pipeline``) that its ``read`` leg
+was busy (``ec.seal.read``: the reader thread's disk read of one chunk of
+the ``.dat``). The legs overlap, so the shares do not sum to 100; the
+highest is the leg that bounds a seal."""
+LAYER = "encoder pipeline"
+UNIT = "%"
+MOVES = "seal_rate"
+SOURCE = "program_span"
+
+
+def read(ctx):
+    from benchmark import stages
+
+    return stages.ratio(ctx, ("ec.seal.read", "busy_s"),
+                        ("ec.seal.pipeline", "busy_s"), 100.0)

@@ -20,6 +20,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..stats import trace
 from ..util import jaxenv
 from ..util.locks import make_lock
 from . import gf
@@ -507,7 +508,9 @@ class TpuCodec(Codec):
             if width % align:
                 padded = align * -(-width // align)
                 piece = np.pad(piece, ((0, 0), (0, padded - width)))
-            res = np.asarray(self.matmul_device(matrix, jnp.asarray(piece)))
+            # one synchronous round trip: stage, launch, copy back
+            with trace.stage_span("ec.codec.launch", bytes=piece.nbytes):
+                res = np.asarray(self.matmul_device(matrix, jnp.asarray(piece)))
             out[:, pos:end] = res[:, :width]
             pos = end
         return out

@@ -20,13 +20,15 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Optional
 
 import numpy as np
 
+from ..stats import trace
 from ..storage import idx as idx_mod
 from ..storage.types import OFFSET_SIZE, TOMBSTONE_FILE_SIZE
-from ..util import faultpoints
+from ..util import faultpoints, glog
 from .codec import Codec, get_codec
 from .constants import (
     LARGE_BLOCK_SIZE,
@@ -146,6 +148,19 @@ def _item_width(item) -> int:
     if item[0] == "cols":
         return item[4]
     return item[2] * item[3]  # block_size * n_rows
+
+
+def _item_dat_bytes(item, k: int, dat_size: int) -> int:
+    """Bytes of the .dat this work item covers (the rest of its width is
+    zero padding past EOF)."""
+    if item[0] == "cols":
+        _, start, block_size, col, width = item
+        return sum(
+            max(0, min(width, dat_size - (start + i * block_size + col)))
+            for i in range(k)
+        )
+    _, start, block_size, g = item
+    return max(0, min(start + g * k * block_size, dat_size) - start)
 
 
 def _region_fully_data(fd: int, start: int, length: int) -> bool:
@@ -273,7 +288,6 @@ def write_ec_files(
     large_block_size: int = LARGE_BLOCK_SIZE,
     small_block_size: int = SMALL_BLOCK_SIZE,
     chunk_bytes: Optional[int] = None,
-    pipeline_stats: Optional[dict] = None,
     plan: Optional[tuple] = None,
     suffix: str = "",
 ) -> None:
@@ -316,8 +330,7 @@ def write_ec_files(
     ]
     try:
         if hasattr(codec, "matmul_device"):
-            _encode_pipelined(dat, items, codec, outputs, dat_size,
-                              stats=pipeline_stats)
+            _encode_pipelined(dat, items, codec, outputs, dat_size)
         else:
             # the parity buffer is consumed (written out) before the next
             # chunk encodes, so one buffer serves the whole stream — a fresh
@@ -357,7 +370,8 @@ def write_ec_files(
 
 
 def _overlap_pipeline(produce, compute, consume, fetch=None,
-                      stats: Optional[dict] = None) -> None:
+                      stats: Optional[dict] = None,
+                      op: str = "ec.overlap") -> None:
     """Four-stage overlap shared by encode and rebuild: a reader thread
     runs `produce` (an iterator of host chunks), the main thread runs
     `compute` (async device dispatch: H2D + kernel launch), a fetch thread
@@ -373,15 +387,23 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     even with writes discarded. ``fetch=None`` degrades to the 3-stage
     form for host-only callers.
 
-    With a ``stats`` dict, per-stage BUSY time (time inside the stage
-    callable, excluding queue blocking) and wall time are recorded, plus
-    ``efficiency`` = max(stage busy) / wall — 1.0 means the slowest stage
-    fully hides the others, i.e. wall ≈ max(stage) rather than Σ(stages),
-    which is the whole point vs the reference's serial read→Encode→write
-    loop (ec_encoder.go:162-192)."""
+    Every chunk passes each leg inside a stage span (stats/trace.py):
+    ``<op>.read``, ``.dispatch``, ``.fetch`` and ``.write`` under
+    ``<op>.pipeline``, the call's wall. They time the stage callable alone,
+    not the queue blocking around it, and the callables count the bytes
+    they move against them (``trace.add_stage_bytes``); the threads run in
+    copies of the caller's context, so the spans of one seal are one tree.
+    The totals are served in /status (``ec_codec.stages``).
+
+    A ``stats`` dict is this call's view of the same spans: per-stage BUSY
+    time and wall time, plus ``efficiency`` = max(stage busy) / wall — 1.0
+    means the slowest stage fully hides the others, i.e. wall ≈ max(stage)
+    rather than Σ(stages), which is the whole point vs the reference's
+    serial read→Encode→write loop (ec_encoder.go:162-192). It is filled
+    only while tracing is on (``SWEED_TRACE``)."""
+    import contextvars
     import queue
     import threading
-    import time as _time
 
     # one-slot mid/out queues: enough lookahead for compute(i+1) to ride
     # the link concurrently with fetch(i), without tripling the chunks of
@@ -390,18 +412,29 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     fetch_q: queue.Queue = queue.Queue(maxsize=1)
     write_q: queue.Queue = queue.Queue(maxsize=1)
     errors: list[BaseException] = []
-    busy = {"read": 0.0, "compute": 0.0, "fetch": 0.0, "write": 0.0}
-    t_wall = _time.perf_counter()
+    busy = {"read": 0.0, "dispatch": 0.0, "fetch": 0.0, "write": 0.0}
+
+    def run_leg(leg, fn, got):
+        """One chunk through one leg, inside that leg's stage span."""
+        with trace.stage_span(f"{op}.{leg}") as span:
+            out = fn(got)
+        if span is not None:
+            busy[leg] += span.duration  # each thread adds to its own leg
+        return out
 
     def reader():
         try:
             it = produce()
             while True:
-                t0 = _time.perf_counter()
-                item = next(it, None)
-                busy["read"] += _time.perf_counter() - t0
+                scope = trace.stage_span(f"{op}.read")
+                with scope as span:
+                    item = next(it, None)
+                    if item is None:
+                        scope.discard()  # the end of input is no chunk
                 if item is None or errors:
                     return
+                if span is not None:
+                    busy["read"] += span.duration
                 read_q.put(item)
         except BaseException as e:  # surfaced after join
             errors.append(e)
@@ -414,10 +447,7 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
                 got = fetch_q.get()
                 if got is None:
                     return
-                t0 = _time.perf_counter()
-                out = fetch(got)
-                busy["fetch"] += _time.perf_counter() - t0
-                write_q.put(out)
+                write_q.put(run_leg("fetch", fetch, got))
         except BaseException as e:
             errors.append(e)
             while fetch_q.get() is not None:  # drain so the feeder unblocks
@@ -431,64 +461,128 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
                 got = write_q.get()
                 if got is None:
                     return
-                t0 = _time.perf_counter()
-                consume(got)
-                busy["write"] += _time.perf_counter() - t0
+                run_leg("write", consume, got)
         except BaseException as e:
             errors.append(e)
             while write_q.get() is not None:  # drain so the feeder unblocks
                 pass
 
+    def thread(target) -> threading.Thread:
+        # a copy of this context each (one Context is entered by one thread
+        # at a time), taken inside the pipeline's span: the legs' spans
+        # parent on it, as util/pipeline.py's do on their submitter's
+        return threading.Thread(
+            target=contextvars.copy_context().run, args=(target,), daemon=True
+        )
+
     mid_q = fetch_q if fetch is not None else write_q
-    rt = threading.Thread(target=reader, daemon=True)
-    wt = threading.Thread(target=writer, daemon=True)
-    ft = threading.Thread(target=fetcher, daemon=True) if fetch is not None else None
-    rt.start()
-    wt.start()
-    if ft is not None:
-        ft.start()
-    try:
-        while True:
-            got = read_q.get()
-            if got is None:
-                break
-            if errors:
-                continue  # keep draining so the reader can finish
-            try:
-                t0 = _time.perf_counter()
-                out = compute(got)
-                busy["compute"] += _time.perf_counter() - t0
-                mid_q.put(out)
-            except BaseException as e:
-                errors.append(e)
-    finally:
-        mid_q.put(None)
+    with trace.stage_span(f"{op}.pipeline", quiet=True) as whole:
+        rt = thread(reader)
+        wt = thread(writer)
+        ft = thread(fetcher) if fetch is not None else None
+        rt.start()
+        wt.start()
         if ft is not None:
-            ft.join()  # fetcher forwards its None to write_q on exit
-        wt.join()
-        # unblock the reader if it is mid-put (main loop exited early)
-        while rt.is_alive():
-            try:
-                read_q.get_nowait()
-            except queue.Empty:
-                rt.join(timeout=0.05)
-        rt.join()
-    if errors:
-        raise errors[0]
-    if stats is not None:
-        wall = _time.perf_counter() - t_wall
+            ft.start()
+        try:
+            while True:
+                got = read_q.get()
+                if got is None:
+                    break
+                if errors:
+                    continue  # keep draining so the reader can finish
+                try:
+                    mid_q.put(run_leg("dispatch", compute, got))
+                except BaseException as e:
+                    errors.append(e)
+        finally:
+            mid_q.put(None)
+            if ft is not None:
+                ft.join()  # fetcher forwards its None to write_q on exit
+            wt.join()
+            # unblock the reader if it is mid-put (main loop exited early)
+            while rt.is_alive():
+                try:
+                    read_q.get_nowait()
+                except queue.Empty:
+                    rt.join(timeout=0.05)
+            rt.join()
+        if errors:
+            raise errors[0]
+    if stats is not None and whole is not None:
+        wall = whole.duration
         stats.update(
             wall_s=wall,
-            read_busy_s=busy["read"],
-            compute_busy_s=busy["compute"],
-            fetch_busy_s=busy["fetch"],
-            write_busy_s=busy["write"],
+            **{f"{leg}_busy_s": s for leg, s in busy.items()},
             efficiency=max(busy.values()) / wall if wall > 0 else 0.0,
         )
 
 
-def _encode_pipelined(dat, items, codec, outputs, dat_size: int,
-                      stats: Optional[dict] = None) -> None:
+def _await(on_device) -> None:
+    ready = getattr(on_device, "block_until_ready", None)
+    if ready is not None:  # a host stand-in (tests) is ready as it is
+        ready()
+
+
+class _StagedWatch:
+    """The host-to-device leg as a stage of its own, ``<op>.h2d``: from a
+    chunk's ``device_put`` call until its staged input is ready. A thread of
+    its own does the waiting, so that no wait is added on the dispatch
+    thread (the pipeline's overlap is as it was), none behind the fetch
+    thread's copy back (the time is the link's, not a queue's), and nothing
+    keeps a chunk's input on the device longer than its kernel does: the
+    watch lets go of it the moment it is ready."""
+
+    def __init__(self, op: str):
+        import queue
+        import threading
+
+        self._stage = f"{op}.h2d"
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, daemon=True, name="ec-h2d"
+        )
+        self._thread.start()
+
+    def watch(self, staged, t_put: float) -> None:
+        """Called by the dispatch thread between ``device_put`` and the
+        launch; the span parents on the dispatch span it is called under."""
+        import contextvars
+
+        self._q.put((contextvars.copy_context(), staged, t_put))
+
+    def _run(self) -> None:
+        while True:
+            item = self._q.get()
+            if item is None:
+                return
+            ctx, staged, t_put = item
+            try:
+                _await(staged)
+            except Exception as e:
+                # the fetch leg meets the same error and raises it
+                glog.warning("%s: staged input never ready: %s", self._stage, e)
+                continue
+            ctx.run(trace.record_stage, self._stage,
+                    time.perf_counter() - t_put, bytes=staged.nbytes)
+            del item, ctx, staged  # not held while waiting for the next
+
+    def close(self) -> None:
+        self._q.put(None)
+        self._thread.join()
+
+
+def _copy_back(op: str, out_dev, copy):
+    """The fetch thread's part of one chunk: wait for the result, then the
+    device-to-host leg as a stage of its own, ``<op>.d2h``: ``copy`` alone."""
+    _await(out_dev)
+    with trace.stage_span(f"{op}.d2h", bytes=out_dev.nbytes):
+        out = copy(out_dev)
+    trace.add_stage_bytes(out_dev.nbytes)
+    return out
+
+
+def _encode_pipelined(dat, items, codec, outputs, dat_size: int) -> None:
     k, m = codec.data_shards, codec.parity_shards
     align = codec.alignment() if hasattr(codec, "alignment") else 1
 
@@ -496,6 +590,7 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int,
         with open(dat, "rb") as f:
             for it in items:
                 data, has_data = _read_item(f, it, k, dat_size)
+                trace.add_stage_bytes(_item_dat_bytes(it, k, dat_size))
                 yield (_item_width(it), data, has_data)
 
     def compute(got):
@@ -506,10 +601,11 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int,
         if width % align:
             padded = align * -(-width // align)
             piece = np.pad(data, ((0, 0), (0, padded - width)))
-        parity_dev = codec.matmul_device(
-            codec.parity_rows, codec.device_put(piece)
-        )
-        return width, data, parity_dev
+        t_put = time.perf_counter()
+        staged = codec.device_put(piece)
+        h2d.watch(staged, t_put)
+        trace.add_stage_bytes(piece.nbytes)
+        return width, data, codec.matmul_device(codec.parity_rows, staged)
 
     # the D2H leg dominates end-to-end at large chunk sizes; pulling the m
     # parity rows as m concurrent row-sized transfers instead of one
@@ -521,15 +617,17 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int,
         max_workers=max(1, min(m, 4)), thread_name_prefix="ec-d2h"
     )
 
+    def parity_rows(parity_dev):
+        return list(
+            fetch_pool.map(np.asarray, (parity_dev[j] for j in range(m)))
+        )
+
     def fetch(got):
         width, data, parity_dev = got
         if parity_dev is None:
             return width, data, None
         # the blocking D2H leg: overlaps the next chunk's H2D + dispatch
-        rows = list(
-            fetch_pool.map(np.asarray, (parity_dev[j] for j in range(m)))
-        )
-        return width, data, rows
+        return width, data, _copy_back("ec.seal", parity_dev, parity_rows)
 
     def consume(got):
         faultpoints.fire("ec.encode.chunk", path=outputs[0].name)
@@ -544,10 +642,13 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int,
             # parity[j] indexing (not parity[j, ...]) so both a 2-D array
             # and the row list from the parallel fetch work here
             outputs[k + j].write(parity[j][:width].tobytes())
+        trace.add_stage_bytes((k + m) * width)
 
+    h2d = _StagedWatch("ec.seal")
     try:
-        _overlap_pipeline(produce, compute, consume, fetch=fetch, stats=stats)
+        _overlap_pipeline(produce, compute, consume, fetch=fetch, op="ec.seal")
     finally:
+        h2d.close()
         fetch_pool.shutdown(wait=True)
 
 
@@ -555,7 +656,6 @@ def rebuild_ec_files(
     base_file_name: str,
     codec: Optional[Codec] = None,
     chunk_bytes: Optional[int] = None,
-    pipeline_stats: Optional[dict] = None,
 ) -> list[int]:
     """Regenerate missing shard files from ≥k present ones
     (RebuildEcFiles / generateMissingEcFiles, :61,95). Returns generated ids."""
@@ -595,7 +695,6 @@ def rebuild_ec_files(
             _rebuild_pipelined(
                 codec, ins, outs, missing, shard_size,
                 _depth_chunk(chunk, shard_size, align),
-                stats=pipeline_stats,
             )
         else:
             pos = 0
@@ -656,8 +755,7 @@ def _rebuild_rows(codec, present_ids: list[int], missing: list[int]) -> np.ndarr
     return np.vstack(blocks)
 
 
-def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk,
-                       stats: Optional[dict] = None) -> None:
+def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
     """Overlap disk reads, H2D staging + device matmul, and shard writes —
     the encode pipeline's shape applied to rebuild (the serial
     read→reconstruct→write loop leaves the device idle during IO)."""
@@ -681,6 +779,7 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk,
                 buf[row, :width] = np.frombuffer(
                     ins[sid].read(width), dtype=np.uint8
                 )
+                trace.add_stage_bytes(width)
                 has_data = True
             yield (width, buf, has_data)
             pos += width
@@ -689,13 +788,18 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk,
         width, buf, has_data = got
         if not has_data or not buf.any():
             return width, None  # zeros reconstruct to zeros
-        return width, codec.matmul_device(rows, codec.device_put(buf))
+        t_put = time.perf_counter()
+        staged = codec.device_put(buf)
+        h2d.watch(staged, t_put)
+        trace.add_stage_bytes(buf.nbytes)
+        return width, codec.matmul_device(rows, staged)
 
     def fetch(got):
         width, out_dev = got
         if out_dev is None:
             return width, None
-        return width, np.asarray(out_dev)  # blocking D2H leg
+        # blocking D2H leg
+        return width, _copy_back("ec.rebuild", out_dev, np.asarray)
 
     def consume(got):
         width, out = got
@@ -705,8 +809,14 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk,
             return
         for j, sid in enumerate(missing):
             outs[sid].write(out[j, :width].tobytes())
+        trace.add_stage_bytes(len(missing) * width)
 
-    _overlap_pipeline(produce, compute, consume, fetch=fetch, stats=stats)
+    h2d = _StagedWatch("ec.rebuild")
+    try:
+        _overlap_pipeline(produce, compute, consume, fetch=fetch,
+                          op="ec.rebuild")
+    finally:
+        h2d.close()
 
 
 # -- .ecx sorted index -------------------------------------------------------

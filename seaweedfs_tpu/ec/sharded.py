@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..stats import trace
 from ..util.jaxenv import import_jax
 from . import gf
 from .codec import (
@@ -379,7 +380,11 @@ class MeshCodec(Codec):
             if width % align:
                 padded = align * -(-width // align)
                 piece = np.pad(piece, ((0, 0), (0, padded - width)))
-            res = np.asarray(self.matmul_device(matrix, self.device_put(piece)))
+            # one synchronous round trip: stage, launch, copy back
+            with trace.stage_span("ec.codec.launch", bytes=piece.nbytes):
+                res = np.asarray(
+                    self.matmul_device(matrix, self.device_put(piece))
+                )
             out[:, pos:end] = res[:, :width]
             pos = end
         return out

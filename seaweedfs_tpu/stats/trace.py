@@ -23,6 +23,13 @@ master → volume hops. This module is the divergence (PARITY: tracing):
 - slow requests  — a finished span slower than ``SWEED_TRACE_SLOW_MS``
   (default 1000) logs a glog warning with its trace id, so the trace of
   an outlier is discoverable from the daemon's own log.
+- stage spans    — ``stage_span`` / ``record_stage``: the same spans for
+  the steps of a long operation (a seal's pipeline legs, a recovery's
+  shard fetches). At close each also adds itself to the process-wide
+  ``STAGES`` table by name (the ``ec_codec.stages`` object of a volume
+  server's /status) and, where JAX is already loaded, is written into the
+  profiler's trace as a ``TraceAnnotation`` of the same name. This module
+  never imports JAX itself.
 
 Ids are random hex (os.urandom): 16 chars of trace id, 8 of span id —
 the Dapper/W3C shape, sized down to this cluster's scale.
@@ -33,6 +40,7 @@ from __future__ import annotations
 import contextvars
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -75,6 +83,7 @@ def slow_threshold_s() -> float:
     except ValueError:
         ms = 1000.0
     val = max(0.0, ms) / 1000.0
+    # sweedlint: ok cross-domain-race a memo of one env string's parse: any thread computes the same tuple, and the assignment is GIL-atomic
     _slow_cache = (raw, val)
     return val
 
@@ -233,6 +242,40 @@ def trace_stats() -> dict:
     return RING.stats()
 
 
+@instrument
+class StageTable:
+    """Process-wide totals of finished stage spans, by span name: ``n``
+    spans and ``busy_s`` seconds inside them, plus the sums of the tags a
+    stage carries — ``bytes`` where it moves bytes, ``failed`` attempts
+    and ``slept_s`` of back-off where it retries. Stages close on request
+    threads and on the encode pipeline's threads at once, so the rows are
+    kept under one lock. A reader takes two snapshots and subtracts."""
+
+    SUMMED_TAGS = ("bytes", "failed", "slept_s")
+
+    def __init__(self):
+        self._lock = make_lock("StageTable._lock")
+        self._rows: dict[str, dict] = {}
+
+    def add(self, span: Span) -> None:
+        with self._lock:
+            row = self._rows.get(span.name)
+            if row is None:
+                row = self._rows[span.name] = {"n": 0, "busy_s": 0.0}
+            row["n"] += 1
+            row["busy_s"] += span.duration
+            for key in self.SUMMED_TAGS:
+                if key in span.tags:
+                    row[key] = row.get(key, 0) + span.tags[key]
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {name: dict(row) for name, row in self._rows.items()}
+
+
+STAGES = StageTable()
+
+
 class _SpanScope:
     """Context manager that owns one span's contextvar window. ``span``
     is None when tracing is disabled — callers guard tag writes on it."""
@@ -258,15 +301,21 @@ class _SpanScope:
         if exc_type is not None:
             self.span.status = "error"
             self.span.tags.setdefault("error", exc_type.__name__)
-        RING.add(self.span)
-        slow = slow_threshold_s()
-        if slow and self.span.duration >= slow:
-            glog.warning(
-                "slow request: %s %s took %.1fms (trace %s span %s)",
-                self.span.service, self.span.name,
-                self.span.duration * 1000.0,
-                self.span.trace_id, self.span.span_id,
-            )
+        self._finish()
+
+    def _finish(self) -> None:
+        _finish(self.span, "request")
+
+
+def _finish(span: Span, what: str, quiet: bool = False) -> None:
+    RING.add(span)
+    slow = slow_threshold_s()
+    if slow and not quiet and span.duration >= slow:
+        glog.warning(
+            "slow %s: %s %s took %.1fms (trace %s span %s)",
+            what, span.service, span.name, span.duration * 1000.0,
+            span.trace_id, span.span_id,
+        )
 
 
 def start_span(
@@ -291,6 +340,108 @@ def start_span(
     if tags:
         span.tags.update(tags)
     return _SpanScope(span)
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where JAX is already in this
+    process, else None. Looked up, never imported: a chipless daemon that
+    never seals never loads JAX, and tracing must not be what does."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+def _stage(name: str, tags: dict) -> Span:
+    """A span under the context's active one, in its service."""
+    cur = _current.get()
+    if cur is None:
+        span = Span(name)
+    else:
+        span = Span(name, service=cur.service, trace_id=cur.trace_id,
+                    parent_id=cur.span_id)
+    span.tags.update(tags)
+    return span
+
+
+def _finish_stage(span: Span, quiet: bool) -> None:
+    STAGES.add(span)
+    _finish(span, "stage", quiet)
+
+
+class _StageScope(_SpanScope):
+    """A span's scope that also enters the profiler's annotation of the
+    same name, and closes into the stage table."""
+
+    __slots__ = ("_quiet", "_annotated", "_discarded")
+
+    def __init__(self, span: Optional[Span], quiet: bool):
+        super().__init__(span)
+        self._quiet = quiet
+        self._annotated = None
+        self._discarded = False
+
+    def __enter__(self) -> Optional[Span]:
+        if self.span is not None:
+            annotation = _trace_annotation()
+            if annotation is not None:
+                self._annotated = annotation(self.span.name)
+                self._annotated.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        super().__exit__(exc_type, exc, tb)
+        if self._annotated is not None:
+            self._annotated.__exit__(exc_type, exc, tb)
+
+    def discard(self) -> None:
+        """The stage found nothing to do (a reader at the end of its
+        input): leave the scope without a row, a ring entry or a line."""
+        self._discarded = True
+
+    def _finish(self) -> None:
+        if not self._discarded:
+            _finish_stage(self.span, self._quiet)
+
+
+def stage_span(name: str, quiet: bool = False, **tags) -> _StageScope:
+    """Open a stage span under the context's active span: a ``start_span``
+    that at close also adds itself to ``STAGES`` under ``name`` and sits in
+    a JAX profiler session's trace under the same name. The tags
+    ``bytes``, ``failed`` and ``slept_s`` are summed into the table; the
+    stage may set them on the span it is handed while it runs. A stage
+    slower than ``SWEED_TRACE_SLOW_MS`` logs one line naming it, unless
+    ``quiet``: a whole operation that is always long (a seal, its pipeline,
+    its commit) is found through its children, not by a line per seal."""
+    if not enabled():
+        return _StageScope(None, quiet)
+    return _StageScope(_stage(name, tags), quiet)
+
+
+def record_stage(name: str, busy_s: float, **tags) -> None:
+    """A stage that ends now and took ``busy_s``, for time that is not one
+    ``with`` block on this thread: a transfer begun on another thread, the
+    reads of one loop taken together. Ring, table and slow line as
+    ``stage_span``; the profiler's trace cannot be written in hindsight."""
+    if not enabled():
+        return
+    span = _stage(name, tags)
+    # sweedlint: ok cross-domain-race a span made here, finished here: in no ring and no table until _finish_stage below
+    span.start -= busy_s
+    # sweedlint: ok cross-domain-race as above
+    span.duration = busy_s
+    _finish_stage(span, quiet=False)
+
+
+def add_stage_bytes(n: int) -> None:
+    """Count ``n`` bytes moved against the stage span this code runs in."""
+    span = _current.get()
+    if span is not None:
+        span.tags["bytes"] = span.tags.get("bytes", 0) + n
 
 
 def h_debug_traces(handler, path, query, body):

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from collections import deque
 from typing import Callable, Optional
 
@@ -22,7 +23,7 @@ from ..ec.codec import Codec, get_codec
 from ..ec.constants import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, TOTAL_SHARDS, shard_ext
 from ..ec.ec_volume import EcVolume, NeedsShardError
 from ..ec.ec_volume import NotFoundError as EcNotFoundError
-from ..stats import heat
+from ..stats import heat, trace
 from ..util import faultpoints, glog, jaxenv
 from .commit import StagedCommit
 from .disk_location import DiskLocation
@@ -132,6 +133,10 @@ class Store:
         # what JAX is held to in this process: "cpu" means it cannot open
         # the chip, None that JAX was never imported
         status["jax_platforms"] = jaxenv.platforms()
+        # totals of the EC path's stage spans (docs/OBSERVABILITY.md): a
+        # reader subtracts two snapshots. Absent while tracing is off
+        if trace.enabled():
+            status["stages"] = trace.STAGES.snapshot()
         return status
 
     # -- volume management (store.go:120-200) --------------------------------
@@ -362,41 +367,50 @@ class Store:
         v = self.find_volume(vid)
         if v is None:
             raise NotFoundError(f"volume {vid} not found")
-        v.read_only = True
-        v.sync()
-        base = v.file_name()
-        from ..ec import encoder
+        with trace.stage_span("ec.seal", quiet=True, vid=vid):
+            v.read_only = True
+            v.sync()
+            base = v.file_name()
+            trace.add_stage_bytes(os.path.getsize(base + ".dat"))
+            from ..ec import encoder
 
-        sc = StagedCommit(base, "ec.encode")
-        for sid in range(TOTAL_SHARDS):
-            sc.stage(base + shard_ext(sid))
-        sc.stage(base + ".ecx")
-        vif_tmp = sc.stage(base + ".vif")
-        try:
-            encoder.write_ec_files(base, self.ec_codec, suffix=".tmp")
-            encoder.write_sorted_file_from_idx(base, ext=".ecx.tmp")
-            # per-shard sha256 into the .vif: the scrub thread's integrity
-            # ground truth (RS is deterministic — rebuilds hash identically)
-            import hashlib
-
-            sums = []
+            sc = StagedCommit(base, "ec.encode")
             for sid in range(TOTAL_SHARDS):
-                digest = hashlib.sha256()
-                with open(base + shard_ext(sid) + ".tmp", "rb") as sf:
-                    for chunk in iter(lambda: sf.read(1 << 20), b""):
-                        digest.update(chunk)
-                sums.append(digest.hexdigest())
-            encoder.save_volume_info(
-                vif_tmp,
-                version=v.version,
-                replication=str(v.super_block.replica_placement),
-                shard_sums=sums,
-            )
-            sc.commit()
-        except BaseException:
-            sc.abort()
-            raise
-        return list(range(TOTAL_SHARDS))
+                sc.stage(base + shard_ext(sid))
+            sc.stage(base + ".ecx")
+            vif_tmp = sc.stage(base + ".vif")
+            try:
+                encoder.write_ec_files(base, self.ec_codec, suffix=".tmp")
+                with trace.stage_span("ec.seal.ecx", quiet=True):
+                    encoder.write_sorted_file_from_idx(base, ext=".ecx.tmp")
+                # per-shard sha256 into the .vif: the scrub thread's
+                # integrity ground truth (RS is deterministic — rebuilds
+                # hash identically)
+                import hashlib
+
+                sums = []
+                for sid in range(TOTAL_SHARDS):
+                    digest = hashlib.sha256()
+                    with trace.stage_span("ec.seal.hash", sid=sid), open(
+                        base + shard_ext(sid) + ".tmp", "rb"
+                    ) as sf:
+                        for chunk in iter(lambda: sf.read(1 << 20), b""):
+                            digest.update(chunk)
+                        trace.add_stage_bytes(sf.tell())
+                    sums.append(digest.hexdigest())
+                # fsync, manifest, renames: the guarantee itself
+                with trace.stage_span("ec.seal.commit", quiet=True):
+                    encoder.save_volume_info(
+                        vif_tmp,
+                        version=v.version,
+                        replication=str(v.super_block.replica_placement),
+                        shard_sums=sums,
+                    )
+                    sc.commit()
+            except BaseException:
+                sc.abort()
+                raise
+            return list(range(TOTAL_SHARDS))
 
     # -- scrub findings (consumed by cluster/lifecycle.py via heartbeats) ----
     def report_corrupt_needle(self, vid: int, nid: int) -> None:
@@ -470,35 +484,53 @@ class Store:
             return None
         from ..util.retry import TRANSIENT, RetryError, RetryPolicy, retry_call
 
-        def _fetch():
-            faultpoints.fire("ec.read.remote-fetch")
-            data = self.remote_shard_reader(vid, sid, offset, size)
-            if data is None or len(data) != size:
-                # a short range is a failed attempt, not a success
-                raise IOError(f"short/empty remote range for {vid}.{sid}")
-            return data
-
         policy = RetryPolicy(
             attempts=max(1, self.remote_fetch_attempts),
             base_s=self.remote_fetch_backoff_s,
             cap_s=max(1.0, self.remote_fetch_backoff_s * 8),
             deadline_s=self.remote_fetch_timeout_s,
         )
-        try:
-            return retry_call(
-                _fetch,
-                policy=policy,
-                # every failure mode here (peer down, timeout, short read,
-                # injected fault) heals the same way: try again, then fall
-                # through to reconstruction — nothing is poison
-                classify=lambda e: TRANSIENT,
-                on_retry=lambda e, attempt, delay: glog.warning(
+        # the whole ask, sleeps included: attempts that raised and the
+        # back-off slept between them are summed into the stage table
+        with trace.stage_span(
+            "ec.read.remote", sid=sid, failed=0, slept_s=0.0
+        ) as span:
+
+            def _fetch():
+                try:
+                    faultpoints.fire("ec.read.remote-fetch")
+                    data = self.remote_shard_reader(vid, sid, offset, size)
+                    if data is None or len(data) != size:
+                        # a short range is a failed attempt, not a success
+                        raise IOError(
+                            f"short/empty remote range for {vid}.{sid}"
+                        )
+                    return data
+                except Exception:
+                    if span is not None:
+                        span.tags["failed"] += 1
+                    raise
+
+            def _on_retry(e, attempt, delay):
+                if span is not None:
+                    span.tags["slept_s"] += delay
+                glog.warning(
                     "remote shard %d.%d fetch attempt %d failed: %s",
                     vid, sid, attempt, e,
-                ),
-            )
-        except RetryError:
-            return None
+                )
+
+            try:
+                return retry_call(
+                    _fetch,
+                    policy=policy,
+                    # every failure mode here (peer down, timeout, short
+                    # read, injected fault) heals the same way: try again,
+                    # then fall through to reconstruction — nothing is poison
+                    classify=lambda e: TRANSIENT,
+                    on_retry=_on_retry,
+                )
+            except RetryError:
+                return None
 
     def _recover_interval(
         self, ev: EcVolume, missing_shard: int, offset: int, size: int
@@ -506,28 +538,44 @@ class Store:
         """Fetch the same byte range from ≥k sibling shards and RS-decode
         (recoverOneRemoteEcShardInterval, store_ec.go:322)."""
         codec = self.ec_codec
-        shards: list[Optional[np.ndarray]] = [None] * ev.total_shards
-        have = 0
-        for sid in range(ev.total_shards):
-            if sid == missing_shard:
-                continue
-            local = ev.shards.get(sid)
-            buf = None
-            if local is not None:
-                buf = local.read_at(offset, size)
-            else:
-                buf = self._remote_shard_read(ev.id, sid, offset, size)
-            if buf is not None and len(buf) == size:
-                shards[sid] = np.frombuffer(buf, dtype=np.uint8)
-                have += 1
-            if have >= ev.data_shards:
-                break
-        if have < ev.data_shards:
-            raise EcNotFoundError(
-                f"volume {ev.id} shard {missing_shard}: only {have} shards reachable"
-            )
-        rebuilt = codec.reconstruct(shards, data_only=missing_shard < ev.data_shards)
-        return rebuilt[missing_shard].tobytes()
+        # quiet, as the decode below: a slow recovery is named by the leaf
+        # stage that was slow (an ask, the local reads, the launch)
+        with trace.stage_span(
+            "ec.recover", quiet=True, missing=missing_shard, size=size,
+            bytes=size,
+        ):
+            shards: list[Optional[np.ndarray]] = [None] * ev.total_shards
+            have = 0
+            local_s, local_bytes = 0.0, 0
+            for sid in range(ev.total_shards):
+                if sid == missing_shard:
+                    continue
+                local = ev.shards.get(sid)
+                buf = None
+                if local is not None:
+                    t = time.perf_counter()
+                    buf = local.read_at(offset, size)
+                    local_s += time.perf_counter() - t
+                    local_bytes += len(buf) if buf is not None else 0
+                else:
+                    buf = self._remote_shard_read(ev.id, sid, offset, size)
+                if buf is not None and len(buf) == size:
+                    shards[sid] = np.frombuffer(buf, dtype=np.uint8)
+                    have += 1
+                if have >= ev.data_shards:
+                    break
+            # the local siblings' reads of this recovery, taken together
+            trace.record_stage("ec.recover.local", local_s, bytes=local_bytes)
+            if have < ev.data_shards:
+                raise EcNotFoundError(
+                    f"volume {ev.id} shard {missing_shard}: only {have} "
+                    "shards reachable"
+                )
+            with trace.stage_span("ec.recover.decode", quiet=True):
+                rebuilt = codec.reconstruct(
+                    shards, data_only=missing_shard < ev.data_shards
+                )
+            return rebuilt[missing_shard].tobytes()
 
     # -- heartbeat (store.go:204-297) ----------------------------------------
     def _volume_message(self, v: Volume) -> dict:

@@ -1,0 +1,427 @@
+"""Stage spans inside the EC path (stats/trace.py ``stage_span``): what a
+seal, a degraded GET and a failing remote read leave in the tracer's ring,
+in its stage table (``ec_codec.stages`` of /status) and in a JAX profiler
+session — on the CPU, at a tiny size, with a host codec and with the Pallas
+kernel interpreted."""
+
+from __future__ import annotations
+
+import glob
+import os
+import socket
+import subprocess
+import sys
+import time
+import urllib.request
+
+import numpy as np
+import pytest
+
+from seaweedfs_tpu import operation
+from seaweedfs_tpu.ec import encoder
+from seaweedfs_tpu.ec.codec import NumpyCodec, TpuCodec
+from seaweedfs_tpu.ec.constants import shard_ext
+from seaweedfs_tpu.server.http_util import http_json
+from seaweedfs_tpu.server.master_server import MasterServer
+from seaweedfs_tpu.server.volume_server import VolumeServer
+from seaweedfs_tpu.shell import commands
+from seaweedfs_tpu.stats import trace
+from seaweedfs_tpu.stats.trace import RING, STAGES, assemble_tree
+from seaweedfs_tpu.storage.needle import Needle
+from seaweedfs_tpu.storage.store import Store
+from seaweedfs_tpu.util import retry
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LEGS = ("read", "dispatch", "fetch", "write")
+LOST = (0, 4, 9, 12)
+
+
+def free_port():
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    return port
+
+
+def delta(before: dict, after: dict, stage: str, field: str):
+    return (after.get(stage, {}).get(field, 0)
+            - before.get(stage, {}).get(field, 0))
+
+
+def tree_of(address: str, trace_id: str) -> list[dict]:
+    r = http_json("GET", f"http://{address}/debug/traces?trace={trace_id}")
+    return assemble_tree(r["spans"])
+
+
+def named(node: dict, name: str) -> list[dict]:
+    return [c for c in node["children"] if c["name"] == name]
+
+
+class DevNumpy(NumpyCodec):
+    """A host codec behind the device interface: the pipeline's four legs
+    run, no JAX needed."""
+
+    def device_put(self, data):
+        return data
+
+    def matmul_device(self, matrix, data):
+        return self.matmul(matrix, np.asarray(data))
+
+
+def make_codec(kind: str):
+    if kind == "numpy":
+        return NumpyCodec()
+    return TpuCodec(use_pallas=True, pallas_interpret=True)
+
+
+@pytest.fixture(scope="module", params=["numpy", "pallas-interpret"])
+def sealed(request, tmp_path_factory):
+    """One master and one volume server in this process; a 12 MiB volume
+    loaded, sealed through the shell's ``ec.encode`` with the stage table
+    snapshotted around it, then shards 0, 4, 9, 12 deleted."""
+    tmp = tmp_path_factory.mktemp("stages")
+    master = MasterServer(port=free_port(), node_timeout=30).start()
+    vs = VolumeServer([str(tmp)], port=free_port(), master_url=master.url,
+                      max_volume_count=4, pulse_seconds=0.3).start()
+    codec = vs.store._ec_codec = make_codec(request.param)
+    address = vs.store.public_url
+    try:
+        deadline = time.time() + 10
+        while not commands.CommandEnv(master=master.url).data_nodes():
+            assert time.time() < deadline, "volume server never registered"
+            time.sleep(0.05)
+        r = http_json(
+            "POST", f"http://{master.url}/vol/grow?collection=st&count=1"
+            "&replication=000")
+        assert r.get("count") == 1, r
+        rng = np.random.default_rng(24)
+        sizes = [int(rng.integers(300_000, 900_000)) for _ in range(20)]
+        a = operation.assign(master.url, count=len(sizes), collection="st")
+        fids = [a.fid] + [f"{a.fid}_{j}" for j in range(1, len(sizes))]
+        blobs = {}
+        for fid, size in zip(fids, sizes):
+            blobs[fid] = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+            operation.upload_data(a.url, fid, blobs[fid], compress=False)
+        vid = int(a.fid.split(",")[0])
+        base = vs.store.find_volume(vid).file_name()
+        dat_size = os.path.getsize(base + ".dat")
+        env = commands.CommandEnv(master=master.url)
+        RING.clear()
+        before = STAGES.snapshot()
+        commands.ec_encode(env, vid, delete_original=True)
+        after = STAGES.snapshot()
+        seal_span = next(
+            s for s in RING.snapshot(4096) if s["name"] == "ec.seal")
+        shard_size = os.path.getsize(base + shard_ext(1))
+        r = http_json(
+            "POST", f"http://{address}/admin/ec/delete_shards?volume={vid}"
+            f"&shards={','.join(map(str, LOST))}")
+        assert sorted(r["removed"]) == list(LOST)
+        yield {
+            "kind": request.param, "codec": codec, "vs": vs, "env": env,
+            "address": address, "vid": vid, "blobs": blobs,
+            "dat_size": dat_size, "shard_size": shard_size,
+            "before": before, "after": after, "seal_span": seal_span,
+        }
+    finally:
+        vs.stop()
+        master.stop()
+
+
+def test_one_seal_in_the_stage_table(sealed):
+    b, a = sealed["before"], sealed["after"]
+    assert delta(b, a, "ec.seal", "n") == 1
+    assert delta(b, a, "ec.seal", "bytes") == sealed["dat_size"]
+    assert delta(b, a, "ec.seal.ecx", "n") == 1
+    assert delta(b, a, "ec.seal.commit", "n") == 1
+    assert delta(b, a, "ec.seal.hash", "n") == 14
+    assert delta(b, a, "ec.seal.hash", "bytes") == 14 * sealed["shard_size"]
+    parts = sum(delta(b, a, f"ec.seal.{p}", "busy_s")
+                for p in ("pipeline", "ecx", "hash", "commit"))
+    assert 0 < parts <= delta(b, a, "ec.seal", "busy_s")
+    if sealed["kind"] == "numpy":
+        # a host codec keeps the serial loop: no pipeline, no legs
+        assert delta(b, a, "ec.seal.pipeline", "n") == 0
+        return
+    _, items = encoder.plan_encode(sealed["codec"], sealed["dat_size"])
+    assert len(items) >= 2
+    assert delta(b, a, "ec.seal.pipeline", "n") == 1
+    for leg in LEGS + ("h2d", "d2h"):
+        assert delta(b, a, f"ec.seal.{leg}", "n") == len(items), leg
+    assert delta(b, a, "ec.seal.read", "bytes") == sealed["dat_size"]
+    assert delta(b, a, "ec.seal.write", "bytes") == 14 * sealed["shard_size"]
+    assert delta(b, a, "ec.seal.d2h", "bytes") == 4 * sealed["shard_size"]
+    assert delta(b, a, "ec.seal.h2d", "bytes") == 10 * sealed["shard_size"]
+
+
+def test_status_serves_the_table_under_ec_codec(sealed):
+    served = http_json("GET", f"http://{sealed['address']}/status")
+    stages = served["ec_codec"]["stages"]
+    assert stages["ec.seal"]["n"] >= 1
+    assert set(stages["ec.seal"]) == {"n", "busy_s", "bytes"}
+    assert set(stages["ec.seal.commit"]) == {"n", "busy_s"}
+
+
+def test_a_seal_is_one_tree_under_its_admin_request(sealed):
+    roots = tree_of(sealed["address"], sealed["seal_span"]["trace_id"])
+    generate = [r for r in roots if r["name"].endswith("/admin/ec/generate")]
+    assert len(generate) == 1
+    (seal,) = named(generate[0], "ec.seal")
+    assert seal["tags"]["vid"] == sealed["vid"]
+    assert seal["service"] == "volume"
+    assert len(named(seal, "ec.seal.hash")) == 14
+    assert named(seal, "ec.seal.ecx") and named(seal, "ec.seal.commit")
+    if sealed["kind"] == "numpy":
+        return
+    # the reader, fetch and writer threads run in copies of the seal's
+    # context: their spans hang under the pipeline's
+    (pipeline,) = named(seal, "ec.seal.pipeline")
+    for leg in LEGS:
+        assert len(named(pipeline, f"ec.seal.{leg}")) >= 2, leg
+    # the link's legs hang under the leg that begins each: the staged
+    # input under its dispatch, the copy back under its fetch
+    assert named(named(pipeline, "ec.seal.dispatch")[0], "ec.seal.h2d")
+    assert named(named(pipeline, "ec.seal.fetch")[0], "ec.seal.d2h")
+
+
+def recovering_get(sealed) -> tuple[str, list[dict]]:
+    """GET needles until one recovers an interval; its trace id and tree."""
+    for fid, want in sealed["blobs"].items():
+        with urllib.request.urlopen(
+                f"http://{sealed['address']}/{fid}") as resp:
+            assert resp.read() == want
+            trace_id = resp.headers["X-Sweed-Trace-Id"]
+        roots = tree_of(sealed["address"], trace_id)
+        if len(roots) == 1 and named(roots[0], "ec.recover"):
+            return trace_id, roots
+    raise AssertionError("no needle had an interval on a lost data shard")
+
+
+def test_a_degraded_get_is_one_tree_down_to_the_launch(sealed):
+    launches = getattr(sealed["codec"], "launches", None)
+    launched = sum(launches.snapshot().values()) if launches else 0
+    before = STAGES.snapshot()
+    trace_id, (get,) = recovering_get(sealed)
+    after = STAGES.snapshot()
+    assert get["name"] == "GET /" and get["service"] == "volume"
+    # the ask for the lost shard comes first, then the recovery beside it
+    assert named(get, "ec.read.remote")
+    recover = named(get, "ec.recover")[0]
+    assert recover["tags"]["missing"] in LOST
+    assert recover["tags"]["bytes"] == recover["tags"]["size"] > 0
+    # one ask per lost sibling it met before it had ten shards
+    remote = named(recover, "ec.read.remote")
+    assert 1 <= len(remote) <= 3
+    assert {r["tags"]["sid"] for r in remote} <= set(LOST)
+    assert all(r["tags"]["failed"] == 3 for r in remote)
+    (local,) = named(recover, "ec.recover.local")
+    # ten shards are left, all here: each gave the interval's range
+    assert local["tags"]["bytes"] == 10 * recover["tags"]["size"]
+    (decode,) = named(recover, "ec.recover.decode")
+    # `weed shell trace <id>` prints the same tree
+    printed = commands.trace_collect(sealed["env"], trace_id)["tree"]
+    assert "volume GET /" in printed.splitlines()[0]
+    for name in ("ec.read.remote", "ec.recover", "ec.recover.local",
+                 "ec.recover.decode"):
+        assert name in printed, printed
+    if sealed["kind"] == "numpy":
+        assert not named(decode, "ec.codec.launch")  # no device, no launch
+        return
+    assert named(decode, "ec.codec.launch")
+    assert "ec.codec.launch" in printed
+    # one span per device round trip, as /status counts them
+    assert delta(before, after, "ec.codec.launch", "n") == (
+        sum(launches.snapshot().values()) - launched) > 0
+    assert delta(before, after, "ec.recover.decode", "n") == delta(
+        before, after, "ec.recover", "n")
+
+
+def test_the_kill_switch_records_nothing_and_status_has_no_stages(
+        sealed, monkeypatch):
+    monkeypatch.setenv("SWEED_TRACE", "0")
+    table, ring = STAGES.snapshot(), RING.stats()["added"]
+    for fid, want in list(sealed["blobs"].items())[:6]:
+        with urllib.request.urlopen(
+                f"http://{sealed['address']}/{fid}") as resp:
+            assert resp.read() == want  # degraded reads go on, unrecorded
+            assert resp.headers.get("X-Sweed-Trace-Id") is None
+    assert STAGES.snapshot() == table
+    assert RING.stats()["added"] == ring
+    served = http_json("GET", f"http://{sealed['address']}/status")
+    assert "stages" not in served["ec_codec"]
+    assert served["ec_codec"]["resolved"] is True
+
+
+def test_remote_reads_count_attempts_that_raised_and_the_back_off_slept(
+        tmp_path, monkeypatch):
+    # full jitter draws from [0, d]: take d itself, so the delays are known
+    monkeypatch.setattr(retry.random, "uniform", lambda lo, hi: hi)
+    store = Store([str(tmp_path)], ec_backend="numpy",
+                  remote_fetch_attempts=3, remote_fetch_backoff_s=0.002)
+    asked = []
+
+    def nobody_holds_it(vid, sid, offset, size):
+        asked.append(sid)
+        raise ConnectionError("no server holds this shard")
+
+    store.remote_shard_reader = nobody_holds_it
+    before = STAGES.snapshot()
+    calls = 4
+    for sid in range(calls):
+        assert store._remote_shard_read(7, sid, 0, 64) is None
+    after = STAGES.snapshot()
+    assert len(asked) == 3 * calls
+    assert delta(before, after, "ec.read.remote", "n") == calls
+    assert delta(before, after, "ec.read.remote", "failed") == 3 * calls
+    # two sleeps a call: base, then twice the base
+    slept = delta(before, after, "ec.read.remote", "slept_s")
+    assert slept == pytest.approx(calls * (0.002 + 0.004))
+    assert delta(before, after, "ec.read.remote", "busy_s") >= slept
+    # a reader that answers leaves a call with nothing failed or slept
+    store.remote_shard_reader = lambda vid, sid, offset, size: b"x" * size
+    assert store._remote_shard_read(7, 0, 0, 64) == b"x" * 64
+    last = STAGES.snapshot()
+    assert delta(after, last, "ec.read.remote", "n") == 1
+    assert delta(after, last, "ec.read.remote", "failed") == 0
+    # and a store no volume server wired asks nobody: no span
+    store.remote_shard_reader = None
+    assert store._remote_shard_read(7, 0, 0, 64) is None
+    assert delta(last, STAGES.snapshot(), "ec.read.remote", "n") == 0
+    store.close()
+
+
+def run_pipeline(write_s: dict, op: str) -> None:
+    def produce():
+        yield from range(4)
+
+    def consume(i):
+        time.sleep(write_s.get(i, 0.0))
+
+    encoder._overlap_pipeline(produce, lambda i: i, consume,
+                              fetch=lambda i: i, op=op)
+
+
+def test_a_slow_chunk_stage_logs_one_line_naming_it(monkeypatch):
+    lines = []
+    monkeypatch.setattr(
+        trace.glog, "warning", lambda fmt, *args: lines.append(fmt % args))
+    monkeypatch.setenv("SWEED_TRACE_SLOW_MS", "40")
+    run_pipeline({2: 0.06}, "ec.stalled")
+    assert len(lines) == 1, lines
+    assert lines[0].startswith("slow stage: ")
+    assert "ec.stalled.write" in lines[0] and "trace " in lines[0]
+    # the pipeline's own span, always as long as its slowest chunk, is
+    # quiet; and a run with no slow chunk logs nothing
+    assert "pipeline" not in lines[0]
+    run_pipeline({}, "ec.calm")
+    assert len(lines) == 1
+    # a plain request span keeps its line as it was
+    with trace.start_span("GET /slow", service="volume"):
+        time.sleep(0.05)
+    assert lines[1].startswith("slow request: volume GET /slow took ")
+
+
+def test_the_end_of_input_is_no_read_stage():
+    before = STAGES.snapshot()
+    run_pipeline({}, "ec.counted")
+    after = STAGES.snapshot()
+    for leg in LEGS:
+        assert delta(before, after, f"ec.counted.{leg}", "n") == 4, leg
+    assert delta(before, after, "ec.counted.pipeline", "n") == 1
+
+
+def test_record_stage_backdates_a_span_under_the_active_one():
+    RING.clear()
+    with trace.start_span("outer", service="volume") as outer:
+        trace.record_stage("ec.test.h2d", 0.25, bytes=1000)
+    h2d = next(s for s in RING.snapshot() if s["name"] == "ec.test.h2d")
+    assert h2d["parent_id"] == outer.span_id
+    assert h2d["service"] == "volume"  # a stage is in its parent's service
+    assert h2d["duration_ms"] == 250.0
+    assert h2d["start"] == pytest.approx(time.time() - 0.25, abs=0.05)
+    row = STAGES.snapshot()["ec.test.h2d"]
+    assert row["bytes"] >= 1000 and row["busy_s"] >= 0.25
+
+
+CHIPLESS = """
+import json, socket, sys, time, urllib.request
+from seaweedfs_tpu.server.master_server import MasterServer
+from seaweedfs_tpu.server.volume_server import VolumeServer
+
+def free_port():
+    s = socket.socket(); s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]; s.close(); return port
+
+master = MasterServer(port=free_port(), node_timeout=30).start()
+vs = VolumeServer([sys.argv[1]], port=free_port(), master_url=master.url,
+                  pulse_seconds=0.2).start()
+for _ in range(3):
+    with urllib.request.urlopen(f"http://{vs.store.public_url}/status") as r:
+        codec = json.load(r)["ec_codec"]
+    time.sleep(0.1)
+print(json.dumps({"jax": "jax" in sys.modules, "codec": codec}))
+vs.stop(); master.stop()
+"""
+
+
+def test_a_chipless_volume_server_polled_on_status_never_imports_jax(tmp_path):
+    env = {k: v for k, v in os.environ.items() if k != "SWEED_EC_BACKEND"}
+    env["PYTHONPATH"] = ROOT
+    r = subprocess.run([sys.executable, "-c", CHIPLESS, str(tmp_path)],
+                       env=env, capture_output=True, text=True, timeout=60)
+    assert r.returncode == 0, r.stderr[-2000:]
+    import json
+
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["jax"] is False
+    assert out["codec"]["resolved"] is False
+    assert out["codec"]["stages"] == {}  # tracing on, no stage run yet
+    assert out["codec"]["jax_platforms"] is None
+
+
+def test_a_profiler_session_holds_the_stages_on_its_own_clock(tmp_path):
+    """With JAX loaded, every stage is also a TraceAnnotation of the same
+    name: the program's spans sit in the ``.xplane.pb`` beside the device's
+    operations (here the CPU's), read as ``benchmark/tools/small_trace.py``
+    reads them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    store = Store([str(tmp_path / "v")], ec_backend="numpy")
+    os.makedirs(tmp_path / "v", exist_ok=True)
+    store.add_volume(3)
+    rng = np.random.default_rng(5)
+    for i in range(1, 6):
+        store.write_volume_needle(3, Needle(
+            cookie=1, id=i,
+            data=rng.integers(0, 256, 40_000, dtype=np.uint8).tobytes()))
+    store._ec_codec = DevNumpy()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        store.ec_encode_volume(3)
+    finally:
+        jax.profiler.stop_trace()
+    store.close()
+    (path,) = glob.glob(str(
+        tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events: dict[str, list[tuple[float, float]]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("ec."):
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    (seal,) = events["ec.seal"]
+    assert events["ec.seal.write"]
+    for name in ("ec.seal.pipeline", "ec.seal.read", "ec.seal.dispatch",
+                 "ec.seal.fetch", "ec.seal.d2h", "ec.seal.write",
+                 "ec.seal.ecx", "ec.seal.hash", "ec.seal.commit"):
+        for start, end in events[name]:
+            assert seal[0] <= start and end <= seal[1], name
+    assert len(events["ec.seal.hash"]) == 14

@@ -5,6 +5,7 @@ Synthetic stages with known busy times prove wall ≈ max(stage), not
 serial read→Encode→write loop (ec_encoder.go:162-192).
 """
 
+import os
 import time
 
 import numpy as np
@@ -50,10 +51,12 @@ def test_slow_writer_hides_reader_and_compute():
 
 
 def test_stats_on_real_encode(tmp_path):
-    """write_ec_files exposes pipeline_stats on a device-backed codec; use a
-    host-backed stub (matmul_device = sync numpy) so CI needs no TPU."""
+    """A device-backed codec's encode leaves its pipeline's stages in the
+    tracer's stage table, each leg once a chunk with the bytes it moved;
+    a host-backed stub (matmul_device = sync numpy) so CI needs no TPU."""
     from seaweedfs_tpu.ec import encoder
     from seaweedfs_tpu.ec.codec import NumpyCodec
+    from seaweedfs_tpu.stats import trace
 
     class DevNumpy(NumpyCodec):
         def device_put(self, data):
@@ -66,14 +69,26 @@ def test_stats_on_real_encode(tmp_path):
     rng = np.random.default_rng(3)
     with open(base + ".dat", "wb") as f:
         f.write(rng.integers(0, 256, 300_000, dtype=np.uint8).tobytes())
-    stats: dict = {}
+    codec = DevNumpy()
+    _, items = encoder.plan_encode(codec, 300_000, 8192, 1024)
+    before = trace.STAGES.snapshot()
     encoder.write_ec_files(
-        base, DevNumpy(), large_block_size=8192, small_block_size=1024,
-        pipeline_stats=stats,
+        base, codec, large_block_size=8192, small_block_size=1024,
     )
-    assert stats["wall_s"] > 0
-    assert {"read_busy_s", "compute_busy_s", "write_busy_s",
-            "efficiency"} <= set(stats)
+    after = trace.STAGES.snapshot()
+
+    def delta(name, field):
+        return after[name][field] - before.get(name, {}).get(field, 0)
+
+    assert delta("ec.seal.pipeline", "n") == 1
+    assert delta("ec.seal.pipeline", "busy_s") > 0
+    for leg in ("read", "dispatch", "fetch", "write", "h2d", "d2h"):
+        assert delta(f"ec.seal.{leg}", "n") == len(items), leg
+        assert delta(f"ec.seal.{leg}", "busy_s") > 0, leg
+    assert delta("ec.seal.read", "bytes") == 300_000
+    shard = os.path.getsize(base + ".ec00")
+    assert delta("ec.seal.write", "bytes") == 14 * shard
+    assert delta("ec.seal.d2h", "bytes") == 4 * shard
 
 
 def test_four_leg_overlap_hides_dispatch_behind_fetch():
