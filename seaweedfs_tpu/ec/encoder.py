@@ -18,8 +18,10 @@ function of the .dat contents.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
+import threading
 import time
 from typing import Optional
 
@@ -59,29 +61,98 @@ def _is_hole(fd: int, start: int, length: int) -> bool:
     return data_off >= start + length
 
 
-def _read_block_columns(
-    f, start: int, block_size: int, col_off: int, width: int, k: int, dat_size: int
-) -> tuple[np.ndarray, bool]:
-    """((k, width) matrix, has_data): column slice [col_off, col_off+width)
-    of each of the k consecutive block segments starting at ``start``;
-    zero-padded past EOF. Hole segments stay zeros without being read;
-    has_data=False means every segment was a hole (or past EOF), so callers
-    can skip the encode outright."""
-    out = np.zeros((k, width), dtype=np.uint8)
-    fd = f.fileno()
-    has_data = False
-    for i in range(k):
-        seg_start = start + i * block_size + col_off
-        if seg_start >= dat_size:
-            continue
-        n = min(width, dat_size - seg_start)
-        if _is_hole(fd, seg_start, n):
-            continue
-        f.seek(seg_start)
-        buf = f.read(n)
-        out[i, : len(buf)] = np.frombuffer(buf, dtype=np.uint8)
-        has_data = True
-    return out, has_data
+# most buffers one scatter read takes (Linux: 1024)
+_IOV_MAX = os.sysconf("SC_IOV_MAX")
+
+
+def _pread_into(fd: int, offset: int, views: list) -> None:
+    """Fill ``views`` — writable 1-D uint8 arrays, in file order — with the
+    consecutive bytes of ``fd`` from ``offset``: scatter reads
+    (``os.preadv``) of at most ``_IOV_MAX`` buffers, straight into place
+    and without moving the descriptor's position. A short count is not an
+    error: the read goes on from where it stopped. Whatever lies past EOF
+    is zeroed, because the buffers are recycled and hold an older chunk."""
+    views = list(views)
+    i = 0
+    while i < len(views):
+        got = os.preadv(fd, views[i : i + _IOV_MAX], offset)
+        if got == 0:  # EOF
+            break
+        offset += got
+        while i < len(views) and got >= len(views[i]):
+            got -= len(views[i])
+            i += 1
+        if got:
+            views[i] = views[i][got:]
+    for v in views[i:]:
+        v[:] = 0
+
+
+class _PoolClosed(Exception):
+    """The pipeline wants no more chunks: raised to a reader that asks for
+    (or waits for) a buffer after `_ChunkBuffers.close`."""
+
+
+# Chunk buffers one call may allocate: two, the one the reader fills and
+# the one the legs after it hold (double buffering). Of the up to eight
+# places of the overlap pipeline that can hold a chunk (four legs, four
+# queue slots) only these are ever occupied: the reader waits for a buffer
+# where it used to wait for a queue slot. Not more, by measurement (v5e
+# host, PERF.md §6 PR 25): a buffer's first touch costs as much as reading
+# it twice, every call, and a third chunk in flight is a third chunk
+# staged on the device (peak HBM 834 against 574 MiB) for no shorter seal.
+_POOL_BUFFERS = 2
+
+
+class _ChunkBuffers:
+    """One call's bounded pool of host buffers for (k, width) chunks.
+
+    `take` hands out a C-contiguous ``(k, width)`` view of a recycled
+    buffer, allocates while fewer than ``count`` exist, and otherwise
+    waits until `give` brings one back: the reader's backpressure, and the
+    bound on the host memory of a call's chunks. A buffer comes back
+    holding its last chunk and is NOT cleared, so whoever fills it writes
+    every byte. Each `take` leaves one stage in the tracer's table,
+    ``<op>.buf.new`` (allocated) or ``<op>.buf.wait`` (recycled;
+    ``busy_s`` is the wait), with the buffer's ``bytes``."""
+
+    def __init__(self, op: str, nbytes: int, count: int = _POOL_BUFFERS):
+        self._op = op
+        self._nbytes = nbytes  # of the largest chunk: any buffer fits any
+        self._unmade = count
+        self._free: list[np.ndarray] = []
+        self._cond = threading.Condition()
+        self._closed = False
+
+    def take(self, k: int, width: int) -> np.ndarray:
+        t0 = time.perf_counter()
+        with self._cond:
+            while not (self._free or self._unmade or self._closed):
+                self._cond.wait()
+            if self._closed:
+                raise _PoolClosed()
+            if self._free:
+                how, flat = "wait", self._free.pop()
+            else:
+                how, flat = "new", None
+                self._unmade -= 1
+        if flat is None:
+            flat = np.empty(self._nbytes, dtype=np.uint8)
+        trace.record_stage(f"{self._op}.buf.{how}",
+                           time.perf_counter() - t0, bytes=flat.nbytes)
+        return flat[: k * width].reshape(k, width)
+
+    def give(self, mat: np.ndarray) -> None:
+        """Nothing reads ``mat`` (a `take`) any more: recycle its buffer."""
+        with self._cond:
+            self._free.append(mat.base)
+            self._cond.notify()
+
+    def close(self) -> None:
+        """Release a reader that waits in `take`: the pipeline is ending."""
+        with self._cond:
+            self._closed = True
+            self._cond.notify_all()
 
 
 def _work_items(
@@ -175,44 +246,65 @@ def _region_fully_data(fd: int, start: int, length: int) -> bool:
     return hole_off >= start + length
 
 
-def _read_item(f, item, k: int, dat_size: int) -> tuple[np.ndarray, bool]:
-    """((k, width) matrix, has_data) for either item kind."""
+def _item_segments(fd: int, item, k: int, dat_size: int) -> list:
+    """Where a work item's data lies: ``(slot, offset, n)`` for each of its
+    block segments that holds any — ``n`` bytes at ``offset`` of the .dat.
+    Slot ``r * k + i`` of a "rows" item is row ``r`` of shard ``i``; a
+    "cols" item has one slot a shard. Segments that are filesystem holes or
+    lie past EOF are left out (they are zeros), so an empty list is a chunk
+    of zeros: it needs no buffer and no encode."""
+    dense = False
     if item[0] == "cols":
         _, start, block_size, col, width = item
-        return _read_block_columns(f, start, block_size, col, width, k, dat_size)
-    _, start, block_size, g = item
-    total = g * k * block_size
-    end = min(start + total, dat_size)
-    if start >= dat_size or _is_hole(f.fileno(), start, end - start):
-        return np.zeros((k, g * block_size), dtype=np.uint8), False
-    arr = np.zeros(total, dtype=np.uint8)
-    if _region_fully_data(f.fileno(), start, end - start):
-        # dense region (the common case): ONE sequential read
-        f.seek(start)
-        buf = f.read(end - start)
-        arr[: len(buf)] = np.frombuffer(buf, dtype=np.uint8)
+        spots = [(i, start + i * block_size + col, width) for i in range(k)]
     else:
-        # mixed data/holes (punched deletes in sealed volumes): per-block
+        _, start, block_size, g = item
+        end = min(start + g * k * block_size, dat_size)
+        if start >= dat_size or _is_hole(fd, start, end - start):
+            return []
+        # dense region (the common case): no lseek a segment. Mixed
+        # data/holes (punched deletes in sealed volumes): per-block
         # SEEK_DATA skips keep the kernel from zero-filling the holes
-        fd = f.fileno()
-        for seg in range(g * k):
-            seg_start = start + seg * block_size
-            if seg_start >= dat_size:
-                break
-            n = min(block_size, dat_size - seg_start)
-            if _is_hole(fd, seg_start, n):
-                continue
-            f.seek(seg_start)
-            buf = f.read(n)
-            arr[seg * block_size : seg * block_size + len(buf)] = (
-                np.frombuffer(buf, dtype=np.uint8)
-            )
-    mat = (
-        arr.reshape(g, k, block_size)
-        .transpose(1, 0, 2)
-        .reshape(k, g * block_size)
-    )
-    return np.ascontiguousarray(mat), True
+        dense = _region_fully_data(fd, start, end - start)
+        spots = [(s, start + s * block_size, block_size) for s in range(g * k)]
+    segments = []
+    for slot, offset, size in spots:
+        n = min(size, dat_size - offset)
+        if n > 0 and (dense or not _is_hole(fd, offset, n)):
+            segments.append((slot, offset, n))
+    return segments
+
+
+def _read_item(fd: int, item, segments: list, mat: np.ndarray) -> None:
+    """Fill ``mat``, the (k, width) matrix of one work item, in one pass:
+    each of ``segments`` (`_item_segments`) is read from the file straight
+    to where the kernel wants it, and what no segment covers is zeroed.
+
+    A "rows" item's segment ``r * k + i`` — block ``i`` of row ``r``,
+    ``block`` bytes of the .dat — lands in ``mat[i, r*block:(r+1)*block]``;
+    a "cols" item's segment ``i`` in ``mat[i]``. Neighbours in the file go
+    into one scatter read (`_pread_into`), so a dense region is still
+    walked once and in order. ``mat`` is a recycled buffer that holds an
+    older chunk: hole segments, segments past EOF and the tail of the one
+    that EOF cuts are zeroed here; nothing else is."""
+    k = mat.shape[0]
+    size = item[4] if item[0] == "cols" else item[2]
+    slots = mat.reshape(k, -1, size)  # slot s is slots[s % k, s // k]
+    wanted = {slot for slot, _, _ in segments}
+    for s in range(k * slots.shape[1]):
+        if s not in wanted:
+            slots[s % k, s // k][:] = 0
+    runs: list = []  # (offset, views): neighbours in the file, one read
+    end = None
+    for slot, offset, n in segments:
+        dst = slots[slot % k, slot // k]
+        dst[n:] = 0
+        if offset != end:
+            runs.append((offset, []))
+        runs[-1][1].append(dst[:n])
+        end = offset + n
+    for offset, views in runs:
+        _pread_into(fd, offset, views)
 
 
 def _depth_chunk(chunk: int, total_width: int, floor: int, depth: int = 8) -> int:
@@ -315,6 +407,16 @@ def write_ec_files(
     neighbouring chunks overlap — the reference's
     serial 256KB read→Encode→write loop (`ec_encoder.go:162-192`) turned into
     a pipeline sized for a TPU. Host-only codecs keep the serial loop.
+
+    Either way a chunk is read ONCE, each block of the .dat straight to
+    its place in a ``(k, width)`` matrix (`_read_item`: row ``i`` is the
+    chunk's columns of shard ``i``), into a buffer of a bounded per-call
+    pool (`_ChunkBuffers`). The pipeline's buffers belong to the reader
+    until a chunk is read, then travel with the chunk through dispatch
+    and fetch to the writer, which returns each to the pool once the ten
+    data rows are in the shard files (`_encode_pipelined`); the serial
+    loop consumes a chunk before it reads the next, so one buffer serves
+    it. A chunk of zeros (a hole, or past EOF) takes no buffer.
     """
     codec = codec or get_codec()
     k, m = codec.data_shards, codec.parity_shards
@@ -335,31 +437,39 @@ def write_ec_files(
             # the parity buffer is consumed (written out) before the next
             # chunk encodes, so one buffer serves the whole stream — a fresh
             # allocation per chunk pays first-touch page faults comparable
-            # to the native kernel's own runtime
+            # to the native kernel's own runtime. Likewise the chunk's own.
             parity_buf = None
+            buffers = _ChunkBuffers("ec.seal", _chunk_nbytes(items, k), count=1)
             with open(dat, "rb") as f:
+                fd = f.fileno()
                 for item in items:
                     faultpoints.fire("ec.encode.chunk", path=outputs[0].name)
                     width = _item_width(item)
-                    data, has_data = _read_item(f, item, k, dat_size)
-                    if not has_data or not data.any():
+                    segments = _item_segments(fd, item, k, dat_size)
+                    data = None
+                    if segments:
+                        data = buffers.take(k, width)
+                        _read_item(fd, item, segments, data)
+                    if data is None or not data.any():
                         # zeros encode to zeros: skip the matmul and leave
                         # holes in the shard files (sparse sealed volumes —
                         # preallocated space, punched deletes — stay sparse
                         # and cheap; the truncate below fixes trailing sizes)
                         for o in outputs:
                             o.seek(width, 1)
-                        continue
-                    if getattr(codec, "supports_out", False):
-                        if parity_buf is None or parity_buf.shape[1] != data.shape[1]:
-                            parity_buf = np.empty((m, data.shape[1]), dtype=np.uint8)
-                        parity = codec.encode(data, out=parity_buf)
                     else:
-                        parity = codec.encode(data)
-                    for i in range(k):
-                        outputs[i].write(data[i].tobytes())
-                    for j in range(m):
-                        outputs[k + j].write(parity[j].tobytes())
+                        if getattr(codec, "supports_out", False):
+                            if parity_buf is None or parity_buf.shape[1] != width:
+                                parity_buf = np.empty((m, width), dtype=np.uint8)
+                            parity = codec.encode(data, out=parity_buf)
+                        else:
+                            parity = codec.encode(data)
+                        for i in range(k):
+                            outputs[i].write(data[i].tobytes())
+                        for j in range(m):
+                            outputs[k + j].write(parity[j].tobytes())
+                    if data is not None:
+                        buffers.give(data)
         final = ec_shard_base_size(dat_size, k, large_block_size,
                                    small_block_size)
         for o in outputs:
@@ -369,16 +479,23 @@ def write_ec_files(
             o.close()
 
 
+def _chunk_nbytes(items, k: int) -> int:
+    """Bytes of the widest work item's (k, width) matrix."""
+    return k * max(map(_item_width, items), default=0)
+
+
 def _overlap_pipeline(produce, compute, consume, fetch=None,
                       stats: Optional[dict] = None,
-                      op: str = "ec.overlap") -> None:
+                      op: str = "ec.overlap",
+                      buffers: Optional[_ChunkBuffers] = None) -> None:
     """Four-stage overlap shared by encode and rebuild: a reader thread
-    runs `produce` (an iterator of host chunks), the main thread runs
-    `compute` (async device dispatch: H2D + kernel launch), a fetch thread
-    runs `fetch` (blocks on device results — the D2H leg), and a writer
-    thread runs `consume` (writes files). Bounded queues give ~2 chunks of
-    lookahead per edge; any stage failing drains the others so every
-    thread exits and the first error is re-raised.
+    runs `produce` (an iterator of read jobs, one a chunk: each returns
+    the host chunk), the main thread runs `compute` (async device
+    dispatch: H2D + kernel launch), a fetch thread runs `fetch` (blocks on
+    device results — the D2H leg), and a writer thread runs `consume`
+    (writes files). Bounded queues give ~2 chunks of lookahead per edge;
+    any stage failing drains the others so every thread exits and the
+    first error is re-raised.
 
     The dedicated fetch leg is what lets H2D of chunk i+1 ride the link
     concurrently with D2H of chunk i (the transfer directions are
@@ -387,13 +504,23 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     even with writes discarded. ``fetch=None`` degrades to the 3-stage
     form for host-only callers.
 
+    ``buffers`` is the pool the caller's chunks live in, if they do. The
+    reader owns a buffer from the moment `produce` takes it (between two
+    jobs, where it also waits for one: backpressure, as a full queue is)
+    until the job has filled it; from then on it belongs to the chunk, and
+    the caller's stage that is last to read it gives it back. The pipeline
+    itself only closes the pool, on the first error of any leg and when it
+    ends: a leg that failed gives nothing back, and a reader waiting for a
+    buffer must end as a reader waiting for a queue slot does.
+
     Every chunk passes each leg inside a stage span (stats/trace.py):
     ``<op>.read``, ``.dispatch``, ``.fetch`` and ``.write`` under
-    ``<op>.pipeline``, the call's wall. They time the stage callable alone,
-    not the queue blocking around it, and the callables count the bytes
-    they move against them (``trace.add_stage_bytes``); the threads run in
-    copies of the caller's context, so the spans of one seal are one tree.
-    The totals are served in /status (``ec_codec.stages``).
+    ``<op>.pipeline``, the call's wall. They time the stage callable alone
+    (the read job, not `produce`'s step to it), not the queue blocking
+    around it, and the callables count the bytes they move against them
+    (``trace.add_stage_bytes``); the threads run in copies of the caller's
+    context, so the spans of one seal are one tree. The totals are served
+    in /status (``ec_codec.stages``).
 
     A ``stats`` dict is this call's view of the same spans: per-stage BUSY
     time and wall time, plus ``efficiency`` = max(stage busy) / wall — 1.0
@@ -403,7 +530,6 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     only while tracing is on (``SWEED_TRACE``)."""
     import contextvars
     import queue
-    import threading
 
     # one-slot mid/out queues: enough lookahead for compute(i+1) to ride
     # the link concurrently with fetch(i), without tripling the chunks of
@@ -414,30 +540,29 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     errors: list[BaseException] = []
     busy = {"read": 0.0, "dispatch": 0.0, "fetch": 0.0, "write": 0.0}
 
-    def run_leg(leg, fn, got):
+    def fail(e: BaseException) -> None:
+        errors.append(e)
+        if buffers is not None:
+            buffers.close()
+
+    def run_leg(leg, fn, *got):
         """One chunk through one leg, inside that leg's stage span."""
         with trace.stage_span(f"{op}.{leg}") as span:
-            out = fn(got)
+            out = fn(*got)
         if span is not None:
             busy[leg] += span.duration  # each thread adds to its own leg
         return out
 
     def reader():
         try:
-            it = produce()
-            while True:
-                scope = trace.stage_span(f"{op}.read")
-                with scope as span:
-                    item = next(it, None)
-                    if item is None:
-                        scope.discard()  # the end of input is no chunk
-                if item is None or errors:
+            for job in produce():
+                if errors:
                     return
-                if span is not None:
-                    busy["read"] += span.duration
-                read_q.put(item)
+                read_q.put(run_leg("read", job))
+        except _PoolClosed:
+            pass  # the error that closed the pool is the one to raise
         except BaseException as e:  # surfaced after join
-            errors.append(e)
+            fail(e)
         finally:
             read_q.put(None)
 
@@ -449,7 +574,7 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
                     return
                 write_q.put(run_leg("fetch", fetch, got))
         except BaseException as e:
-            errors.append(e)
+            fail(e)
             while fetch_q.get() is not None:  # drain so the feeder unblocks
                 pass
         finally:
@@ -463,7 +588,7 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
                     return
                 run_leg("write", consume, got)
         except BaseException as e:
-            errors.append(e)
+            fail(e)
             while write_q.get() is not None:  # drain so the feeder unblocks
                 pass
 
@@ -494,13 +619,16 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
                 try:
                     mid_q.put(run_leg("dispatch", compute, got))
                 except BaseException as e:
-                    errors.append(e)
+                    fail(e)
         finally:
             mid_q.put(None)
             if ft is not None:
                 ft.join()  # fetcher forwards its None to write_q on exit
             wt.join()
-            # unblock the reader if it is mid-put (main loop exited early)
+            # unblock the reader if it waits for a buffer or is mid-put
+            # (main loop exited early)
+            if buffers is not None:
+                buffers.close()
             while rt.is_alive():
                 try:
                     read_q.get_nowait()
@@ -583,19 +711,31 @@ def _copy_back(op: str, out_dev, copy):
 
 
 def _encode_pipelined(dat, items, codec, outputs, dat_size: int) -> None:
+    """`write_ec_files` through the overlap pipeline. A chunk's buffer is
+    the reader's while it is filled, then the chunk's: dispatch stages it,
+    fetch awaits the parity (so the staged input has been consumed), and
+    the writer, last to read it, gives it back to the pool."""
     k, m = codec.data_shards, codec.parity_shards
     align = codec.alignment() if hasattr(codec, "alignment") else 1
+    buffers = _ChunkBuffers("ec.seal", _chunk_nbytes(items, k))
+
+    def read_chunk(fd, it, segments, data):
+        if data is not None:
+            _read_item(fd, it, segments, data)
+        trace.add_stage_bytes(_item_dat_bytes(it, k, dat_size))
+        return _item_width(it), data
 
     def produce():
         with open(dat, "rb") as f:
+            fd = f.fileno()
             for it in items:
-                data, has_data = _read_item(f, it, k, dat_size)
-                trace.add_stage_bytes(_item_dat_bytes(it, k, dat_size))
-                yield (_item_width(it), data, has_data)
+                segments = _item_segments(fd, it, k, dat_size)
+                data = buffers.take(k, _item_width(it)) if segments else None
+                yield functools.partial(read_chunk, fd, it, segments, data)
 
     def compute(got):
-        width, data, has_data = got
-        if not has_data or not data.any():
+        width, data = got
+        if data is None or not data.any():
             return width, data, None  # zero chunk: parity is zeros, skip device
         piece = data
         if width % align:
@@ -635,18 +775,21 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int) -> None:
         if parity is None:
             for o in outputs:  # keep sparse regions sparse (holes)
                 o.seek(width, 1)
-            return
-        for i in range(k):
-            outputs[i].write(data[i, :width].tobytes())
-        for j in range(m):
-            # parity[j] indexing (not parity[j, ...]) so both a 2-D array
-            # and the row list from the parallel fetch work here
-            outputs[k + j].write(parity[j][:width].tobytes())
-        trace.add_stage_bytes((k + m) * width)
+        else:
+            for i in range(k):
+                outputs[i].write(data[i, :width].tobytes())
+            for j in range(m):
+                # parity[j] indexing (not parity[j, ...]) so both a 2-D array
+                # and the row list from the parallel fetch work here
+                outputs[k + j].write(parity[j][:width].tobytes())
+            trace.add_stage_bytes((k + m) * width)
+        if data is not None:
+            buffers.give(data)
 
     h2d = _StagedWatch("ec.seal")
     try:
-        _overlap_pipeline(produce, compute, consume, fetch=fetch, op="ec.seal")
+        _overlap_pipeline(produce, compute, consume, fetch=fetch,
+                          op="ec.seal", buffers=buffers)
     finally:
         h2d.close()
         fetch_pool.shutdown(wait=True)
@@ -758,48 +901,65 @@ def _rebuild_rows(codec, present_ids: list[int], missing: list[int]) -> np.ndarr
 def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
     """Overlap disk reads, H2D staging + device matmul, and shard writes —
     the encode pipeline's shape applied to rebuild (the serial
-    read→reconstruct→write loop leaves the device idle during IO)."""
+    read→reconstruct→write loop leaves the device idle during IO).
+
+    Row ``r`` of a chunk's ``(k, padded)`` buffer is read straight from the
+    ``r``-th of the first k present shards. The buffer is not carried past
+    the device, so the fetch leg gives it back to the pool once the
+    chunk's RESULT is ready and copied back, never at ``device_put``
+    (and not before the copy back: a buffer given earlier is a third chunk
+    staged on the device while the copy still runs): JAX keeps a host
+    array immutable until it is transferred, and on the CPU platform the
+    staged input may be the numpy memory itself."""
     k = codec.data_shards
     present_ids = sorted(ins)
     first_k = present_ids[:k]
     rows = _rebuild_rows(codec, present_ids, missing)
     align = codec.alignment() if hasattr(codec, "alignment") else 1
+    widest = min(chunk, shard_size)
+    buffers = _ChunkBuffers("ec.rebuild", k * -(-widest // align) * align)
+
+    def read_chunk(pos, width, held, buf):
+        if buf is None:
+            return width, None
+        buf[:, width:] = 0  # the alignment tail: zeros encode to zeros
+        for row, sid in enumerate(first_k):
+            if row in held:
+                _pread_into(ins[sid].fileno(), pos, [buf[row, :width]])
+                trace.add_stage_bytes(width)
+            else:
+                buf[row, :width] = 0  # a hole: not read, and not left stale
+        return width, buf
 
     def produce():
         pos = 0
         while pos < shard_size:
             width = min(chunk, shard_size - pos)
-            padded = -(-width // align) * align  # zeros encode to zeros
-            buf = np.zeros((k, padded), dtype=np.uint8)
-            has_data = False
-            for row, sid in enumerate(first_k):
-                if _is_hole(ins[sid].fileno(), pos, width):
-                    continue
-                ins[sid].seek(pos)
-                buf[row, :width] = np.frombuffer(
-                    ins[sid].read(width), dtype=np.uint8
-                )
-                trace.add_stage_bytes(width)
-                has_data = True
-            yield (width, buf, has_data)
+            held = [row for row, sid in enumerate(first_k)
+                    if not _is_hole(ins[sid].fileno(), pos, width)]
+            buf = buffers.take(k, -(-width // align) * align) if held else None
+            yield functools.partial(read_chunk, pos, width, held, buf)
             pos += width
 
     def compute(got):
-        width, buf, has_data = got
-        if not has_data or not buf.any():
-            return width, None  # zeros reconstruct to zeros
+        width, buf = got
+        if buf is None or not buf.any():
+            return width, buf, None  # zeros reconstruct to zeros
         t_put = time.perf_counter()
         staged = codec.device_put(buf)
         h2d.watch(staged, t_put)
         trace.add_stage_bytes(buf.nbytes)
-        return width, codec.matmul_device(rows, staged)
+        return width, buf, codec.matmul_device(rows, staged)
 
     def fetch(got):
-        width, out_dev = got
-        if out_dev is None:
-            return width, None
-        # blocking D2H leg
-        return width, _copy_back("ec.rebuild", out_dev, np.asarray)
+        width, buf, out_dev = got
+        out = None
+        if out_dev is not None:
+            # blocking D2H leg
+            out = _copy_back("ec.rebuild", out_dev, np.asarray)
+        if buf is not None:
+            buffers.give(buf)
+        return width, out
 
     def consume(got):
         width, out = got
@@ -814,7 +974,7 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
     h2d = _StagedWatch("ec.rebuild")
     try:
         _overlap_pipeline(produce, compute, consume, fetch=fetch,
-                          op="ec.rebuild")
+                          op="ec.rebuild", buffers=buffers)
     finally:
         h2d.close()
 

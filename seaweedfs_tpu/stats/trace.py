@@ -377,13 +377,12 @@ class _StageScope(_SpanScope):
     """A span's scope that also enters the profiler's annotation of the
     same name, and closes into the stage table."""
 
-    __slots__ = ("_quiet", "_annotated", "_discarded")
+    __slots__ = ("_quiet", "_annotated")
 
     def __init__(self, span: Optional[Span], quiet: bool):
         super().__init__(span)
         self._quiet = quiet
         self._annotated = None
-        self._discarded = False
 
     def __enter__(self) -> Optional[Span]:
         if self.span is not None:
@@ -398,14 +397,8 @@ class _StageScope(_SpanScope):
         if self._annotated is not None:
             self._annotated.__exit__(exc_type, exc, tb)
 
-    def discard(self) -> None:
-        """The stage found nothing to do (a reader at the end of its
-        input): leave the scope without a row, a ring entry or a line."""
-        self._discarded = True
-
     def _finish(self) -> None:
-        if not self._discarded:
-            _finish_stage(self.span, self._quiet)
+        _finish_stage(self.span, self._quiet)
 
 
 def stage_span(name: str, quiet: bool = False, **tags) -> _StageScope:

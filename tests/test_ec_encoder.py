@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu.ec import encoder, locate
-from seaweedfs_tpu.ec.codec import CpuCodec
+from seaweedfs_tpu.ec.codec import CpuCodec, NumpyCodec
 from seaweedfs_tpu.ec.constants import shard_ext
 from seaweedfs_tpu.storage import idx
 from seaweedfs_tpu.storage.needle import VERSION3, Needle
@@ -222,3 +222,300 @@ def test_zero_tail_padding_matches_reference_semantics(tmp_path):
     assert s9[:100] == payload[900:1000]
     # row 1 shard 9 covers dat[1900:2000) → 1792-1900 < 0 → all zeros
     assert s9[100:200] == b"\x00" * 100
+
+
+# -- the reader leg: every block read once, to its place in a recycled buffer --
+K = 10
+BLK = 4096  # one filesystem block, so that a punched segment is a real hole
+
+
+class DevNumpy(NumpyCodec):
+    """A host codec behind the device interface: the overlap pipeline and
+    its buffer pool run, no JAX needed. ``align`` pads a rebuild's chunks."""
+
+    align = 1
+
+    def alignment(self):
+        return self.align
+
+    def device_put(self, data):
+        return data
+
+    def matmul_device(self, matrix, data):
+        return self.matmul(matrix, np.asarray(data))
+
+
+def old_read_item(f, item, k, dat_size):
+    """The reader this PR replaced (read, zero-filled copy, transpose; a
+    row at a time for "cols"), kept as the reference for the new one."""
+    fd = f.fileno()
+    if item[0] == "cols":
+        _, start, block_size, col, width = item
+        out = np.zeros((k, width), dtype=np.uint8)
+        has_data = False
+        for i in range(k):
+            seg_start = start + i * block_size + col
+            if seg_start >= dat_size:
+                continue
+            n = min(width, dat_size - seg_start)
+            if encoder._is_hole(fd, seg_start, n):
+                continue
+            f.seek(seg_start)
+            buf = f.read(n)
+            out[i, : len(buf)] = np.frombuffer(buf, dtype=np.uint8)
+            has_data = True
+        return out, has_data
+    _, start, block_size, g = item
+    total = g * k * block_size
+    end = min(start + total, dat_size)
+    if start >= dat_size or encoder._is_hole(fd, start, end - start):
+        return np.zeros((k, g * block_size), dtype=np.uint8), False
+    arr = np.zeros(total, dtype=np.uint8)
+    for seg in range(g * k):
+        seg_start = start + seg * block_size
+        if seg_start >= dat_size:
+            break
+        n = min(block_size, dat_size - seg_start)
+        if encoder._is_hole(fd, seg_start, n):
+            continue
+        f.seek(seg_start)
+        buf = f.read(n)
+        arr[seg * block_size : seg * block_size + len(buf)] = (
+            np.frombuffer(buf, dtype=np.uint8))
+    mat = arr.reshape(g, k, block_size).transpose(1, 0, 2)
+    return np.ascontiguousarray(mat.reshape(k, g * block_size)), True
+
+
+def reference_shards(dat: bytes, large: int, small: int) -> list[bytes]:
+    """All 14 shards from the .dat in memory: the striping rule spelt out,
+    parity by NumpyCodec over whole shards."""
+    size = encoder.ec_shard_base_size(len(dat), K, large, small)
+    data = np.zeros((K, size), dtype=np.uint8)
+    src = np.frombuffer(dat, dtype=np.uint8)
+    pos = out = 0
+    while len(dat) - pos > large * K:
+        for i in range(K):
+            data[i, out : out + large] = src[pos + i * large :][:large]
+        pos, out = pos + large * K, out + large
+    while pos < len(dat):
+        for i in range(K):
+            seg = src[pos + i * small :][:small]
+            data[i, out : out + len(seg)] = seg
+        pos, out = pos + small * K, out + small
+    parity = NumpyCodec().encode(data)
+    return [bytes(r) for r in data] + [bytes(r) for r in parity]
+
+
+def write_dat(path: str, runs: list, seed: int = 7) -> bytes:
+    """A .dat of ``runs``: ("data", n) random bytes, ("hole", n) a seek the
+    filesystem keeps as a hole. Returns the bytes a reader must see."""
+    rng = np.random.default_rng(seed)
+    image = bytearray()
+    with open(path, "wb") as f:
+        for kind, n in runs:
+            if kind == "hole":
+                f.seek(n, 1)
+                image += bytes(n)
+            else:
+                blob = rng.integers(1, 256, n, dtype=np.uint8).tobytes()
+                f.write(blob)
+                image += blob
+        f.truncate(len(image))
+    return bytes(image)
+
+
+ROW = K * BLK
+# name -> (runs, large block, small block, chunk_bytes)
+READER_CASES = {
+    # rows of four blocks a chunk, the file ends on a row's edge
+    "dense": ([("data", 12 * ROW)], 64 * BLK, BLK, 4 * BLK),
+    # the last chunk ends in the middle of a block, three blocks into a row
+    "ends-mid-row": ([("data", 9 * ROW + 3 * BLK + 1234)], 64 * BLK, BLK,
+                     4 * BLK),
+    # punched deletes: holes of whole blocks inside regions that hold data
+    "punched": ([("data", 3 * BLK), ("hole", 5 * BLK), ("data", 9 * BLK),
+                 ("hole", 2 * BLK), ("data", ROW + 7 * BLK), ("hole", ROW),
+                 ("data", 2 * ROW + 11)], 64 * BLK, BLK, 2 * BLK),
+    # whole chunks that are one hole (no buffer, no encode), data after
+    "all-hole-regions": ([("data", ROW), ("hole", 8 * ROW), ("data", 2 * ROW),
+                          ("hole", 4 * ROW + 5 * BLK)], 64 * BLK, BLK,
+                         2 * BLK),
+    # blocks wider than the chunk, large and small: "cols" items, with a
+    # large row that is one hole, holes that take whole segments of a
+    # small row and a tail past EOF
+    "cols": ([("data", 4 * ROW), ("hole", 4 * ROW), ("data", 4 * ROW),
+              ("hole", 8 * BLK), ("data", 9 * BLK),
+              ("hole", 4 * BLK), ("data", 4 * BLK + 99)], 4 * BLK, 2 * BLK,
+             BLK),
+}
+
+
+@pytest.fixture()
+def poisoned(monkeypatch):
+    """Every buffer the pool hands out is filled with 0xFF first, new or
+    recycled: a byte the reader fails to write shows in the shards."""
+    take = encoder._ChunkBuffers.take
+
+    def poisoned_take(self, k, width):
+        mat = take(self, k, width)
+        mat.base[:] = 0xFF
+        return mat
+
+    monkeypatch.setattr(encoder._ChunkBuffers, "take", poisoned_take)
+
+
+@pytest.mark.parametrize("codec_cls", [NumpyCodec, DevNumpy],
+                         ids=["serial", "pipelined"])
+@pytest.mark.parametrize("case", sorted(READER_CASES))
+def test_seal_from_poisoned_buffers_matches_the_reference(
+        tmp_path, poisoned, case, codec_cls):
+    runs, large, small, chunk = READER_CASES[case]
+    base = str(tmp_path / "v")
+    image = write_dat(base + ".dat", runs)
+    codec = codec_cls()
+    _, items = encoder.plan_encode(codec, len(image), large, small, chunk)
+    kinds = {it[0] for it in items}
+    assert kinds == ({"cols"} if case == "cols" else {"rows"}), kinds
+    assert len(items) > encoder._POOL_BUFFERS  # buffers do come back
+    encoder.write_ec_files(base, codec, large, small, chunk_bytes=chunk)
+    want = reference_shards(image, large, small)
+    for sid in range(14):
+        with open(base + shard_ext(sid), "rb") as f:
+            assert f.read() == want[sid], f"{case}: shard {sid} differs"
+
+
+@pytest.mark.parametrize("case", sorted(READER_CASES))
+def test_two_volumes_through_one_buffer_leave_nothing_stale(tmp_path, case):
+    """The serial loop's shape, twice through ONE pool of one buffer: first
+    a dense volume (so the buffer is full of data), then the case's."""
+    runs, large, small, chunk = READER_CASES[case]
+    size = sum(n for _, n in runs)
+    first, second = str(tmp_path / "a.dat"), str(tmp_path / "b.dat")
+    write_dat(first, [("data", size)], seed=1)
+    write_dat(second, runs, seed=2)
+    _, items = encoder.plan_encode(NumpyCodec(), size, large, small, chunk)
+    pool = encoder._ChunkBuffers("ec.test", encoder._chunk_nbytes(items, K),
+                                 count=1)
+    no_data = 0
+    for path in (first, second):
+        with open(path, "rb") as f:
+            fd = f.fileno()
+            for item in items:
+                want, has_data = old_read_item(f, item, K, size)
+                segments = encoder._item_segments(fd, item, K, size)
+                assert bool(segments) == has_data, item
+                if not segments:
+                    no_data += 1
+                    continue
+                mat = pool.take(K, encoder._item_width(item))
+                encoder._read_item(fd, item, segments, mat)
+                assert mat.flags.c_contiguous and mat.shape == want.shape
+                assert np.array_equal(mat, want), item
+                pool.give(mat)
+    assert (no_data > 0) == (case in ("all-hole-regions", "cols")), no_data
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+def test_rebuild_from_poisoned_buffers_pads_the_last_chunk(
+        tmp_path, poisoned, sparse):
+    """A device codec whose launches want 256-byte multiples: every chunk's
+    [width:padded] tail, and a sparse volume's hole rows, must read zeros."""
+    runs = ([("data", ROW + 77), ("hole", 6 * ROW), ("data", 3 * ROW + 5)]
+            if sparse else [("data", 10 * ROW + 1001)])
+    base = str(tmp_path / "v")
+    image = write_dat(base + ".dat", runs)
+    codec = DevNumpy()
+    encoder.write_ec_files(base, codec, 64 * BLK, BLK, chunk_bytes=2 * BLK)
+    want = reference_shards(image, 64 * BLK, BLK)
+    gone = (0, 4, 9, 12)
+    for sid in gone:
+        os.remove(base + shard_ext(sid))
+    codec.align = 256
+    # 5000 is no multiple of 256, nor is the last chunk's width
+    assert sorted(encoder.rebuild_ec_files(base, codec, chunk_bytes=5000)) == (
+        list(gone))
+    for sid in gone:
+        with open(base + shard_ext(sid), "rb") as f:
+            assert f.read() == want[sid], f"shard {sid} differs"
+
+
+def test_short_reads_are_read_on_not_left_stale(tmp_path, poisoned, monkeypatch):
+    """A scatter read may return fewer bytes than asked, anywhere: the
+    reader goes on from there, across buffers, until EOF."""
+    preadv = os.preadv
+    calls = []
+
+    def short_preadv(fd, views, offset):
+        want = sum(len(v) for v in views)
+        cut = max(1, min(want, 1000 + 7 * len(calls)))  # never a whole block
+        calls.append(cut)
+        part, left = [], cut
+        for v in views:
+            part.append(v[:left])
+            left -= len(part[-1])
+            if not left:
+                break
+        return preadv(fd, part, offset)
+
+    monkeypatch.setattr(encoder.os, "preadv", short_preadv)
+    base = str(tmp_path / "v")
+    image = write_dat(base + ".dat", [("data", 2 * ROW + 3 * BLK + 17)])
+    codec = DevNumpy()
+    encoder.write_ec_files(base, codec, 64 * BLK, BLK, chunk_bytes=2 * BLK)
+    want = reference_shards(image, 64 * BLK, BLK)
+    os.remove(base + shard_ext(3))
+    os.remove(base + shard_ext(11))
+    encoder.rebuild_ec_files(base, codec, chunk_bytes=3 * BLK)
+    assert len(calls) > 14 * 3 * BLK // 2000
+    for sid in range(14):
+        with open(base + shard_ext(sid), "rb") as f:
+            assert f.read() == want[sid], f"shard {sid} differs"
+
+
+@pytest.mark.parametrize("leg", ["read", "dispatch", "fetch", "write"])
+def test_an_error_in_any_leg_ends_a_call_whose_reader_waits_for_a_buffer(leg):
+    """Nobody gives a buffer back, so after `_POOL_BUFFERS` chunks the reader
+    waits in `take`; the leg then fails on the last chunk it is handed. The
+    call must end with that error, not hang."""
+    import threading
+    import time
+
+    last = encoder._POOL_BUFFERS - 1
+    buffers = encoder._ChunkBuffers("ec.stuck", 64)
+    waiting = threading.Event()
+    take = buffers.take
+
+    def produce():
+        for i in range(20):
+            if i > last:
+                waiting.set()  # the next take can only wait
+            buf = take(1, 64)
+            yield lambda i=i, buf=buf: fail_in("read", i)
+
+    def fail_in(here, i):
+        if here == leg and i == last:
+            if leg != "read":
+                assert waiting.wait(10)
+                time.sleep(0.05)  # let the reader reach the wait
+            raise RuntimeError(f"injected in {leg}")
+        return i
+
+    result: list = []
+
+    def run():
+        try:
+            encoder._overlap_pipeline(
+                produce,
+                lambda i: fail_in("dispatch", i),
+                lambda i: fail_in("write", i),
+                fetch=lambda i: fail_in("fetch", i),
+                op="ec.stuck", buffers=buffers)
+            result.append("no error")
+        except RuntimeError as e:
+            result.append(str(e))
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(timeout=20)
+    assert not t.is_alive(), f"pipeline hung on an error in {leg}"
+    assert result == [f"injected in {leg}"]

@@ -293,7 +293,8 @@ def test_remote_reads_count_attempts_that_raised_and_the_back_off_slept(
 
 def run_pipeline(write_s: dict, op: str) -> None:
     def produce():
-        yield from range(4)
+        for i in range(4):
+            yield lambda i=i: i
 
     def consume(i):
         time.sleep(write_s.get(i, 0.0))
@@ -425,3 +426,109 @@ def test_a_profiler_session_holds_the_stages_on_its_own_clock(tmp_path):
         for start, end in events[name]:
             assert seal[0] <= start and end <= seal[1], name
     assert len(events["ec.seal.hash"]) == 14
+
+
+# -- the reader's buffer pool: ec.<op>.buf.new / ec.<op>.buf.wait ------------
+def test_a_seal_takes_one_buffer_a_chunk_that_carries_data(sealed):
+    b, a = sealed["before"], sealed["after"]
+    _, items = encoder.plan_encode(sealed["codec"], sealed["dat_size"])
+    taken = (delta(b, a, "ec.seal.buf.new", "n")
+             + delta(b, a, "ec.seal.buf.wait", "n"))
+    assert taken == len(items)  # a dense volume: every chunk carries data
+    assert 1 <= delta(b, a, "ec.seal.buf.new", "n") <= encoder._POOL_BUFFERS
+    if sealed["kind"] == "numpy":
+        # the serial loop reads each chunk into its one buffer
+        assert delta(b, a, "ec.seal.buf.new", "n") == 1
+        return
+    assert taken == delta(b, a, "ec.seal.read", "n")
+    # the stages hang beside the read spans, not inside them
+    roots = tree_of(sealed["address"], sealed["seal_span"]["trace_id"])
+    (generate,) = [r for r in roots if r["name"].endswith("/admin/ec/generate")]
+    (pipeline,) = named(named(generate, "ec.seal")[0], "ec.seal.pipeline")
+    assert named(pipeline, "ec.seal.buf.new")
+    assert not any(named(read, "ec.seal.buf.new")
+                   for read in named(pipeline, "ec.seal.read"))
+
+
+def pooled_seal(tmp_path, monkeypatch, chunks: int, hole_chunks: int,
+                write_s: float = 0.0):
+    """Seal a volume of ``chunks`` chunks (the last ``hole_chunks`` of them
+    one hole) through the pipeline with a host codec behind the device
+    interface; the stage table's delta and the most buffers ever out."""
+    blk = 4096
+    base = str(tmp_path / "1")
+    rng = np.random.default_rng(25)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(1, 256, (chunks - hole_chunks) * 20 * blk,
+                             dtype=np.uint8).tobytes())
+        f.truncate(chunks * 20 * blk)
+    out = {"live": 0, "peak": 0}
+    take, give = encoder._ChunkBuffers.take, encoder._ChunkBuffers.give
+
+    def counted_take(self, k, width):
+        mat = take(self, k, width)
+        out["live"] += 1
+        out["peak"] = max(out["peak"], out["live"])
+        return mat
+
+    def counted_give(self, mat):
+        time.sleep(write_s)  # a slow writer: the reader runs out of buffers
+        out["live"] -= 1
+        give(self, mat)
+
+    monkeypatch.setattr(encoder._ChunkBuffers, "take", counted_take)
+    monkeypatch.setattr(encoder._ChunkBuffers, "give", counted_give)
+    codec = DevNumpy()
+    _, items = encoder.plan_encode(codec, chunks * 20 * blk, 1 << 30, blk,
+                                   2 * blk)
+    assert len(items) == chunks and {it[0] for it in items} == {"rows"}
+    before = STAGES.snapshot()
+    encoder.write_ec_files(base, codec, 1 << 30, blk, chunk_bytes=2 * blk)
+    after = STAGES.snapshot()
+    return out, lambda stage, field: delta(before, after, stage, field)
+
+
+def test_the_pool_never_holds_more_than_its_size_and_recycles_the_rest(
+        tmp_path, monkeypatch):
+    out, d = pooled_seal(tmp_path, monkeypatch, chunks=14, hole_chunks=3)
+    assert d("ec.seal.read", "n") == 14
+    # a chunk that is one hole takes no buffer: 11 carry data
+    assert d("ec.seal.buf.new", "n") + d("ec.seal.buf.wait", "n") == 11
+    assert 1 <= d("ec.seal.buf.new", "n") <= encoder._POOL_BUFFERS
+    assert out["peak"] <= encoder._POOL_BUFFERS and out["live"] == 0
+    assert d("ec.seal.buf.wait", "bytes") == (
+        d("ec.seal.buf.wait", "n") * 10 * 2 * 4096)
+
+
+def test_the_wait_for_a_buffer_is_outside_the_read_stage(tmp_path, monkeypatch):
+    write_s = 0.03
+    out, d = pooled_seal(tmp_path, monkeypatch, chunks=12, hole_chunks=0,
+                         write_s=write_s)
+    waits = d("ec.seal.buf.wait", "n")
+    assert d("ec.seal.buf.new", "n") == encoder._POOL_BUFFERS
+    assert waits == 12 - encoder._POOL_BUFFERS
+    # the writer gives one buffer back every write_s: the reader waited
+    # about that long for each, and none of it is in ec.seal.read
+    assert d("ec.seal.buf.wait", "busy_s") >= 0.7 * write_s * waits
+    assert d("ec.seal.read", "busy_s") < 0.5 * d("ec.seal.buf.wait", "busy_s")
+    assert out["peak"] == encoder._POOL_BUFFERS
+
+
+def test_a_rebuild_recycles_its_buffers_at_the_fetch_leg(tmp_path):
+    blk = 4096
+    base = str(tmp_path / "1")
+    rng = np.random.default_rng(26)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(1, 256, 240 * blk, dtype=np.uint8).tobytes())
+    codec = DevNumpy()
+    encoder.write_ec_files(base, codec, 1 << 30, blk, chunk_bytes=2 * blk)
+    for sid in LOST:
+        os.remove(base + shard_ext(sid))
+    before = STAGES.snapshot()
+    encoder.rebuild_ec_files(base, codec, chunk_bytes=2 * blk)
+    after = STAGES.snapshot()
+    chunks = delta(before, after, "ec.rebuild.read", "n")
+    assert chunks == 12
+    new = delta(before, after, "ec.rebuild.buf.new", "n")
+    assert 1 <= new <= encoder._POOL_BUFFERS
+    assert new + delta(before, after, "ec.rebuild.buf.wait", "n") == chunks

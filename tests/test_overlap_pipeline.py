@@ -5,6 +5,7 @@ Synthetic stages with known busy times prove wall ≈ max(stage), not
 serial read→Encode→write loop (ec_encoder.go:162-192).
 """
 
+import functools
 import os
 import time
 
@@ -16,10 +17,13 @@ from seaweedfs_tpu.ec.encoder import _overlap_pipeline
 def _run(n_items, t_read, t_compute, t_write):
     stats: dict = {}
 
-    def produce():
+    def read(i):
+        time.sleep(t_read)
+        return i
+
+    def produce():  # one read job a chunk
         for i in range(n_items):
-            time.sleep(t_read)
-            yield i
+            yield functools.partial(read, i)
 
     def compute(x):
         time.sleep(t_compute)
@@ -99,7 +103,8 @@ def test_four_leg_overlap_hides_dispatch_behind_fetch():
     n, tc, tf = 8, 0.02, 0.06
 
     def produce():
-        yield from range(n)
+        for i in range(n):
+            yield lambda i=i: i
 
     def compute(x):
         time.sleep(tc)
@@ -121,7 +126,8 @@ def test_four_leg_overlap_hides_dispatch_behind_fetch():
 
 def test_fetch_leg_error_propagates():
     def produce():
-        yield from range(5)
+        for i in range(5):
+            yield lambda i=i: i
 
     def compute(x):
         return x
