@@ -78,6 +78,11 @@ def cmd_volume(args):
     from .server.volume_server import VolumeServer
 
     sec = _security_conf()
+    if args.ec_chip is not None:
+        # before the server resolves its codec, which opens the backend
+        from .util import jaxenv
+
+        jaxenv.claim_chip(args.ec_chip)
     dirs = args.dir.split(",")
     vs = VolumeServer(
         dirs,
@@ -1261,6 +1266,10 @@ def main(argv=None):
                    choices=["memory", "dense", "sqlite", "sorted"],
                    help="needle map kind (weed volume -index memory|leveldb)")
     v.add_argument("-ec.backend", dest="ec_backend", default="", choices=["", "tpu", "cpu", "numpy", "mesh"])
+    v.add_argument("-ec.chip", dest="ec_chip", type=int, default=None,
+                   help="the one chip of this host that is this server's "
+                        "(0-based): several volume servers on a multi-chip "
+                        "host take one each (docs/SCALING.md)")
     v.add_argument("-metrics.address", dest="metrics_address", default="",
                    help="Prometheus push gateway host:port (push loop)")
     v.add_argument("-metrics.intervalSeconds", dest="metrics_interval",

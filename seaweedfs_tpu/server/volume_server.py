@@ -32,6 +32,7 @@ from typing import Optional
 from ..ec import encoder
 from ..ec.constants import TOTAL_SHARDS, shard_ext
 from ..ec.ec_volume import EcVolume
+from ..stats import trace
 from ..storage.file_id import parse_needle_id_cookie
 from ..storage.needle import (
     FLAG_HAS_LAST_MODIFIED,
@@ -136,9 +137,11 @@ class VolumeServer:
 
     # -- remote EC shard read via master shard lookup ------------------------
     def _remote_shard_reader(self, vid, shard_id, offset, size):
-        r = http_json(
-            "GET", f"http://{self.master_url}/dir/lookup_ec?volumeId={vid}"
-        )
+        # the master is asked on every ask: who holds this shard now
+        with trace.stage_span("ec.read.lookup"):
+            r = http_json(
+                "GET", f"http://{self.master_url}/dir/lookup_ec?volumeId={vid}"
+            )
         holders = r.get("shard_id_locations", {}).get(str(shard_id)) or r.get(
             "shard_id_locations", {}
         ).get(shard_id, [])
@@ -981,21 +984,25 @@ class VolumeServer:
             exts += [".ecx"]
         if q.get("copy_vif", "true") == "true":
             exts += [".vif"]
-        for ext in exts:
-            status, data = http_bytes(
-                "GET",
-                f"http://{source}/admin/file?volume={vid}&collection={collection}&ext={ext}",
-            )
-            if status != 200:
-                if ext in (".vif",):
-                    continue
-                return 500, {"error": f"fetch {ext} from {source}: {status}"}
-            # stage + rename: a crash mid-fetch leaves a .tmp the startup
-            # recovery scan GCs, never a short shard under its final name
-            from ..storage.commit import atomic_write
+        from ..storage.commit import atomic_write
 
-            atomic_write(base + ext, data)
-            copied.append(ext)
+        # one pull of a spread (or a gather): every file fetched and staged
+        with trace.stage_span("ec.spread.copy", vid=vid, bytes=0) as span:
+            for ext in exts:
+                status, data = http_bytes(
+                    "GET",
+                    f"http://{source}/admin/file?volume={vid}&collection={collection}&ext={ext}",
+                )
+                if status != 200:
+                    if ext in (".vif",):
+                        continue
+                    return 500, {"error": f"fetch {ext} from {source}: {status}"}
+                # stage + rename: a crash mid-fetch leaves a .tmp the startup
+                # recovery scan GCs, never a short shard under its final name
+                atomic_write(base + ext, data)
+                copied.append(ext)
+                if span is not None:
+                    span.tags["bytes"] += len(data)
         # re-fetched shard bytes supersede any scrub findings on them
         self.store.clear_corrupt(vid, shard_ids=shard_ids)
         return 200, {"copied": copied}
@@ -1223,7 +1230,13 @@ class VolumeServer:
         ev = self.store.find_ec_volume(vid)
         if ev is None or sid not in ev.shards:
             return 404, {"error": f"shard {vid}.{sid} not here"}
-        return 200, ev.shards[sid].read_at(offset, size)
+        # the holder's side of another server's ask: the read alone, the
+        # reply's way back is the asker's ``ec.read.remote``
+        with trace.stage_span("ec.shard.serve", sid=sid) as span:
+            data = ev.shards[sid].read_at(offset, size)
+            if span is not None:
+                span.tags["bytes"] = len(data)
+        return 200, data
 
     def _h_needle_ids(self, h, path, q, body):
         """List live needle keys of a volume (volume.fsck's raw material;
@@ -1657,12 +1670,10 @@ class VolumeServer:
             glog.info("ec backend %r ready", self.store.ec_codec.backend)
         vs = self
 
-        from ..stats import trace as _trace
-
         class Handler(JsonHandler):
             trace_service = "volume"
             routes = [
-                ("GET", "/debug/traces", _trace.h_debug_traces),
+                ("GET", "/debug/traces", trace.h_debug_traces),
                 ("POST", "/admin/assign_volume", vs._h_assign_volume),
                 ("POST", "/admin/delete_volume", vs._h_delete_volume),
                 ("POST", "/_batch_delete", vs._h_batch_delete),

@@ -249,9 +249,11 @@ class StageTable:
     stage carries — ``bytes`` where it moves bytes, ``failed`` attempts
     and ``slept_s`` of back-off where it retries. Stages close on request
     threads and on the encode pipeline's threads at once, so the rows are
-    kept under one lock. A reader takes two snapshots and subtracts."""
+    kept under one lock. A reader takes two snapshots and subtracts.
+    A stage that asks another server also sums ``ok`` (asks answered) and
+    ``ok_s`` (seconds inside the attempts that were answered)."""
 
-    SUMMED_TAGS = ("bytes", "failed", "slept_s")
+    SUMMED_TAGS = ("bytes", "failed", "slept_s", "ok", "ok_s")
 
     def __init__(self):
         self._lock = make_lock("StageTable._lock")

@@ -133,6 +133,10 @@ class Store:
         # what JAX is held to in this process: "cpu" means it cannot open
         # the chip, None that JAX was never imported
         status["jax_platforms"] = jaxenv.platforms()
+        # the chip of its host this process claimed (volume -ec.chip), or
+        # None. A process narrowed to one chip numbers it 0 like any other,
+        # so the device id alone does not tell two servers' chips apart
+        status["chip"] = jaxenv.claimed_chip()
         # totals of the EC path's stage spans (docs/OBSERVABILITY.md): a
         # reader subtracts two snapshots. Absent while tracing is off
         if trace.enabled():
@@ -491,12 +495,16 @@ class Store:
             deadline_s=self.remote_fetch_timeout_s,
         )
         # the whole ask, sleeps included: attempts that raised and the
-        # back-off slept between them are summed into the stage table
+        # back-off slept between them are summed into the stage table; an
+        # ask that was answered adds 1 to ``ok``, the range to ``bytes`` and
+        # the answered attempt's own wall (lookup + fetch) to ``ok_s``
         with trace.stage_span(
-            "ec.read.remote", sid=sid, failed=0, slept_s=0.0
+            "ec.read.remote", sid=sid, failed=0, slept_s=0.0, ok=0,
+            ok_s=0.0, bytes=0,
         ) as span:
 
             def _fetch():
+                t = time.perf_counter()
                 try:
                     faultpoints.fire("ec.read.remote-fetch")
                     data = self.remote_shard_reader(vid, sid, offset, size)
@@ -505,11 +513,15 @@ class Store:
                         raise IOError(
                             f"short/empty remote range for {vid}.{sid}"
                         )
-                    return data
                 except Exception:
                     if span is not None:
                         span.tags["failed"] += 1
                     raise
+                if span is not None:
+                    span.tags["ok"] = 1
+                    span.tags["ok_s"] = time.perf_counter() - t
+                    span.tags["bytes"] = size
+                return data
 
             def _on_retry(e, attempt, delay):
                 if span is not None:
@@ -558,7 +570,14 @@ class Store:
                     local_s += time.perf_counter() - t
                     local_bytes += len(buf) if buf is not None else 0
                 else:
+                    t = time.perf_counter()
                     buf = self._remote_shard_read(ev.id, sid, offset, size)
+                    if buf is not None:  # never short: that is a failed ask
+                        # one sibling fetched from the server that holds it
+                        trace.record_stage(
+                            "ec.recover.remote", time.perf_counter() - t,
+                            sid=sid, bytes=size,
+                        )
                 if buf is not None and len(buf) == size:
                     shards[sid] = np.frombuffer(buf, dtype=np.uint8)
                     have += 1

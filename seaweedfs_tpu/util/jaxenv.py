@@ -27,6 +27,14 @@ facts are settled before the first backend touch and never again:
   instruction set — the loader itself warns of SIGILL on every hit — so a
   cache carried to another host is the stale ``native/build`` problem
   again, for nothing a test needs.
+- **which chip of the host is this process's own.**  libtpu gives a
+  process every chip of its host unless told otherwise, and a chip belongs
+  to one process: of four volume servers started side by side on a
+  four-chip host the first would take all four and the rest would fail.
+  :func:`claim_chip` (``volume -ec.chip N``) narrows this process to chip
+  ``N`` through libtpu's own environment, which it reads once, when JAX
+  first opens the backend — so the claim is made before that, and refused
+  after (docs/SCALING.md "One chip a volume server").
 - **how many programs were compiled.**  JAX's own monitoring events are
   counted so a daemon can report, through ``/status``, how many XLA/Mosaic
   compilations a request cost and how many the persistent cache answered.
@@ -46,6 +54,11 @@ _lock = threading.Lock()
 _jax = None
 _host_only = False  # the first caller was host-only: platforms pinned to cpu
 _counts = {"requests": 0, "cache_hits": 0}
+_chip: int | None = None  # the host's chip this process claimed, if any
+
+# libtpu's slice-builder port of a process that claimed chip N: each
+# process of a host needs its own, and a fixed rule needs no coordination
+_CHIP_PORT_BASE = 8476
 
 
 def compile_cache_dir() -> str | None:
@@ -64,6 +77,37 @@ def platforms() -> str | None:
     pinned (or under ``JAX_PLATFORMS=cpu``), ``""`` when any backend may
     be opened; None while JAX has not been imported here."""
     return None if _jax is None else (_jax.config.jax_platforms or "")
+
+
+def claim_chip(index: int) -> None:
+    """Narrow this process to chip ``index`` of its host: a topology of
+    one process holding one chip, of which only that chip is visible.
+    Must come before the first :func:`import_jax`; libtpu reads its
+    environment once."""
+    global _chip
+    if index < 0:
+        raise ValueError(f"chip index {index}: chips are numbered from 0")
+    if _jax is not None:
+        raise RuntimeError(
+            "claim_chip after JAX opened its backend: the chip must be "
+            "claimed before the first import_jax()"
+        )
+    port = str(_CHIP_PORT_BASE + index)
+    os.environ.update({
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": port,
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "CLOUD_TPU_TASK_ID": "0",
+    })
+    _chip = index
+
+
+def claimed_chip() -> int | None:
+    """The chip of the host this process claimed (None: whatever libtpu
+    gives it, which is every chip)."""
+    return _chip
 
 
 def _on_event(event: str, **_kw) -> None:

@@ -42,6 +42,29 @@ def _fresh_http_pool():
         conns.clear()
 
 
+@pytest.fixture
+def time_limit(request):
+    """A time limit of its own for the test that uses it (pytest-timeout is
+    not installed): the module's ``LIMITS`` gives the seconds by test name,
+    60 where it names none. SIGALRM, so main thread only — which is where
+    pytest and xdist's workers run tests."""
+    import signal
+
+    limits = getattr(request.module, "LIMITS", {})
+    seconds = limits.get(request.node.originalname, 60)
+
+    def over(*_):
+        raise TimeoutError(f"{request.node.name} passed its {seconds} s")
+
+    old = signal.signal(signal.SIGALRM, over)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

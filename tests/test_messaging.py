@@ -150,13 +150,18 @@ def test_replay_from_persisted_segments(stack):
             urllib.request.Request(f"http://{b.url}/_flush", method="POST"),
             timeout=10,
         )
-    time.sleep(0.5)
-    # segments visible as filer files under /topics
+    # segments visible as filer files under /topics (polled: on a loaded
+    # machine the flush's writes land later than a fixed half second)
     from seaweedfs_tpu.filer.client import FilerClient
 
     fc = FilerClient(filer.url)
-    segs = fc.list("/topics/logs/audit/00", limit=100)
-    assert any(e["name"].endswith(".seg") for e in segs)
+    deadline = time.monotonic() + 10
+    while True:
+        segs = fc.list("/topics/logs/audit/00", limit=100)
+        if any(e["name"].endswith(".seg") for e in segs):
+            break
+        assert time.monotonic() < deadline, segs
+        time.sleep(0.1)
     # a fresh subscriber (different broker instance state) replays history
     msgs, _ = mc.fetch("logs", "audit", 0, since_ns=0)
     assert [m["value"].decode() for m in msgs] == [f"ev{i}" for i in range(10)]
