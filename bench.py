@@ -2281,8 +2281,13 @@ def probe_e2e(dat_mb: int, sink: str = "disk") -> None:
         t0 = time.perf_counter()
         if sink == "null":
             # same items + pipeline as write_ec_files, shard bytes discarded
-            outputs = [_NullSink() for _ in range(codec.total_shards)]
-            encoder._encode_pipelined(base + ".dat", items, codec, outputs, n)
+            shards = encoder._HashedShards(
+                [_NullSink() for _ in range(codec.total_shards)])
+            try:
+                encoder._encode_pipelined(
+                    base + ".dat", items, codec, shards, n)
+            finally:
+                shards.close()
         else:
             # the exact plan the warm loop used — the timed run must launch
             # only warmed kernel shapes, so no internal re-derivation

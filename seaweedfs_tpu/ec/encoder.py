@@ -19,10 +19,13 @@ function of the .dat contents.
 from __future__ import annotations
 
 import functools
+import hashlib
 import json
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import wait as wait_all
 from typing import Optional
 
 import numpy as np
@@ -374,6 +377,95 @@ def plan_encode(
     return chunk, items
 
 
+class _HashedShards:
+    """The shard files of one seal, each with a running SHA-256 of exactly
+    the bytes that go into it, in the order they go: the sums of the .vif
+    (`save_volume_info`), taken while a chunk's rows are in memory instead
+    of by reading the staged files back.
+
+    The rows of a chunk are independent — fourteen files, fourteen digests
+    — and ``write`` and ``update`` both release the interpreter lock, so a
+    pool that lives as long as the call takes them side by side, a row a
+    task; the caller's thread waits for all of a chunk's rows, so a file
+    and its digest see the chunks in order, and a recycled buffer is free
+    when `append` returns. A row is handed to both as the one view it is:
+    no copy. The pool is as wide as the rows and the host's cores allow
+    (v5e host, 13 cores, PERF.md §6 PR 27: with one or two threads the
+    writer bounds the pipeline, from four up a seal is no longer for it;
+    the widest leaves that margin to hosts whose SHA-256 is slower)."""
+
+    def __init__(self, outputs: list):
+        self.name = outputs[0].name  # what a faultpoint of the seal names
+        self._outputs = outputs
+        self._digests = [hashlib.sha256() for _ in outputs]
+        self._fed = [0] * len(outputs)  # bytes, a digest
+        self._busy = [0.0] * len(outputs)  # seconds inside update, a digest
+        self._zeros = np.zeros(0, dtype=np.uint8)
+        self._pool = ThreadPoolExecutor(
+            max_workers=max(1, min(len(outputs), os.cpu_count() or 1)),
+            thread_name_prefix="ec-hash",
+        )
+
+    def append(self, rows) -> None:
+        """Row ``i`` of ``rows`` (one buffer a shard) goes to the end of
+        file ``i`` and into digest ``i``."""
+        self._each_row(self._append, rows)
+
+    def skip(self, width: int) -> None:
+        """A chunk of zeros: every file skips ``width`` bytes (the region
+        stays a hole), every digest is fed them from one zero buffer."""
+        if len(self._zeros) < width:
+            # never written to: the pages are the kernel's one zero page
+            self._zeros = np.zeros(width, dtype=np.uint8)
+        zeros = self._zeros[:width]
+        self._each_row(self._skip, [zeros] * len(self._outputs))
+
+    def _append(self, sid: int, row) -> None:
+        row = np.ascontiguousarray(row)
+        self._outputs[sid].write(row)
+        self._feed(sid, row)
+
+    def _skip(self, sid: int, zeros) -> None:
+        self._outputs[sid].seek(len(zeros), 1)
+        self._feed(sid, zeros)
+
+    def _feed(self, sid: int, row) -> None:
+        t0 = time.perf_counter()
+        self._digests[sid].update(row)
+        self._busy[sid] += time.perf_counter() - t0
+        self._fed[sid] += row.nbytes
+
+    def _each_row(self, fn, rows) -> None:
+        tasks = [self._pool.submit(fn, sid, row) for sid, row in enumerate(rows)]
+        wait_all(tasks)  # all of them, also when one raised: no row in flight
+        for task in tasks:
+            task.result()
+
+    def finish(self, final: int) -> list[str]:
+        """Bring every file to its ``final`` size (trailing holes) and hand
+        back the hex sums, one a shard. A digest that was not fed exactly
+        the file's bytes raises: the scrub would take a sum over other
+        bytes for a corrupt shard and start a rebuild. Leaves one
+        ``ec.seal.hash`` stage a shard: ``busy_s`` the seconds inside its
+        digest's updates — spent on the pool's threads, beside the other
+        rows' and beside the pipeline's other legs — and the ``bytes`` fed."""
+        for o, fed in zip(self._outputs, self._fed):
+            o.truncate(final)
+            if fed != final:
+                raise RuntimeError(
+                    f"{o.name}: digest fed {fed} bytes, "
+                    f"the shard file holds {final}"
+                )
+        for sid, busy_s in enumerate(self._busy):
+            trace.record_stage("ec.seal.hash", busy_s, sid=sid, bytes=final)
+        return [d.hexdigest() for d in self._digests]
+
+    def close(self) -> None:
+        self._pool.shutdown(wait=True)
+        for o in self._outputs:
+            o.close()
+
+
 def write_ec_files(
     base_file_name: str,
     codec: Optional[Codec] = None,
@@ -382,8 +474,10 @@ def write_ec_files(
     chunk_bytes: Optional[int] = None,
     plan: Optional[tuple] = None,
     suffix: str = "",
-) -> None:
-    """Generate all shard files from ``base.dat`` (WriteEcFiles, :57).
+) -> list[str]:
+    """Generate all shard files from ``base.dat`` (WriteEcFiles, :57) and
+    return their SHA-256 sums, hex, one a shard: each taken over the bytes
+    of its file as they were written (`_HashedShards`).
 
     ``suffix`` — appended to every shard file name. The crash-safe commit
     path (Store.ec_encode_volume) passes ``".tmp"`` so the shard set is
@@ -403,7 +497,8 @@ def write_ec_files(
     streams column chunks off disk, the main thread stages them into HBM and
     dispatches the (async) encode kernel, a fetch thread blocks on each
     chunk's parity (the D2H leg), and a writer thread appends the 14 shard
-    files. Disk read, H2D copy, compute, D2H and file writes for
+    files and feeds their digests, the fourteen rows of a chunk side by
+    side. Disk read, H2D copy, compute, D2H and file writes for
     neighbouring chunks overlap — the reference's
     serial 256KB read→Encode→write loop (`ec_encoder.go:162-192`) turned into
     a pipeline sized for a TPU. Host-only codecs keep the serial loop.
@@ -426,13 +521,13 @@ def write_ec_files(
         codec, dat_size, large_block_size, small_block_size, chunk_bytes
     )
 
-    outputs = [
+    shards = _HashedShards([
         open(base_file_name + shard_ext(i) + suffix, "wb")
         for i in range(k + m)
-    ]
+    ])
     try:
         if hasattr(codec, "matmul_device"):
-            _encode_pipelined(dat, items, codec, outputs, dat_size)
+            _encode_pipelined(dat, items, codec, shards, dat_size)
         else:
             # the parity buffer is consumed (written out) before the next
             # chunk encodes, so one buffer serves the whole stream — a fresh
@@ -443,7 +538,7 @@ def write_ec_files(
             with open(dat, "rb") as f:
                 fd = f.fileno()
                 for item in items:
-                    faultpoints.fire("ec.encode.chunk", path=outputs[0].name)
+                    faultpoints.fire("ec.encode.chunk", path=shards.name)
                     width = _item_width(item)
                     segments = _item_segments(fd, item, k, dat_size)
                     data = None
@@ -454,9 +549,8 @@ def write_ec_files(
                         # zeros encode to zeros: skip the matmul and leave
                         # holes in the shard files (sparse sealed volumes —
                         # preallocated space, punched deletes — stay sparse
-                        # and cheap; the truncate below fixes trailing sizes)
-                        for o in outputs:
-                            o.seek(width, 1)
+                        # and cheap; finish() fixes trailing sizes)
+                        shards.skip(width)
                     else:
                         if getattr(codec, "supports_out", False):
                             if parity_buf is None or parity_buf.shape[1] != width:
@@ -464,19 +558,14 @@ def write_ec_files(
                             parity = codec.encode(data, out=parity_buf)
                         else:
                             parity = codec.encode(data)
-                        for i in range(k):
-                            outputs[i].write(data[i].tobytes())
-                        for j in range(m):
-                            outputs[k + j].write(parity[j].tobytes())
+                        shards.append([*data, *parity])
                     if data is not None:
                         buffers.give(data)
-        final = ec_shard_base_size(dat_size, k, large_block_size,
-                                   small_block_size)
-        for o in outputs:
-            o.truncate(final)
+        return shards.finish(
+            ec_shard_base_size(dat_size, k, large_block_size, small_block_size)
+        )
     finally:
-        for o in outputs:
-            o.close()
+        shards.close()
 
 
 def _chunk_nbytes(items, k: int) -> int:
@@ -710,11 +799,13 @@ def _copy_back(op: str, out_dev, copy):
     return out
 
 
-def _encode_pipelined(dat, items, codec, outputs, dat_size: int) -> None:
+def _encode_pipelined(dat, items, codec, shards: _HashedShards,
+                      dat_size: int) -> None:
     """`write_ec_files` through the overlap pipeline. A chunk's buffer is
     the reader's while it is filled, then the chunk's: dispatch stages it,
     fetch awaits the parity (so the staged input has been consumed), and
-    the writer, last to read it, gives it back to the pool."""
+    the writer, last to read it (its rows go to the files and their
+    digests, `_HashedShards.append`), gives it back to the pool."""
     k, m = codec.data_shards, codec.parity_shards
     align = codec.alignment() if hasattr(codec, "alignment") else 1
     buffers = _ChunkBuffers("ec.seal", _chunk_nbytes(items, k))
@@ -751,8 +842,6 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int) -> None:
     # parity rows as m concurrent row-sized transfers instead of one
     # array-sized one overlaps them on runtimes with per-transfer setup
     # cost (and degrades to the same bytes moved on those without)
-    from concurrent.futures import ThreadPoolExecutor
-
     fetch_pool = ThreadPoolExecutor(
         max_workers=max(1, min(m, 4)), thread_name_prefix="ec-d2h"
     )
@@ -770,18 +859,16 @@ def _encode_pipelined(dat, items, codec, outputs, dat_size: int) -> None:
         return width, data, _copy_back("ec.seal", parity_dev, parity_rows)
 
     def consume(got):
-        faultpoints.fire("ec.encode.chunk", path=outputs[0].name)
+        faultpoints.fire("ec.encode.chunk", path=shards.name)
         width, data, parity = got
         if parity is None:
-            for o in outputs:  # keep sparse regions sparse (holes)
-                o.seek(width, 1)
+            shards.skip(width)  # keep sparse regions sparse (holes)
         else:
-            for i in range(k):
-                outputs[i].write(data[i, :width].tobytes())
-            for j in range(m):
-                # parity[j] indexing (not parity[j, ...]) so both a 2-D array
-                # and the row list from the parallel fetch work here
-                outputs[k + j].write(parity[j][:width].tobytes())
+            # parity[j] indexing (not parity[j, ...]) so both a 2-D array
+            # and the row list from the parallel fetch work here
+            shards.append(
+                [*data, *(parity[j][:width] for j in range(m))]
+            )
             trace.add_stage_bytes((k + m) * width)
         if data is not None:
             buffers.give(data)

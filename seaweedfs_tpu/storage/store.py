@@ -384,24 +384,14 @@ class Store:
             sc.stage(base + ".ecx")
             vif_tmp = sc.stage(base + ".vif")
             try:
-                encoder.write_ec_files(base, self.ec_codec, suffix=".tmp")
+                # per-shard sha256 for the .vif: the scrub thread's
+                # integrity ground truth (RS is deterministic — rebuilds
+                # hash identically), taken of each shard as it is written
+                sums = encoder.write_ec_files(
+                    base, self.ec_codec, suffix=".tmp"
+                )
                 with trace.stage_span("ec.seal.ecx", quiet=True):
                     encoder.write_sorted_file_from_idx(base, ext=".ecx.tmp")
-                # per-shard sha256 into the .vif: the scrub thread's
-                # integrity ground truth (RS is deterministic — rebuilds
-                # hash identically)
-                import hashlib
-
-                sums = []
-                for sid in range(TOTAL_SHARDS):
-                    digest = hashlib.sha256()
-                    with trace.stage_span("ec.seal.hash", sid=sid), open(
-                        base + shard_ext(sid) + ".tmp", "rb"
-                    ) as sf:
-                        for chunk in iter(lambda: sf.read(1 << 20), b""):
-                            digest.update(chunk)
-                        trace.add_stage_bytes(sf.tell())
-                    sums.append(digest.hexdigest())
                 # fsync, manifest, renames: the guarantee itself
                 with trace.stage_span("ec.seal.commit", quiet=True):
                     encoder.save_volume_info(
