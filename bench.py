@@ -1,28 +1,21 @@
-"""Headline benchmark: RS(10,4) ec.encode throughput + 4-missing-shard rebuild p50.
+"""Cluster probes: each `--probe-<name>` starts a small cluster of this
+repo's daemons (in-process or as subprocesses), drives one workload through
+it and prints ONE JSON line on stdout; diagnostics go to stderr.
 
-Prints ONE JSON line:
-    {"metric": "ec.encode", "value": <GB/s>, "unit": "GB/s/chip",
-     "vs_baseline": <value / 8.0>, "rebuild": {...}, ...extras}
+    python bench.py --probe-smallfile N C         1 KB files, native data plane
+    python bench.py --probe-filer-pipe MB WINDOW [CHUNK_MB]
+    python bench.py --probe-serving MODE CONNS_CSV [TOTAL]
+    python bench.py --probe-trace [TOTAL] [CONNS]
+    python bench.py --probe-hotshard [NEEDLES] [REQUESTS]
+    python bench.py --probe-lifecycle [FILES] [REQUESTS]
+    python bench.py --probe-sync [FILES] [OUTAGE_S]
+    python bench.py --probe-meta [FILES] [C]
+    python bench.py --probe-query [MB]
 
-Baseline: BASELINE.md north stars — ≥8 GB/s/chip RS(10,4) encode on TPU v5e,
-bit-identical to the Go/klauspost path (asserted against the C++ oracle before
-timing), and 4-missing-shard rebuild p50 (the reference's `ec.rebuild`
-worst case, `weed/storage/erasure_coding/ec_encoder.go:233`).
-
-Method notes:
-- The kernel probes generate volume bytes on-device: that isolates the encode
-  kernel, which is the component this framework replaces (the klauspost SIMD
-  Encode loop, `weed/storage/erasure_coding/ec_encoder.go:179`), from the
-  host link. The served path is `chip_smoke.py`'s and the e2e probes'.
-- A chip belongs to one process. This parent never imports JAX; every device
-  probe is one child process, run one at a time, and a RESOURCE_EXHAUSTED
-  (which poisons a device session) dies with its child.
-- A device probe that finds no TPU exits non-zero, and a device probe that
-  fails fails the run: a rate is never written for a device that was not
-  there. The cluster probes' servers are told `ec_backend="cpu"`/"numpy", so
-  none of them asks for the chip.
-- Each probe runs 3 timed repetitions and reports the best.
-- All diagnostics go to stderr; stdout carries exactly one JSON line.
+The probes' servers are told `ec_backend="cpu"`/"numpy", so none of them
+asks for a chip; nothing here imports JAX or the EC layer. What the EC path
+does on the chip is measured by the benchmark (`BENCHMARK.json`,
+`benchmark/run.py`) and checked by `chip_smoke.py`.
 """
 
 import json
@@ -39,332 +32,6 @@ from seaweedfs_tpu.util.netports import free_port  # noqa: E402
 def log(*a):
     print(*a, file=sys.stderr, flush=True)
 
-
-def _require_tpu():
-    """Child mode: ``jax`` for a device probe, or exit non-zero. These
-    probes publish GB/s/chip; without a chip there is nothing to publish,
-    and a CPU run is not written under a device metric's name."""
-    from seaweedfs_tpu.util.jaxenv import import_jax
-
-    jax = import_jax()
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        sys.exit(
-            f"device probe needs a TPU: JAX offers {dev.platform} "
-            f"({dev.device_kind})"
-        )
-    return jax
-
-
-def _timed_reps(run_once, reps: int = 3, iters: int = 6) -> list[float]:
-    """Best-of-reps timing loop: returns per-rep seconds/iter."""
-    out = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        run_once(iters)
-        out.append((time.perf_counter() - t0) / iters)
-    return out
-
-
-def _sustained_rate(run_chain, bytes_per_iter: int, short: int = 32,
-                    long_: int = 160, reps: int = 3) -> tuple[float, float]:
-    """(sustained GB/s, raw long-chain GB/s).
-
-    Chains of device ops measured at two lengths; the difference cancels the
-    fixed chain overhead (jit dispatch ramp + ONE host sync per chain).
-    """
-    def best(iters):
-        times = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            run_chain(iters)
-            times.append(time.perf_counter() - t0)
-        return min(times)
-
-    t_s = best(short)
-    t_l = best(long_)
-    sustained = bytes_per_iter * (long_ - short) / max(t_l - t_s, 1e-9) / 1e9
-    raw = bytes_per_iter * long_ / t_l / 1e9
-    return sustained, raw
-
-
-# -- tile autotune sidecar -----------------------------------------------------
-# The alt-geometry probes (RS(6,3)/RS(12,4)) historically swung ~50% between
-# runs because every run RE-SWEPT tiles under a wall-clock guard: a slow host
-# truncated the sweep at a different tile each time and published whatever it
-# had. Warm-first protocol instead: the FIRST run sweeps (it is the warmup —
-# its number is the sweep's best, and the winning tile is persisted to a JSON
-# sidecar); every later run loads the pinned tile and measures ONLY it, so
-# run-to-run spread is the kernel's own, not the tile lottery's.
-
-def _tile_cache_path() -> str:
-    """SWEED_TILE_CACHE > ~/.cache/sweed_tile.json > repo-local fallback
-    (CI containers with read-only or absent home directories)."""
-    env = os.environ.get("SWEED_TILE_CACHE")
-    if env:
-        return env
-    cache_dir = os.path.expanduser("~/.cache")
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        probe = os.path.join(cache_dir, ".sweed_tile_probe")
-        with open(probe, "w"):
-            pass
-        os.remove(probe)
-        return os.path.join(cache_dir, "sweed_tile.json")
-    except OSError:
-        return os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), ".sweed_tile.json"
-        )
-
-
-def _tile_cache_load() -> dict:
-    try:
-        with open(_tile_cache_path()) as f:
-            d = json.load(f)
-        return d if isinstance(d, dict) else {}
-    except (OSError, ValueError):
-        return {}
-
-
-def _tile_cache_store(key: str, entry: dict) -> None:
-    path = _tile_cache_path()
-    d = _tile_cache_load()
-    d[key] = entry
-    tmp = path + ".tmp"
-    try:
-        with open(tmp, "w") as f:
-            json.dump(d, f, indent=1, sort_keys=True)
-        os.replace(tmp, path)
-    except OSError as e:  # cache is an optimization; the bench must not die
-        log(f"tile cache write failed ({path}): {e}")
-
-
-def probe_gate() -> None:
-    """Child mode: the bit-identity gate (device kernel vs the C++ oracle,
-    small shapes) and the device line. Prints one JSON object; exits
-    non-zero when the bytes differ or there is no TPU."""
-    import numpy as np
-
-    jax = _require_tpu()
-    from seaweedfs_tpu.ec.codec import CpuCodec, TpuCodec
-
-    cpu = CpuCodec()
-    tpu_small = TpuCodec(chunk_bytes=8 * 65536, tile_bytes=65536, pallas_tile=65536)
-    rng = np.random.default_rng(0)
-    gate = rng.integers(0, 256, (10, 3 * 65536 + 777), dtype=np.uint8)
-    if not np.array_equal(cpu.encode(gate), tpu_small.encode(gate)):
-        sys.exit("bit-identity check FAILED")
-    dev = jax.devices()[0]
-    print(json.dumps({
-        "platform": dev.platform,
-        "device_kind": dev.device_kind,
-        "count": len(jax.devices()),
-        "kernel": tpu_small.kernel,
-    }))
-
-
-def probe_encode(chunk_mb: int, tile_kb: int) -> None:
-    """Child mode: time encode for one config, print one float (GB/s)."""
-    jax = _require_tpu()
-    jnp = jax.numpy
-
-    from seaweedfs_tpu.ec.codec import TpuCodec
-
-    codec = TpuCodec(
-        chunk_bytes=chunk_mb * 1024 * 1024, pallas_tile=tile_kb * 1024
-    )
-    n = chunk_mb * 1024 * 1024
-
-    @jax.jit
-    def checksum(x):
-        return jnp.sum(x, dtype=jnp.uint32)
-
-    # 4 distinct buffers cycled through the chain: rules out any
-    # identical-request caching in the runtime inflating the rate
-    bufs = [
-        jax.random.bits(jax.random.PRNGKey(i), (10, n), dtype=jnp.uint8)
-        for i in range(4)
-    ]
-    for b in bufs:
-        b.block_until_ready()
-    _ = int(checksum(codec.matmul_device(codec.parity_rows, bufs[0])))  # warm
-
-    def run(iters):
-        acc = None
-        for i in range(iters):
-            s = checksum(codec.matmul_device(codec.parity_rows, bufs[i % 4]))
-            acc = s if acc is None else acc + s
-        _ = int(acc)  # forces execution of the whole chain
-
-    sustained, raw = _sustained_rate(run, 10 * n)
-    print(f"{sustained:.4f} {raw:.4f}")
-
-
-def probe_rebuild(shard_mb: int, tile_kb: int) -> None:
-    """Child mode: 4-missing-data-shard rebuild. Prints 'p50_s gbps'.
-
-    Worst case of the reference's `ec.rebuild`: data shards 0-3 lost, rebuilt
-    from the 10 remaining (6 data + 4 parity) via the inverted decode matrix
-    (`ec_encoder.go:233` rebuildEcFiles → klauspost Reconstruct).
-    """
-    jax = _require_tpu()
-    jnp = jax.numpy
-
-    from seaweedfs_tpu.ec.codec import TpuCodec
-
-    codec = TpuCodec(pallas_tile=tile_kb * 1024)
-    n = shard_mb * 1024 * 1024
-    present_rows = list(range(4, 14))  # shards 4..13 survive
-    decode = codec._decode_matrix_for(present_rows)[:4]  # rows for shards 0-3
-
-    @jax.jit
-    def checksum(x):
-        return jnp.sum(x, dtype=jnp.uint32)
-
-    # generate in ≤32MB-wide pieces: threefry materialises ~8 bytes of
-    # intermediates per output byte, so one (10, n) draw OOMs for big shards
-    gen_w = 32 * 1024 * 1024
-    pieces = [
-        jax.random.bits(jax.random.PRNGKey(i), (10, min(gen_w, n - off)),
-                        dtype=jnp.uint8)
-        for i, off in enumerate(range(0, n, gen_w))
-    ]
-    # distinct chunk-width buffers for the sustained chain (kept BEFORE the
-    # concatenate: device-side re-slicing would add copies the production
-    # chunk-streaming rebuild never performs)
-    cw = min(n, codec.chunk_bytes)
-    chunk_bufs = [p for p in pieces if p.shape[1] == cw][:4]
-    while len(chunk_bufs) < 4:  # small shards: keep the rotation distinct
-        chunk_bufs.append(
-            jax.random.bits(
-                jax.random.PRNGKey(1000 + len(chunk_bufs)), (10, cw),
-                dtype=jnp.uint8,
-            )
-        )
-    present = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
-    del pieces
-    present.block_until_ready()
-    rebuilt = codec.matmul_device(decode, present)
-    _ = int(checksum(rebuilt))  # compile + warm (full-shard chunked path)
-
-    times = []
-    for _ in range(9):
-        t0 = time.perf_counter()
-        rebuilt = codec.matmul_device(decode, present)
-        _ = int(checksum(rebuilt))
-        times.append(time.perf_counter() - t0)
-    p50 = sorted(times)[len(times) // 2]
-    del rebuilt, present  # free HBM headroom before queuing the chain
-
-    # sustained KERNEL rate, same methodology and shape regime as encode's
-    # probe: one chunk-width launch per iteration over rotated distinct
-    # buffers, standard 32/160 chain lengths so the fixed per-chain sync
-    # actually cancels (r4 ran 4-iteration deltas on big shards — most of
-    # the 'rebuild 30% slower' gap was whole-shard slicing + concatenate
-    # plus under-cancelled fixed cost, not the 4×10 matmul itself)
-    _ = int(checksum(codec.matmul_device(decode, chunk_bufs[0])))  # warm shape
-
-    def run(iters):
-        acc = None
-        for i in range(iters):
-            s = checksum(codec.matmul_device(decode, chunk_bufs[i % len(chunk_bufs)]))
-            acc = s if acc is None else acc + s
-        _ = int(acc)
-
-    sustained, _raw = _sustained_rate(run, 10 * cw)
-    # GB/s of source bytes processed (10 shards in, 4 rebuilt out)
-    print(f"{p50:.6f} {10 * n / p50 / 1e9:.4f} {sustained:.4f}")
-
-
-def probe_mesh(chunk_mb: int, tile_kb: int) -> None:
-    """Child mode: the MESH code path (MeshCodec.matmul_device) on a 1-device
-    mesh (dp=sp=tp=1) on the real chip. With tp=1 the per-device body is the
-    fused Pallas kernel under shard_map, so this certifies the multichip
-    configuration inherits the single-chip rate (VERDICT r2 weak #3).
-    Prints one float (GB/s)."""
-    jax = _require_tpu()
-    jnp = jax.numpy
-    import numpy as np
-
-    from seaweedfs_tpu.ec.sharded import MeshCodec, build_mesh
-
-    mesh = build_mesh(1)
-    codec = MeshCodec(
-        mesh=mesh, chunk_bytes=chunk_mb * 1024 * 1024,
-        pallas_tile=tile_kb * 1024,
-    )
-    assert codec.use_pallas, "mesh probe must take the fused-kernel path"
-    n = chunk_mb * 1024 * 1024
-
-    @jax.jit
-    def checksum(x):
-        return jnp.sum(x, dtype=jnp.uint32)
-
-    rng = np.random.default_rng(0)
-    bufs = [
-        codec.device_put(rng.integers(0, 256, (10, n), dtype=np.uint8))
-        for _ in range(4)
-    ]
-    for b in bufs:
-        b.block_until_ready()
-    _ = int(checksum(codec.matmul_device(codec.parity_rows, bufs[0])))  # warm
-
-    def run(iters):
-        acc = None
-        for i in range(iters):
-            s = checksum(codec.matmul_device(codec.parity_rows, bufs[i % 4]))
-            acc = s if acc is None else acc + s
-        _ = int(acc)
-
-    sustained, _raw = _sustained_rate(run, 10 * n)
-    print(f"{sustained:.4f}")
-
-
-def probe_rebuild_stream(shard_gb: int, chunk_mb: int) -> None:
-    """Child mode: MEASURED 30GB-class rebuild via the chunked stream.
-
-    A 30 GB volume has 3 GB shards (RS(10,4), ec_encoder.go:17-23); 10×3 GB
-    of surviving shards don't fit HBM at once, so the production path
-    (`rebuild_ec_files`, ec/encoder.py) streams column chunks. This probe
-    executes that exact chunk loop on-device — shard_gb per shard in
-    chunk_mb chunks, chained without per-chunk host sync — and reports the
-    full-shard p50 over 3 runs, in place of a linear extrapolation
-    (VERDICT r2 weak #2). Prints 'p50_s gbps n_chunks'."""
-    jax = _require_tpu()
-    jnp = jax.numpy
-
-    from seaweedfs_tpu.ec.codec import TpuCodec
-
-    codec = TpuCodec(pallas_tile=16 * 1024)
-    chunk = chunk_mb * 1024 * 1024
-    n_chunks = (shard_gb * 1024) // chunk_mb
-    present_rows = list(range(4, 14))
-    decode = codec._decode_matrix_for(present_rows)[:4]
-
-    @jax.jit
-    def checksum(x):
-        return jnp.sum(x, dtype=jnp.uint32)
-
-    bufs = [
-        jax.random.bits(jax.random.PRNGKey(i), (10, chunk), dtype=jnp.uint8)
-        for i in range(4)
-    ]
-    for b in bufs:
-        b.block_until_ready()
-    _ = int(checksum(codec.matmul_device(decode, bufs[0])))  # compile + warm
-
-    times = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        acc = None
-        for _c in range(n_chunks):
-            s = checksum(codec.matmul_device(decode, bufs[_c % 4]))
-            acc = s if acc is None else acc + s
-        _ = int(acc)  # one host sync per full shard rebuild
-        times.append(time.perf_counter() - t0)
-    p50 = sorted(times)[len(times) // 2]
-    total_bytes = 10 * chunk * n_chunks
-    print(f"{p50:.4f} {total_bytes / p50 / 1e9:.4f} {n_chunks}")
 
 
 def probe_smallfile(n: int, c: int) -> None:
@@ -2217,378 +1884,6 @@ def probe_meta(n_files: int = 480, c: int = 16) -> None:
             four["lookups_per_s"] / max(one["lookups_per_s"], 0.1), 2),
     }))
 
-class _NullSink:
-    """File-like that discards writes: isolates read+H2D+compute+D2H from
-    any filesystem at all (the 'where is the first real bottleneck' probe)."""
-
-    name = "<null sink>"  # the encoder names its first output at a faultpoint
-
-    def write(self, b):
-        return len(b)
-
-    def seek(self, off, whence=0):
-        return 0
-
-    def truncate(self, size=None):
-        return 0
-
-    def close(self):
-        pass
-
-
-def probe_e2e(dat_mb: int, sink: str = "disk") -> None:
-    """Child mode: end-to-end .dat→14-shard-files encode through the overlap
-    pipeline (write_ec_files), the path `/admin/ec/generate` runs. Prints one
-    line: 'gbps efficiency read_s compute_s write_s'.
-
-    sink: 'disk' (tempdir on this host's disk), 'tmpfs' (/dev/shm — removes
-    the disk from both ends), or 'null' (shard writes discarded — pure
-    read+device path)."""
-    import tempfile
-
-    import numpy as np
-
-    from seaweedfs_tpu.ec import encoder
-    from seaweedfs_tpu.ec.codec import TpuCodec
-
-    _require_tpu()
-    codec = TpuCodec()
-    n = dat_mb * 1024 * 1024
-    parent = "/dev/shm" if sink in ("tmpfs", "null") else None
-    with tempfile.TemporaryDirectory(dir=parent) as tmp:
-        base = os.path.join(tmp, "1")
-        rng = np.random.default_rng(0)
-        with open(base + ".dat", "wb") as f:
-            f.write(rng.integers(0, 256, n, dtype=np.uint8).tobytes())
-        # the same work plan write_ec_files will compute internally —
-        # shared planner, so the warm list below cannot drift from the
-        # timed run's actual item widths
-        k = codec.data_shards
-        chunk, items = encoder.plan_encode(codec, n)
-        # warm every kernel shape the timed run will launch: Mosaic
-        # compiles per column width, and one compile inside the timed
-        # region would swamp the measurement
-        align = codec.alignment()
-        for w in sorted({encoder._item_width(it) for it in items}):
-            pw = align * -(-w // align)
-            codec.matmul_device(
-                codec.parity_rows,
-                codec.device_put(np.ones((k, pw), dtype=np.uint8)),
-            ).block_until_ready()
-        from seaweedfs_tpu.stats import trace
-
-        before = trace.STAGES.snapshot()
-        t0 = time.perf_counter()
-        if sink == "null":
-            # same items + pipeline as write_ec_files, shard bytes discarded
-            shards = encoder._HashedShards(
-                [_NullSink() for _ in range(codec.total_shards)])
-            try:
-                encoder._encode_pipelined(
-                    base + ".dat", items, codec, shards, n)
-            finally:
-                shards.close()
-        else:
-            # the exact plan the warm loop used — the timed run must launch
-            # only warmed kernel shapes, so no internal re-derivation
-            encoder.write_ec_files(base, codec, plan=(chunk, items))
-        dt = time.perf_counter() - t0
-        # this run's stages: the tracer's table after, less before
-        after = trace.STAGES.snapshot()
-        legs = ("read", "dispatch", "fetch", "write")
-        wall, *busy = (
-            after[name]["busy_s"] - before.get(name, {}).get("busy_s", 0.0)
-            for name in ("ec.seal.pipeline", *(f"ec.seal.{l}" for l in legs))
-        )
-        efficiency = max(busy) / wall
-        log(
-            f"overlap pipeline [{sink}]: wall={wall:.2f}s "
-            + " ".join(f"{l}={b:.2f}s" for l, b in zip(legs, busy))
-            + f" efficiency={efficiency:.2f} "
-            f"(1.0 = wall==max(stage); serial loop would be "
-            f"{sum(busy) / wall:.2f}x slower)"
-        )
-    print(f"{n / dt / 1e9:.4f} {efficiency:.3f} "
-          + " ".join(f"{b:.3f}" for b in busy))
-
-
-def probe_extras(sweep_guard_s: float = 240.0) -> None:
-    """Child mode: the remaining BASELINE.md bench configs in one cheap
-    subprocess — CPU-path 1 GB encode, alt geometries RS(6,3)/RS(12,4) on
-    the device, and the 1-missing-data-shard reconstruct p50. Prints one
-    JSON line."""
-    out = {}
-
-    # CPU path: the C++ fallback encoding 1 GB (the non-TPU rate). The
-    # loader rebuilds the lib unless its stamp says it was made from this
-    # source for THIS host's CPU (native/__init__.py), and the compiled
-    # kernel variant is recorded alongside the rate, so the artifact is
-    # self-explaining — r4 published 0.028 GB/s with no way to tell a
-    # stale .so from a no-AVX2 host from transient pressure. Best-of-3
-    # guards the latter.
-    jax = _require_tpu()
-    jnp = jax.numpy
-    import numpy as np
-
-    from seaweedfs_tpu.ec.codec import CpuCodec, TpuCodec
-
-    cpu = CpuCodec()
-    out["cpu_kernel"] = cpu._lib.kernel_variant()
-    giga = np.random.default_rng(0).integers(
-        0, 256, (10, 100 * 1024 * 1024), dtype=np.uint8
-    )
-    cpu.encode(giga[:, : 1024 * 1024])  # warm
-    # sustained = reused parity buffer, the streaming-encoder scenario
-    # (encoder.py passes out= per chunk; klauspost's Go benchmarks likewise
-    # reuse the shard slices) — allocating 400 MB of parity per call costs
-    # mmap + first-touch page faults comparable to the GFNI kernel itself
-    parity_buf = np.empty((cpu.parity_shards, giga.shape[1]), dtype=np.uint8)
-    runs = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        cpu.encode(giga, out=parity_buf)
-        runs.append(1.0 * giga.size / (time.perf_counter() - t0) / 1e9)
-    out["cpu_encode_gbps"] = round(max(runs), 3)
-    out["cpu_encode_runs_gbps"] = [round(r, 3) for r in runs]
-    del parity_buf
-    t0 = time.perf_counter()
-    cpu.encode(giga)
-    out["cpu_encode_fresh_gbps"] = round(
-        1.0 * giga.size / (time.perf_counter() - t0) / 1e9, 3
-    )
-    # before/after: the same kernel WITHOUT the cached prep blob — the
-    # multiply tables are re-derived inside the call — so the artifact
-    # shows what the prep cache buys
-    matrix = np.ascontiguousarray(cpu.parity_rows, dtype=np.uint8)
-    t0 = time.perf_counter()
-    cpu._lib.rs_matmul(matrix, giga)
-    out["cpu_encode_noprep_gbps"] = round(
-        1.0 * giga.size / (time.perf_counter() - t0) / 1e9, 3
-    )
-    del giga
-
-    @jax.jit
-    def checksum(x):
-        return jnp.sum(x, dtype=jnp.uint32)
-
-    # alt geometries on the device (chained ops, ONE host sync per chain —
-    # per-op syncs would measure the sync). Tile is SWEPT like the main
-    # RS(10,4) probe: r4 pinned these to 32KB and published RS(6,3) well
-    # below the range the README claimed; the sweep finds each geometry's
-    # own best tile, bounded by a wall-clock guard (compiles dominate).
-    # Warm-first: a pinned tile in the sidecar (see _tile_cache_path)
-    # collapses the sweep to that single tile — the ~50% run-to-run swing
-    # on these geometries was the guard truncating the sweep at a
-    # different tile each run, not kernel variance.
-    t_extras = time.perf_counter()
-    n = 32 * 1024 * 1024
-    # historically-best tile FIRST per geometry (r5 probes: RS(6,3) peaked
-    # at 64KB — 88.6 vs 59.3 GB/s at 32KB; RS(12,4) at 32KB) so the
-    # wall-clock guard stopping the sweep early still keeps the best config
-    tile_order = {(6, 3): (64, 32, 128, 16), (12, 4): (32, 64, 16, 128)}
-    dev_kind = jax.devices()[0].device_kind
-    tile_cache = _tile_cache_load()
-    for (k, m), tiles in tile_order.items():
-        cache_key = f"rs{k},{m}:{dev_kind}"
-        pin = tile_cache.get(cache_key, {}).get("tile_kb")
-        pinned = pin in tiles
-        if pinned:
-            tiles = (pin,)
-        # one input buffer per geometry (tile-invariant): regenerating it
-        # per tile would waste the sweep's own wall budget, and a stale
-        # reference pinned by the run closure would keep two resident
-        buf = jax.random.bits(jax.random.PRNGKey(k), (k, n), dtype=jnp.uint8)
-        buf.block_until_ready()
-        best_g, best_tile = 0.0, None
-        for tile_kb in tiles:
-            if best_tile is not None \
-                    and time.perf_counter() - t_extras > sweep_guard_s:
-                break
-            codec = TpuCodec(k, m, pallas_tile=tile_kb * 1024)
-            _ = int(checksum(codec.matmul_device(codec.parity_rows, buf)))
-
-            def run(iters, codec=codec, buf=buf):
-                acc = None
-                for _ in range(iters):
-                    s = checksum(codec.matmul_device(codec.parity_rows, buf))
-                    acc = s if acc is None else acc + s
-                _ = int(acc)
-
-            sustained, _raw = _sustained_rate(run, k * n, short=8, long_=40)
-            del run  # drop the closure so buf has one owner again
-            if sustained > best_g:
-                best_g, best_tile = sustained, tile_kb
-        del buf
-        out[f"rs{k}{m}_encode_gbps"] = round(best_g, 2)
-        out[f"rs{k}{m}_tile_kb"] = best_tile
-        out[f"rs{k}{m}_tile_pinned"] = pinned
-        if best_tile is not None and not pinned:
-            _tile_cache_store(cache_key, {
-                "tile_kb": best_tile,
-                "gbps": round(best_g, 2),
-                "device": dev_kind,
-            })
-
-    # 1-missing-data-shard reconstruct (the common degraded-read case —
-    # decode is a (1 × 10) matmul instead of the 4-row worst case); big
-    # width so the single host sync doesn't dominate
-    codec = TpuCodec(pallas_tile=32 * 1024)
-    present_rows = list(range(1, 11))  # shard 0 lost
-    decode = codec._decode_matrix_for(present_rows)[:1]
-    gen_w = 32 * 1024 * 1024
-    buf = None
-    # fall back to narrower widths rather than dying RESOURCE_EXHAUSTED
-    # with the whole extras JSON unprinted (this is the last section)
-    last_err = ""
-    for n in (128 * 1024 * 1024, 64 * 1024 * 1024, 32 * 1024 * 1024):
-        pieces = None
-        try:
-            pieces = [
-                jax.random.bits(jax.random.PRNGKey(100 + i),
-                                (10, min(gen_w, n - off)), dtype=jnp.uint8)
-                for i, off in enumerate(range(0, n, gen_w))
-            ]
-            buf = jnp.concatenate(pieces, axis=1)
-            buf.block_until_ready()
-            _ = int(checksum(codec.matmul_device(decode, buf)))
-            break
-        except Exception as e:  # noqa: BLE001 — RESOURCE_EXHAUSTED et al.
-            buf = None
-            last_err = str(e)[:200]  # a non-OOM bug must stay visible
-        finally:
-            del pieces  # drop the failed width's arrays BEFORE retrying
-    if buf is None:
-        out["reconstruct1_error"] = last_err or "unknown"
-        print(json.dumps(out))
-        return
-    out["reconstruct1_width_mb"] = n // (1024 * 1024)
-    times = []
-    for _ in range(9):
-        t0 = time.perf_counter()
-        _ = int(checksum(codec.matmul_device(decode, buf)))
-        times.append(time.perf_counter() - t0)
-    p50 = sorted(times)[len(times) // 2]
-    # p50 is the honest single-call latency (incl. one host sync); the GB/s
-    # figure comes from chained ops so the fixed per-op host sync doesn't
-    # masquerade as kernel cost (same method as every other probe)
-    out["reconstruct1_p50_s"] = round(p50, 4)
-
-    def run1(iters):
-        acc = None
-        for _ in range(iters):
-            s = checksum(codec.matmul_device(decode, buf))
-            acc = s if acc is None else acc + s
-        _ = int(acc)
-
-    # same chain lengths as the geometry sweep above (8/40): the r5 runs
-    # with short=4/long=16 scattered 30-51 GB/s on identical code — the
-    # fixed-sync cancellation needs more ops to converge at this op size
-    sustained, _raw = _sustained_rate(run1, 10 * n, short=8, long_=40)
-    out["reconstruct1_gbps"] = round(sustained, 2)
-    # the rate trails encode because a 1-missing decode has 8 output bit
-    # rows vs encode's 32 on the 128-row MXU tile — skinny-output
-    # utilization, not a dispatch fallback (the fused kernel runs here)
-    print(json.dumps(out))
-
-
-def probe_roofline(n_mb: int = 256, guard_s: float = 240.0) -> None:
-    """Child mode: the memory-bandwidth roofline behind the encode plateau.
-
-    Two measurements, one JSON line:
-
-    * ``stream_copy_gbps`` — a jitted uint8 ``x + 1`` chained through an
-      ``n_mb`` buffer (each link reads + writes every byte, data dependence
-      prevents elision). That is the STREAM-style practical HBM ceiling
-      this runtime reaches — no arithmetic to hide behind, so no kernel
-      can legitimately move bytes faster.
-    * ``tiles[]`` — achieved RS(10,4) GF-matmul HBM traffic (read k·n,
-      write m·n per op; the per-op checksum's extra parity read is NOT
-      counted, so the fraction is conservative) at several tile sizes,
-      each as a fraction of the copy ceiling.
-
-    Interpretation: the ~75 GB/s input-rate encode plateau is
-    memory-bound iff the best tile's ``roofline_frac`` sits near 1.0 —
-    then no tile/kernel tweak moves the headline, only bandwidth does. A
-    tile whose fraction falls off is kernel-bound at that shape (VMEM
-    re-streaming), which is tuning headroom, not a hardware wall.
-    """
-    jax = _require_tpu()
-    jnp = jax.numpy
-
-    from seaweedfs_tpu.ec.codec import TpuCodec
-
-    t_start = time.perf_counter()
-    width = 32 * 1024 * 1024
-    chain = (8, 40)
-    if jax.default_backend() == "cpu":
-        # host-memory roofline is still meaningful, but CPU XLA runs the
-        # bit-matmul ~100x slower — shrink so the probe fits its timeout
-        n_mb = min(n_mb, 64)
-        width = 4 * 1024 * 1024
-        chain = (2, 8)
-    out = {"buffer_mb": n_mb, "device": jax.devices()[0].device_kind}
-
-    @jax.jit
-    def checksum(x):
-        return jnp.sum(x, dtype=jnp.uint32)
-
-    @jax.jit
-    def stream(x):
-        return x + jnp.uint8(1)
-
-    n = n_mb * 1024 * 1024
-    buf = jax.random.bits(jax.random.PRNGKey(0), (n,), dtype=jnp.uint8)
-    buf.block_until_ready()
-    stream(buf).block_until_ready()  # warm/compile
-
-    def run_copy(iters):
-        y = buf
-        for _ in range(iters):
-            y = stream(y)
-        _ = int(checksum(y))
-
-    ceiling, raw = _sustained_rate(
-        run_copy, 2 * n, short=chain[0], long_=chain[1]
-    )
-    out["stream_copy_gbps"] = round(ceiling, 2)
-    out["stream_copy_raw_gbps"] = round(raw, 2)
-    del buf
-
-    k_, m_ = 10, 4
-    data = jax.random.bits(jax.random.PRNGKey(1), (k_, width), dtype=jnp.uint8)
-    data.block_until_ready()
-    tiles_out = []
-    for tile_kb in (8, 16, 32, 64, 128):
-        if tiles_out and time.perf_counter() - t_start > guard_s:
-            out["truncated_at_tile_kb"] = tile_kb  # no silent caps
-            break
-        try:
-            codec = TpuCodec(pallas_tile=tile_kb * 1024)
-            _ = int(checksum(codec.matmul_device(codec.parity_rows, data)))
-        except Exception as e:  # noqa: BLE001 — tile too big for VMEM etc.
-            tiles_out.append({"tile_kb": tile_kb, "error": str(e)[:120]})
-            continue
-
-        def run(iters, codec=codec):
-            acc = None
-            for _ in range(iters):
-                s = checksum(codec.matmul_device(codec.parity_rows, data))
-                acc = s if acc is None else acc + s
-            _ = int(acc)
-
-        enc, _r = _sustained_rate(
-            run, k_ * width, short=chain[0], long_=chain[1]
-        )
-        del run
-        hbm = enc * (k_ + m_) / k_
-        entry = {"tile_kb": tile_kb, "encode_gbps": round(enc, 2),
-                 "hbm_gbps": round(hbm, 2)}
-        if ceiling > 0:
-            entry["roofline_frac"] = round(hbm / ceiling, 3)
-        tiles_out.append(entry)
-    out["tiles"] = tiles_out
-    print(json.dumps(out))
-
 
 def probe_query(size_mb: int = 256) -> None:
     """Child mode: vectorized S3-Select scan (query/scan.py) vs the
@@ -2662,549 +1957,8 @@ def probe_query(size_mb: int = 256) -> None:
     print(json.dumps(out))
 
 
-def _run_probe(args: list[str], timeout: int = 420):
-    cmd = [sys.executable, os.path.abspath(__file__)] + args
-    return subprocess.run(
-        cmd, capture_output=True, text=True, timeout=timeout,
-        cwd=os.path.dirname(os.path.abspath(__file__)),
-    )
-
-
-def main() -> None:
-    t_setup = time.perf_counter()
-    # device probes that failed: any entry fails the run (exit 1)
-    device_failures: list[str] = []
-
-    def device_probe(args: list[str], what: str, timeout: int = 420):
-        """Run one device probe child; its stdout on success, else None
-        with the failure recorded."""
-        try:
-            r = _run_probe(args, timeout=timeout)
-        except subprocess.TimeoutExpired:
-            log(f"{what} timed out")
-            device_failures.append(f"{what}: timed out")
-            return None
-        if r.returncode == 0 and r.stdout.strip():
-            return r
-        tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-        log(f"{what} failed: {tail[0][:140]}")
-        device_failures.append(f"{what}: {tail[0][:140]}")
-        return None
-
-    # -- correctness gate + device line, in a child: this parent never
-    # touches JAX, because a chip belongs to one process and every probe
-    # below is a child that needs it
-    r = device_probe(["--probe-gate"], "identity gate", timeout=300)
-    if r is None:
-        print(
-            json.dumps(
-                {
-                    "metric": "ec.encode",
-                    "value": 0.0,
-                    "unit": "GB/s/chip",
-                    "vs_baseline": 0.0,
-                    "error": "identity gate FAILED: " + device_failures[-1],
-                }
-            )
-        )
-        sys.exit(1)
-    dev = json.loads(r.stdout.strip().splitlines()[-1])
-    log("bit-identity vs C++ oracle: OK")
-    log(f"device: {dev['device_kind']} ({dev['platform']}) x{dev['count']}")
-
-    # -- small-file data plane (the reference's weed benchmark workload) ------
-    smallfile = None
-    try:
-        r = _run_probe(["--probe-smallfile", "10000", "16"], timeout=300)
-        if r.returncode == 0 and r.stdout.strip():
-            smallfile = json.loads(r.stdout.strip().splitlines()[-1])
-            smallfile["note"] = (
-                "1KB files, c=16, client+servers share this host's core(s); "
-                "reference baseline: 15,708 w/s, 47,019 r/s on a MacBook i7 "
-                "(README.md:504-538)"
-            )
-            log(
-                f"smallfile: write {smallfile['write']['rps']} req/s "
-                f"p50={smallfile['write']['p50_ms']}ms; read "
-                f"{smallfile['read']['rps']} req/s "
-                f"p50={smallfile['read']['p50_ms']}ms (turbo={smallfile['turbo']})"
-            )
-        else:
-            tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-            log(f"smallfile probe failed: {tail[0][:140]}")
-        # full reference scale: the exact workload behind BASELINE.md's
-        # 15,708 w/s / 47,019 r/s (benchmark.go:71-75 defaults, n=1048576).
-        # The quick n=10k run above keeps signal on constrained hosts; the
-        # full run is attempted whenever the quick run passed and the time
-        # budget allows (~45-60s of actual pump wall at measured rates).
-        # measured ~61s wall on this host (write+read phases ~45s); gate on
-        # the PROJECTED duration from the quick run's measured rates, so a
-        # constrained host doesn't burn the full subprocess timeout
-        projected_s = (
-            1048576 / max(smallfile["write"]["rps"], 1)
-            + 1048576 / max(smallfile["read"]["rps"], 1)
-            if smallfile else float("inf")
-        )
-        if smallfile and projected_s < 600 \
-                and time.perf_counter() - t_setup < 1500:
-            rf = _run_probe(["--probe-smallfile", "1048576", "16"],
-                            timeout=900)
-            if rf.returncode == 0 and rf.stdout.strip():
-                full = json.loads(rf.stdout.strip().splitlines()[-1])
-                full["note"] = (
-                    "FULL reference scale: 1,048,576 × 1KB files, c=16 "
-                    "(benchmark.go defaults); baseline 15,708 w/s / "
-                    "47,019 r/s"
-                )
-                smallfile["full_scale"] = full
-                log(
-                    f"smallfile FULL n=1048576: write "
-                    f"{full['write']['rps']} req/s (failed "
-                    f"{full['write']['failed']}); read {full['read']['rps']} "
-                    f"req/s (failed {full['read']['failed']})"
-                )
-            else:
-                tailf = (rf.stderr or "").strip().splitlines()[-1:] or [""]
-                log(f"smallfile full-scale run failed: {tailf[0][:140]}")
-    except subprocess.TimeoutExpired:
-        log("smallfile probe timed out")
-
-    # -- filer data-plane pipeline (large-file PUT/GET, window sweep) ---------
-    # window=1 is the serial pre-pipeline data plane; window=4 overlaps
-    # chunk fetches on GET and chunk uploads on PUT (util/pipeline.py)
-    filer_pipe = {}
-    for w in (1, 4):
-        try:
-            r = _run_probe(["--probe-filer-pipe", "128", str(w), "2"],
-                           timeout=300)
-            if r.returncode == 0 and r.stdout.strip():
-                filer_pipe[f"window_{w}"] = json.loads(
-                    r.stdout.strip().splitlines()[-1]
-                )
-                fp = filer_pipe[f"window_{w}"]
-                log(
-                    f"filer_pipe window={w}: PUT {fp['put_gbps']:.3f} GB/s, "
-                    f"GET {fp['get_gbps']:.3f} GB/s "
-                    f"(128MB, 2MB chunks, {fp['modeled_rtt_ms']:.0f}ms "
-                    f"modeled volume latency, identical={fp['identical']})"
-                )
-            else:
-                tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-                log(f"filer_pipe probe window={w} failed: {tail[0][:140]}")
-        except subprocess.TimeoutExpired:
-            log(f"filer_pipe probe window={w} timed out")
-    if len(filer_pipe) == 2:
-        w1, w4 = filer_pipe["window_1"], filer_pipe["window_4"]
-        filer_pipe["speedup"] = {
-            "put": round(w4["put_gbps"] / max(w1["put_gbps"], 1e-9), 2),
-            "get": round(w4["get_gbps"] / max(w1["get_gbps"], 1e-9), 2),
-            "byte_identical": w1["sha256"] == w4["sha256"]
-            and w1["identical"] and w4["identical"],
-        }
-        log(
-            f"filer_pipe speedup window=4 vs 1: "
-            f"PUT {filer_pipe['speedup']['put']}x, "
-            f"GET {filer_pipe['speedup']['get']}x, "
-            f"byte_identical={filer_pipe['speedup']['byte_identical']}"
-        )
-
-    # -- serving core: thread-per-connection vs asyncio reactor ---------------
-    # same filer smallfile GET workload, keep-alive connection sweep; the
-    # reactor's case is the high-connection regime where thread-per-conn
-    # burns its wall time on scheduler thrash
-    serving = {}
-    for mode in ("threads", "aio"):
-        try:
-            # the qos isolation phase adds ~20s of fixed-duration paced
-            # traffic on top of the connection sweep
-            r = _run_probe(["--probe-serving", mode, "64,1024", "20000"],
-                           timeout=540)
-            if r.returncode == 0 and r.stdout.strip():
-                serving[mode] = json.loads(r.stdout.strip().splitlines()[-1])
-                for row in serving[mode]["sweep"]:
-                    s, p = row["sat"], row["paced"]
-                    log(
-                        f"serving[{mode}] c={row['conns']}: sat "
-                        f"{s['rps']} req/s p99={s['p99_ms']}ms "
-                        f"failed={s['failed']}; paced {p['rps']} req/s "
-                        f"p50={p['p50_ms']}ms p99={p['p99_ms']}ms "
-                        f"failed={p['failed']} mismatched={p['mismatched']}"
-                    )
-                ss = serving[mode].get("serving_state", {})
-                qos = serving[mode].get("qos", {})
-                log(
-                    f"serving[{mode}] native_hits="
-                    f"{ss.get('native_hits')} fallbacks="
-                    f"{ss.get('native_fallbacks')}; qos compliant p99 "
-                    f"solo={qos.get('compliant_solo_p99_ms')}ms vs "
-                    f"contended={qos.get('compliant_contended_p99_ms')}ms "
-                    f"(greedy shed={qos.get('greedy_shed')}) "
-                    f"isolation_ok={qos.get('isolation_ok')}"
-                )
-            else:
-                tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-                log(f"serving probe [{mode}] failed: {tail[0][:140]}")
-        except subprocess.TimeoutExpired:
-            log(f"serving probe [{mode}] timed out")
-    if len(serving) == 2:
-        by = {
-            (m, row["conns"]): row
-            for m in serving for row in serving[m]["sweep"]
-        }
-        hi = max(c for (_, c) in by)
-        lo = min(c for (_, c) in by)
-        t, a = by.get(("threads", hi)), by.get(("aio", hi))
-        a_lo = by.get(("aio", lo))
-        if t and a and a_lo:
-            p99_hi = a["paced"]["p99_ms"]
-            p99_lo = a_lo["paced"]["p99_ms"]
-            serving["aio_vs_threads"] = {
-                "conns": hi,
-                "sat_rps_ratio": round(
-                    a["sat"]["rps"] / max(t["sat"]["rps"], 1e-9), 2
-                ),
-                "aio_paced_p99_vs_low_conns": round(
-                    p99_hi / max(p99_lo, 1e-9), 2
-                ) if p99_hi and p99_lo else None,
-                "aio_failed": a["sat"]["failed"] + a["paced"]["failed"],
-                "aio_mismatched": (
-                    a["sat"]["mismatched"] + a["paced"]["mismatched"]
-                ),
-            }
-            log(f"serving aio vs threads @c={hi}: "
-                f"{serving['aio_vs_threads']['sat_rps_ratio']}x sat rps; "
-                f"aio paced p99 "
-                f"{serving['aio_vs_threads']['aio_paced_p99_vs_low_conns']}x "
-                f"its c={lo} paced p99")
-
-    # -- tracing tax + the multi-daemon trace tree ---------------------------
-    trace_bench = None
-    try:
-        r = _run_probe(["--probe-trace", "8000", "16"], timeout=420)
-        if r.returncode == 0 and r.stdout.strip():
-            trace_bench = json.loads(r.stdout.strip().splitlines()[-1])
-            put_svcs = (trace_bench.get("put_trace") or {}).get(
-                "services", []
-            )
-            log(
-                f"trace: {trace_bench['rps']['traced']} req/s traced vs "
-                f"{trace_bench['rps']['untraced']} untraced "
-                f"({trace_bench['overhead_pct']}% tax, within 2% budget: "
-                f"{trace_bench['within_budget']}); PUT tree spans "
-                f"{put_svcs}"
-            )
-        else:
-            tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-            log(f"trace probe failed: {tail[0][:140]}")
-    except subprocess.TimeoutExpired:
-        log("trace probe timed out")
-
-    # -- hot-shard path: zipfian storm vs heat rebalance + needle cache -------
-    hotshard = None
-    try:
-        r = _run_probe(["--probe-hotshard", "2000000", "40000"], timeout=600)
-        if r.returncode == 0 and r.stdout.strip():
-            hotshard = json.loads(r.stdout.strip().splitlines()[-1])
-            log(
-                f"hotshard: baseline p99={hotshard['baseline']['p99_ms']}ms "
-                f"→ balanced p99={hotshard['after_balance']['p99_ms']}ms "
-                f"→ cached p99={hotshard['after_cache']['p99_ms']}ms "
-                f"({hotshard['p99_improvement']}x, hit ratio "
-                f"{hotshard['cache_hit_ratio']}, "
-                f"mismatched={hotshard['mismatched']})"
-            )
-        else:
-            tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-            log(f"hotshard probe failed: {tail[0][:140]}")
-    except subprocess.TimeoutExpired:
-        log("hotshard probe timed out")
-
-    # -- active-active replication: lag, outage recovery, dlq drain ----------
-    sync_bench = None
-    try:
-        r = _run_probe(["--probe-sync", "120", "6"], timeout=420)
-        if r.returncode == 0 and r.stdout.strip():
-            sync_bench = json.loads(r.stdout.strip().splitlines()[-1])
-            log(
-                f"sync: steady lag p50={sync_bench['steady']['lag_p50_s']}s "
-                f"max={sync_bench['steady']['lag_max_s']}s, reconverge "
-                f"after {sync_bench['outage_s']}s outage = "
-                f"{sync_bench['time_to_converge_s']}s, dlq depth after = "
-                f"{sync_bench['totals']['dlq_depth']}, redelivered = "
-                f"{sync_bench['totals']['redelivered']}"
-            )
-        else:
-            tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-            log(f"sync probe failed: {tail[0][:140]}")
-    except subprocess.TimeoutExpired:
-        log("sync probe timed out")
-
-    # -- sharded filer fleet: metadata-plane scale-out -----------------------
-    meta_bench = None
-    try:
-        r = _run_probe(["--probe-meta", "480", "16"], timeout=420)
-        if r.returncode == 0 and r.stdout.strip():
-            meta_bench = json.loads(r.stdout.strip().splitlines()[-1])
-            log(
-                f"meta: creates {meta_bench['fleet_1']['creates_per_s']}/s "
-                f"(1 filer) -> {meta_bench['fleet_4']['creates_per_s']}/s "
-                f"(4 filers) = {meta_bench['create_scaling_x']}x, gateways "
-                f"identical={meta_bench['fleet_4']['gateways_identical']}, "
-                f"s3 keys match={meta_bench['fleet_4']['s3_keys_match']}"
-            )
-        else:
-            tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-            log(f"meta probe failed: {tail[0][:140]}")
-    except subprocess.TimeoutExpired:
-        log("meta probe timed out")
-
-    # -- lifecycle autopilot: drifting hot set, live re-tiering --------------
-    lifecycle_bench = None
-    try:
-        r = _run_probe(["--probe-lifecycle", "64", "4000"], timeout=420)
-        if r.returncode == 0 and r.stdout.strip():
-            lifecycle_bench = json.loads(r.stdout.strip().splitlines()[-1])
-            log(
-                f"lifecycle: quiesced p99="
-                f"{lifecycle_bench['quiesced']['p99_ms']}ms → live p99="
-                f"{lifecycle_bench['live']['p99_ms']}ms (ratio "
-                f"{lifecycle_bench['p99_ratio']}), tracking "
-                f"{lifecycle_bench['tracking']['fraction']} "
-                f"(cold moved {lifecycle_bench['tracking']['cold_moved']}/"
-                f"{lifecycle_bench['tracking']['cold_total']}, hot local "
-                f"{lifecycle_bench['tracking']['hot_still_local']}/"
-                f"{lifecycle_bench['tracking']['hot_total']}), s3 bytes "
-                f"{lifecycle_bench['tier']['s3_bytes']}, mismatched="
-                f"{lifecycle_bench['mismatched']}"
-            )
-        else:
-            tail = (r.stderr or "").strip().splitlines()[-1:] or [""]
-            log(f"lifecycle probe failed: {tail[0][:140]}")
-    except subprocess.TimeoutExpired:
-        log("lifecycle probe timed out")
-
-    # -- encode probes in fresh subprocesses ----------------------------------
-    best, best_cfg, best_raw = 0.0, None, 0.0
-    successes = 0
-    # (32,128) measured up to ~77-88 GB/s in r5 probes (tile sweep beyond
-    # 32KB was never tried before); kept second so the best-of-2 early
-    # stop compares it against the long-standing (32,16)
-    for chunk_mb, tile_kb in ((32, 16), (32, 128), (32, 64), (32, 32),
-                              (16, 16), (8, 16)):
-        r = device_probe(["--probe", str(chunk_mb), str(tile_kb)],
-                         f"encode chunk={chunk_mb}MB tile={tile_kb}KB")
-        if r is not None:
-            parts = r.stdout.strip().splitlines()[-1].split()
-            gbps = float(parts[0])
-            raw = float(parts[1]) if len(parts) > 1 else gbps
-            log(
-                f"encode chunk={chunk_mb}MB tile={tile_kb}KB: "
-                f"{gbps:.2f} GB/s sustained ({raw:.2f} incl. dispatch)"
-            )
-            successes += 1
-            if gbps > best:
-                best, best_cfg, best_raw = gbps, (chunk_mb, tile_kb), raw
-        if successes >= 2 and best >= 8.0:
-            break  # enough signal; don't burn bench time
-
-    # -- mesh code path on one chip (certifies multichip inherits the rate) ---
-    mesh_gbps = None
-    for chunk_mb, tile_kb in ((32, 16), (16, 16)):
-        r = device_probe(["--probe-mesh", str(chunk_mb), str(tile_kb)],
-                         f"mesh probe chunk={chunk_mb}MB", timeout=300)
-        if r is not None:
-            mesh_gbps = float(r.stdout.strip().splitlines()[-1])
-            log(
-                f"mesh path (shard_map+fused kernel, 1-device mesh) "
-                f"chunk={chunk_mb}MB tile={tile_kb}KB: {mesh_gbps:.2f} GB/s"
-            )
-            break
-
-    # -- rebuild probe (4-missing-data-shard worst case) ----------------------
-    # matmul_device splits widths beyond chunk_bytes into bounded launches
-    # (one huge Mosaic grid used to RESOURCE_EXHAUST past 64MB), so big
-    # shards run the same chunked path production uses (rebuild_ec_files).
-    # Tile sweep for the rebuild shape too: encode's sweep settled on 16KB
-    # tiles, and the rebuild 4×10 matmul is the same shape class — r4 only
-    # ever ran rebuild at 32KB (VERDICT weak #4). The BEST unpipelined rate
-    # across shard sizes is kept, stopping early once the 8 GB/s bar is
-    # cleared; smaller sizes are the low-HBM fallback.
-    rebuild = None
-    for shard_mb, tile_kb in (
-        (256, 16), (256, 128), (256, 32), (256, 16), (128, 16), (96, 16),
-        (64, 16), (32, 16), (16, 16),
-    ):
-        if rebuild is not None and time.perf_counter() - t_setup > 900:
-            log("rebuild sweep stopped on time budget")
-            break
-        r = device_probe(["--probe-rebuild", str(shard_mb), str(tile_kb)],
-                         f"rebuild shard={shard_mb}MB tile={tile_kb}KB")
-        if r is None:
-            continue
-        p50_s, gbps, pipe_gbps = (float(x) for x in r.stdout.strip().split())
-        log(
-            f"rebuild shard={shard_mb}MB tile={tile_kb}KB: "
-            f"p50={p50_s*1e3:.1f}ms "
-            f"({gbps:.2f} GB/s; sustained kernel {pipe_gbps:.2f} GB/s)"
-        )
-        best_pipe = round(pipe_gbps, 2) if rebuild is None else max(
-            rebuild["pipelined_gbps"], round(pipe_gbps, 2)
-        )
-        if rebuild is None or gbps > rebuild["gbps"]:
-            rebuild = {
-                "p50_s": round(p50_s, 4),
-                "gbps": round(gbps, 2),
-                "pipelined_gbps": round(pipe_gbps, 2),
-                "shard_mb": shard_mb,
-                "tile_kb": tile_kb,
-                "missing": [0, 1, 2, 3],
-            }
-        rebuild["pipelined_gbps"] = best_pipe
-        if rebuild["gbps"] >= 8.0 and rebuild["pipelined_gbps"] >= 60.0:
-            break
-
-    # -- MEASURED 30GB-class rebuild: the chunked stream, full 3GB shards -----
-    if rebuild is not None:
-        for chunk_mb in (32, 16):
-            r = device_probe(["--probe-rebuild-stream", "3", str(chunk_mb)],
-                             f"rebuild-stream chunk={chunk_mb}MB")
-            if r is None:
-                continue
-            p50_s, gbps, n_chunks = r.stdout.strip().split()
-            rebuild["volume30gb_p50_s_measured"] = float(p50_s)
-            rebuild["volume30gb_stream_gbps"] = float(gbps)
-            rebuild["volume30gb_chunks"] = int(float(n_chunks))
-            log(
-                f"30GB-class rebuild (3GB shards, {chunk_mb}MB chunk "
-                f"stream): p50={float(p50_s):.2f}s ({float(gbps):.2f} GB/s)"
-            )
-            break
-
-    # -- end-to-end .dat→shard-files probes ------------------------------------
-    # three sinks isolate the first real bottleneck: disk (production-
-    # shaped), tmpfs (disk removed from both ends), null (shard writes
-    # discarded — pure read+device path)
-    e2e = {}
-    overlap_eff = None
-    for sink in ("disk", "tmpfs", "null"):
-        if sink != "disk" and time.perf_counter() - t_setup > 1400:
-            log(f"e2e [{sink}] skipped on time budget")
-            continue
-        r = device_probe(["--probe-e2e", "128", sink], f"e2e probe [{sink}]")
-        if r is None:
-            continue
-        parts = r.stdout.strip().splitlines()[-1].split()
-        e2e[sink] = {
-            "gbps": float(parts[0]),
-            "efficiency": float(parts[1]),
-            "read_busy_s": float(parts[2]),
-            "compute_busy_s": float(parts[3]),
-            "fetch_busy_s": float(parts[4]),
-            "write_busy_s": float(parts[5]),
-        }
-        if sink == "disk":
-            overlap_eff = float(parts[1])
-        for line in (r.stderr or "").splitlines():
-            if "overlap pipeline" in line:
-                log(line.strip())
-        log(
-            f"e2e [{sink}] .dat→14 shard files (128MB): "
-            f"{e2e[sink]['gbps']:.3f} GB/s"
-        )
-
-    # -- remaining BASELINE.md configs (cpu 1GB, alt geometries, 1-missing) ---
-    # the subprocess's internal sweep guard must sit WELL inside the kill
-    # timeout, or a slow host loses the whole extras JSON (it is printed
-    # only at the end) — including the CPU numbers computed before the
-    # sweep even started
-    extras = None
-    budget_left = time.perf_counter() - t_setup < 1700
-    timeout_s, guard_s = (700, 240) if budget_left else (180, 20)
-    r = device_probe(["--probe-extras", str(guard_s)], "extras probe",
-                     timeout=timeout_s)
-    if r is not None:
-        extras = json.loads(r.stdout.strip().splitlines()[-1])
-        log(f"extras: {extras}")
-
-    # -- roofline: streaming-copy HBM ceiling vs GF-matmul bytes/s ------------
-    roofline = None
-    r = device_probe(["--probe-roofline", "256", "240"], "roofline probe",
-                     timeout=700)
-    if r is not None:
-        roofline = json.loads(r.stdout.strip().splitlines()[-1])
-        log(f"roofline: {roofline}")
-
-    # -- query pushdown: vectorized scan vs pure-Python engine ----------------
-    query_bench = None
-    r = device_probe(["--probe-query", "256"], "query probe", timeout=900)
-    if r is not None:
-        query_bench = json.loads(r.stdout.strip().splitlines()[-1])
-        log(f"query: {query_bench}")
-
-    log(f"best encode: {best:.2f} GB/s at {best_cfg}, total {time.perf_counter() - t_setup:.0f}s")
-    print(
-        json.dumps(
-            {
-                "metric": "ec.encode",
-                "value": round(best, 2),
-                "unit": "GB/s/chip",
-                "vs_baseline": round(best / 8.0, 3),
-                "baseline": "8 GB/s/chip RS(10,4) target (BASELINE.md)",
-                "value_incl_dispatch": round(best_raw, 2),
-                "method": (
-                    "sustained rate from two chained-op lengths (32 vs 160), "
-                    "cancelling the fixed per-chain sync"
-                ),
-                "rebuild": rebuild,
-                "extras": extras,
-                "roofline": roofline,
-                "mesh_single_chip_gbps": mesh_gbps,
-                "smallfile": smallfile,
-                "filer_pipe": filer_pipe,
-                "serving": serving,
-                "trace": trace_bench,
-                "hotshard": hotshard,
-                "sync": sync_bench,
-                "meta_shard": meta_bench,
-                "lifecycle": lifecycle_bench,
-                "e2e": e2e,
-                "device_failures": device_failures,
-                "overlap_efficiency": overlap_eff,
-                "query": query_bench,
-                "config": {
-                    "rs": [10, 4],
-                    "kernel": "pallas-fused",
-                    "chunk_mb": best_cfg[0] if best_cfg else None,
-                    "pallas_tile_kb": best_cfg[1] if best_cfg else None,
-                    "device": dev["device_kind"],
-                    "platform": dev["platform"],
-                    "device_count": dev["count"],
-                },
-            }
-        )
-    )
-    if device_failures:
-        log("device probes FAILED: " + "; ".join(device_failures))
-        sys.exit(1)
-
-
 if __name__ == "__main__":
-    if sys.argv[1:2] == ["--probe-gate"]:
-        probe_gate()
-    elif len(sys.argv) >= 4 and sys.argv[1] == "--probe":
-        probe_encode(int(sys.argv[2]), int(sys.argv[3]))
-    elif len(sys.argv) >= 4 and sys.argv[1] == "--probe-rebuild":
-        probe_rebuild(int(sys.argv[2]), int(sys.argv[3]))
-    elif len(sys.argv) >= 4 and sys.argv[1] == "--probe-mesh":
-        probe_mesh(int(sys.argv[2]), int(sys.argv[3]))
-    elif len(sys.argv) >= 4 and sys.argv[1] == "--probe-rebuild-stream":
-        probe_rebuild_stream(int(sys.argv[2]), int(sys.argv[3]))
-    elif sys.argv[1:2] == ["--probe-extras"]:
-        probe_extras(float(sys.argv[2]) if len(sys.argv) > 2 else 240.0)
-    elif sys.argv[1:2] == ["--probe-roofline"]:
-        probe_roofline(int(sys.argv[2]) if len(sys.argv) > 2 else 256,
-                       float(sys.argv[3]) if len(sys.argv) > 3 else 240.0)
-    elif sys.argv[1:2] == ["--probe-query"]:
+    if sys.argv[1:2] == ["--probe-query"]:
         probe_query(int(sys.argv[2]) if len(sys.argv) > 2 else 256)
     elif len(sys.argv) >= 4 and sys.argv[1] == "--probe-smallfile":
         probe_smallfile(int(sys.argv[2]), int(sys.argv[3]))
@@ -3231,8 +1985,5 @@ if __name__ == "__main__":
             int(sys.argv[2]) if len(sys.argv) > 2 else 2_000_000,
             int(sys.argv[3]) if len(sys.argv) > 3 else 40_000,
         )
-    elif len(sys.argv) >= 3 and sys.argv[1] == "--probe-e2e":
-        probe_e2e(int(sys.argv[2]),
-                  sys.argv[3] if len(sys.argv) > 3 else "disk")
     else:
-        main()
+        sys.exit(__doc__)

@@ -1,4 +1,5 @@
-"""RS codecs: encode/reconstruct with pluggable backends (tpu | cpu | numpy).
+"""RS codecs: encode/reconstruct with pluggable backends
+(tpu | mesh | cpu | numpy).
 
 All backends compute the same function — GF(2^8) matmul with the
 klauspost-compatible matrix (gf.build_matrix) — so shard bytes are identical
@@ -7,9 +8,17 @@ regardless of where they were computed. Mirrors the reference's use of
 `weed/storage/erasure_coding/ec_encoder.go:179,270`,
 `weed/storage/store_ec.go:367`).
 
-The TPU backend expresses the GF(2^8) matmul as a GF(2) bit-matrix matmul:
-bytes are unpacked to bits, multiplied by the 8×-expanded bit matrix with an
-int8 MXU matmul, reduced mod 2, and repacked. See gf.gf_matrix_to_bit_matrix.
+`Codec` is the whole interface the file-level encoder (ec/encoder.py)
+uses: the shard counts and matrices, ``chunk_bytes``, ``alignment()``,
+``device_put``, ``matmul_device`` and ``device_memory_free()``. A host
+codec (`NumpyCodec`, `CpuCodec`) implements ``matmul`` and inherits the
+rest: its "device" is the host's memory. The JAX codecs (`TpuCodec` here,
+`MeshCodec` in ec/sharded.py) share `JaxCodec` and express the GF(2^8)
+matmul as a GF(2) bit-matrix matmul: bytes are unpacked to bits,
+multiplied by the 8×-expanded bit matrix with an int8 MXU matmul, reduced
+mod 2, and repacked — fused in one Pallas kernel on a TPU
+(`build_pallas_gf_matmul`), as plain XLA elsewhere (`xla_gf_matmul`). See
+gf.gf_matrix_to_bit_matrix.
 """
 
 from __future__ import annotations
@@ -28,7 +37,13 @@ from .constants import DATA_SHARDS, PARITY_SHARDS
 
 
 class Codec:
-    """Base: shard-count bookkeeping + reconstruct planning (host-side)."""
+    """Base: shard-count bookkeeping + reconstruct planning (host-side),
+    and the interface the encoder's pipeline drives. A backend implements
+    ``matmul``; one that holds a device also overrides the staging hooks
+    below."""
+
+    # columns of a chunk the encoder hands to one matmul_device call
+    chunk_bytes = 8 * 1024 * 1024
 
     def __init__(self, data_shards: int = DATA_SHARDS, parity_shards: int = PARITY_SHARDS):
         self.data_shards = data_shards
@@ -37,23 +52,36 @@ class Codec:
         self.matrix = gf.build_matrix(data_shards, self.total_shards)
         self.parity_rows = self.matrix[data_shards:]
 
-    # -- backend hook --------------------------------------------------------
+    # -- backend hooks -------------------------------------------------------
     def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
         """(R×k GF matrix) @ (k×N bytes) → (R×N bytes). Backend-specific."""
         raise NotImplementedError
 
-    # Backends whose matmul accepts an ``out=`` result buffer (reused across
-    # streaming chunks — allocating a fresh parity buffer per call costs page
-    # faults comparable to the matmul itself at native-kernel rates).
-    supports_out = False
+    def alignment(self) -> int:
+        """Column widths fed to matmul_device must be multiples of this."""
+        return 1
+
+    def device_put(self, data: np.ndarray):
+        """Stage (k, N) host bytes where matmul_device wants them. A host
+        codec computes on them where they are."""
+        return data
+
+    def matmul_device(self, matrix: np.ndarray, data):
+        """``matmul`` on staged data (a `device_put`), N a multiple of
+        `alignment`; the result stays where it was computed until the
+        caller copies it back (``np.asarray``)."""
+        return self.matmul(matrix, np.asarray(data))
+
+    def device_memory_free(self) -> Optional[int]:
+        """Free bytes of the tightest device's memory, or None where
+        nothing bounds a chunk but the host's memory."""
+        return None
 
     # -- public API ----------------------------------------------------------
-    def encode(self, data: np.ndarray, out: "np.ndarray | None" = None) -> np.ndarray:
+    def encode(self, data: np.ndarray) -> np.ndarray:
         """data (k, N) → parity (m, N)."""
         if data.shape[0] != self.data_shards:
             raise ValueError(f"expected {self.data_shards} data rows, got {data.shape[0]}")
-        if out is not None and self.supports_out:
-            return self.matmul(self.parity_rows, data, out=out)
         return self.matmul(self.parity_rows, data)
 
     def encode_shards(self, data: np.ndarray) -> np.ndarray:
@@ -148,7 +176,6 @@ class NumpyCodec(Codec):
     blob — the old path walked the full mul table per call."""
 
     _BLOCK = 1 << 16  # per-row block bytes; (k+R)·block stays L2-resident
-    supports_out = True
     backend = kernel = "numpy"
 
     def __init__(self, *args, **kwargs):
@@ -163,19 +190,11 @@ class NumpyCodec(Codec):
             self._tab_cache[key] = cached
         return cached
 
-    def matmul(
-        self,
-        matrix: np.ndarray,
-        data: np.ndarray,
-        out: "np.ndarray | None" = None,
-    ) -> np.ndarray:
+    def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
         tabs = self._tables(matrix)  # (R, k, 2, 16)
         rows, k = matrix.shape
         n = data.shape[1]
-        if out is None:
-            out = np.zeros((rows, n), dtype=np.uint8)
-        else:
-            out[:] = 0
+        out = np.zeros((rows, n), dtype=np.uint8)
         for pos in range(0, n, self._BLOCK):
             blk = data[:, pos : pos + self._BLOCK]
             lo_idx = blk & 0x0F
@@ -215,16 +234,9 @@ class CpuCodec(Codec):
             self._prep_cache[key] = cached
         return cached
 
-    supports_out = True
-
-    def matmul(
-        self,
-        matrix: np.ndarray,
-        data: np.ndarray,
-        out: "np.ndarray | None" = None,
-    ) -> np.ndarray:
+    def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
         matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-        return self._lib.rs_matmul(matrix, data, prep=self._prep(matrix), out=out)
+        return self._lib.rs_matmul(matrix, data, prep=self._prep(matrix))
 
 
 class LaunchCounter:
@@ -299,7 +311,150 @@ def build_pallas_gf_matmul(jax, n_out_rows: int, k: int, n_cols: int,
     )
 
 
-class TpuCodec(Codec):
+def xla_gf_matmul(jax, bitmat, data, tp_axis: Optional[str] = None):
+    """The XLA formulation of the GF(2^8) matmul, over the last two axes
+    of ``data`` uint8[..., k, n]: unpack → int8 bit-matmul → mod 2 →
+    repack, with ``bitmat`` int8[8R, 8k] (gf.gf_matrix_to_bit_matrix) →
+    uint8[..., R, n]. What runs wherever the fused Pallas kernel does not
+    (CPU tests); traced inside a jit or a shard_map body.
+
+    ``tp_axis`` names a mesh axis the bit-contraction is split over:
+    ``bitmat`` is then this device's int8[8R, 8k/tp] column slice, the
+    device contracts it against its slice of the bits, and the partial
+    counts are summed over the axis before the mod 2 (XOR is addition
+    mod 2, so summing counts commutes with it)."""
+    jnp = jax.numpy
+    *lead, k, n = data.shape
+    shifts = jnp.arange(8, dtype=jnp.uint8)
+    bits = (data[..., None, :] >> shifts[:, None]) & jnp.uint8(1)
+    bits = bits.reshape(*lead, k * 8, n).astype(jnp.int8)
+    if tp_axis is not None:
+        rows = bitmat.shape[1]
+        bits = jax.lax.dynamic_slice_in_dim(
+            bits, jax.lax.axis_index(tp_axis) * rows, rows, axis=-2
+        )
+    acc = jnp.einsum(
+        "ok,...kn->...on", bitmat, bits, preferred_element_type=jnp.int32
+    )
+    if tp_axis is not None:
+        acc = jax.lax.psum(acc, axis_name=tp_axis)
+    out_bits = (acc & 1).astype(jnp.uint8).reshape(*lead, -1, 8, n)
+    weights = (jnp.uint8(1) << shifts)[:, None]
+    return jnp.sum(out_bits * weights, axis=-2, dtype=jnp.uint32).astype(jnp.uint8)
+
+
+class JaxCodec(Codec):
+    """What the JAX-backed codecs share: the kernel choice, the launch
+    counts, the jit and bit-matrix caches, the ``ec_codec`` object of
+    /status, the HBM budget and the one host-side ``matmul`` loop. A
+    subclass places data (``alignment``, ``device_put``) and launches
+    (``matmul_device``). `TpuCodec` and `MeshCodec` are siblings, each
+    with a ``matmul`` and a ``matmul_device`` of its own to ``getattr``:
+    a tracer that wraps both classes' methods wraps each call once, where
+    one codec subclassing the other would nest the wrappers."""
+
+    mesh = None  # a jax.sharding.Mesh where launches are sharded over one
+
+    def __init__(self, data_shards: int = DATA_SHARDS,
+                 parity_shards: int = PARITY_SHARDS, *, devices,
+                 chunk_bytes: int, use_pallas: Optional[bool],
+                 pallas_tile: int, pallas_interpret: bool):
+        super().__init__(data_shards, parity_shards)
+        self._jax = jaxenv.import_jax()
+        self.devices = list(devices)
+        self.chunk_bytes = chunk_bytes
+        if use_pallas is None:
+            # Mosaic (the Pallas TPU compiler) needs a real TPU
+            use_pallas = all(d.platform == "tpu" for d in self.devices)
+        self.use_pallas = use_pallas
+        self.pallas_tile = pallas_tile
+        self._pallas_interpret = pallas_interpret
+        if not use_pallas:
+            self.kernel = "xla"
+        else:
+            self.kernel = "pallas-interpret" if pallas_interpret else "pallas"
+        self._jit_cache: dict = {}
+        self._bitmat_cache: dict = {}
+        # device launches by kernel, so /status shows that no launch took a
+        # path the operator did not ask for
+        self.launches = LaunchCounter()
+
+    def describe(self) -> dict:
+        """The ``ec_codec`` object of a volume server's /status: enough for
+        an operator (or chip_smoke.py) to assert the device, the kernel and
+        the compile cache through the normal entry point, without importing
+        JAX beside the daemon."""
+        jax = self._jax
+        first = self.devices[0]
+        return {
+            "backend": self.backend,
+            "platform": first.platform,
+            "device_kind": first.device_kind,
+            "device_count": jax.device_count(),
+            "mesh": dict(self.mesh.shape) if self.mesh is not None else None,
+            "kernel": self.kernel,
+            "pallas_tile": self.pallas_tile,
+            "launches": self.launches.snapshot(),
+            "devices": [
+                {
+                    "id": d.id,
+                    "peak_bytes_in_use": (d.memory_stats() or {}).get(
+                        "peak_bytes_in_use"
+                    ),
+                }
+                for d in self.devices
+            ],
+            "compile_cache_dir": jaxenv.compile_cache_dir(),
+            "compiles": jaxenv.compile_counts(),
+            "x64": bool(jax.config.jax_enable_x64),
+            "versions": {
+                "jax": jax.__version__,
+                "jaxlib": _dist_version("jaxlib"),
+                "libtpu": _dist_version("libtpu"),
+                "runtime": first.client.platform_version,
+            },
+        }
+
+    def device_memory_free(self) -> Optional[int]:
+        """Free HBM bytes of the tightest of the codec's devices (every
+        device holds the same share of each chunk, so the fullest one
+        bounds the chunk): a snapshot, so callers budget with headroom.
+        None only where the platform keeps no allocator stats (the CPU
+        backend); a TPU that reports none is an error, not a licence to
+        skip the budget."""
+        free = [_device_memory_free(d) for d in self.devices]
+        return None if None in free else min(free)
+
+    def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
+        out_rows, _ = matrix.shape
+        n = data.shape[1]
+
+        # One chunk/pad/slice loop for every kernel. Every chunk (tails
+        # included) is padded to an alignment multiple: zeros encode to zeros
+        # and are sliced off, and fixed widths bound the set of compiled
+        # kernel shapes (Mosaic pays seconds per new shape, and arbitrary
+        # tail widths would hand it unaligned lane dimensions).
+        align = self.alignment()
+        out = np.empty((out_rows, n), dtype=np.uint8)
+        pos = 0
+        while pos < n:
+            end = min(pos + self.chunk_bytes, n)
+            piece = data[:, pos:end]
+            width = end - pos
+            if width % align:
+                padded = align * -(-width // align)
+                piece = np.pad(piece, ((0, 0), (0, padded - width)))
+            # one synchronous round trip: stage, launch, copy back
+            with trace.stage_span("ec.codec.launch", bytes=piece.nbytes):
+                res = np.asarray(
+                    self.matmul_device(matrix, self.device_put(piece))
+                )
+            out[:, pos:end] = res[:, :width]
+            pos = end
+        return out
+
+
+class TpuCodec(JaxCodec):
     """JAX bit-matmul kernel on the process's default JAX device.
 
     On a TPU the fused Pallas kernel (Mosaic) is the only kernel; on any
@@ -324,32 +479,17 @@ class TpuCodec(Codec):
         pallas_interpret: bool = False,
         **kwargs,
     ):
-        super().__init__(*args, **kwargs)
-        # deferred so numpy/cpu paths never require jax
-        self._jax = jax = jaxenv.import_jax()
         if chunk_bytes % tile_bytes:
             raise ValueError("chunk_bytes must be a multiple of tile_bytes")
-        self.chunk_bytes = chunk_bytes
-        self.tile_bytes = tile_bytes
         # a backend that cannot start (chip held by another process, no
         # runtime) raises here, at construction — never a quiet XLA fallback
-        self.device = jax.devices()[0]
-        self.devices = [self.device]
-        if use_pallas is None:
-            # Mosaic (the Pallas TPU compiler) needs a real TPU
-            use_pallas = self.device.platform == "tpu"
-        self.use_pallas = use_pallas
-        self.pallas_tile = pallas_tile
-        self._pallas_interpret = pallas_interpret
-        self._jit_cache: dict = {}
-        self._bitmat_cache: dict = {}
-        # device launches by kernel, so /status shows that no launch took a
-        # path the operator did not ask for
-        self.launches = LaunchCounter()
-        self.kernel = _jax_kernel_name(use_pallas, pallas_interpret)
-
-    def describe(self) -> dict:
-        return _describe_jax_codec(self, None)
+        super().__init__(
+            *args, devices=jaxenv.import_jax().devices()[:1],
+            chunk_bytes=chunk_bytes, use_pallas=use_pallas,
+            pallas_tile=pallas_tile, pallas_interpret=pallas_interpret,
+            **kwargs,
+        )
+        self.tile_bytes = tile_bytes
 
     def _kernel(self, n_out_rows: int, k: int):
         """Jitted tiled bit-matmul for a (n_out_rows × k) matrix shape.
@@ -366,33 +506,16 @@ class TpuCodec(Codec):
             lax = jax.lax
             tile = self.tile_bytes
 
-            def matmul_tile(bitmat, data_tile):
-                kk, n = data_tile.shape
-                shifts = jnp.arange(8, dtype=jnp.uint8)
-                bits = (data_tile[:, None, :] >> shifts[None, :, None]) & jnp.uint8(1)
-                bits = bits.reshape(kk * 8, n).astype(jnp.int8)
-                acc = lax.dot_general(
-                    bitmat,
-                    bits,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                )
-                out_bits = (acc & 1).astype(jnp.uint8).reshape(-1, 8, n)
-                weights = (jnp.uint8(1) << shifts)[None, :, None]
-                return jnp.sum(out_bits * weights, axis=1, dtype=jnp.uint32).astype(
-                    jnp.uint8
-                )
-
             @jax.jit
             def gf_bit_matmul(bitmat, data):
                 kk, n = data.shape
                 if n <= tile:
-                    return matmul_tile(bitmat, data)
+                    return xla_gf_matmul(jax, bitmat, data)
                 n_tiles = n // tile  # callers pad chunks to tile multiples
 
                 def body(i, out):
                     piece = lax.dynamic_slice(data, (0, i * tile), (kk, tile))
-                    res = matmul_tile(bitmat, piece)
+                    res = xla_gf_matmul(jax, bitmat, piece)
                     return lax.dynamic_update_slice(out, res, (0, i * tile))
 
                 out = jnp.zeros((bitmat.shape[0] // 8, n), dtype=jnp.uint8)
@@ -430,8 +553,8 @@ class TpuCodec(Codec):
         return fn
 
     def _bitmat(self, matrix: np.ndarray, planewise: bool = False):
-        """Device-resident bit matrix, cached so repeated calls (e.g. the
-        benchmark's timed loop) don't re-expand or re-upload it."""
+        """Device-resident bit matrix, cached so repeated calls (a seal's
+        chunks, a degraded read's decode) don't re-expand or re-upload it."""
         key = (matrix.tobytes(), planewise)
         cached = self._bitmat_cache.get(key)
         if cached is None:
@@ -441,19 +564,11 @@ class TpuCodec(Codec):
         return cached
 
     def alignment(self) -> int:
-        """Column widths fed to matmul_device must be multiples of this."""
         return self.pallas_tile if self.use_pallas else self.tile_bytes
 
     def device_put(self, data: np.ndarray):
         """Stage host bytes into HBM (async; the overlap pipeline's H2D leg)."""
         return self._jax.device_put(data)
-
-    def device_memory_free(self) -> Optional[int]:
-        """Free HBM bytes on the codec's device: a snapshot, so callers
-        budget with headroom. None only where the platform keeps no
-        allocator stats (the CPU backend); a TPU that reports none is an
-        error, not a licence to skip the budget."""
-        return _device_memory_free(self.device)
 
     def matmul_device(self, matrix: np.ndarray, data_dev):
         """Device-resident matmul: data_dev is a jax array (k, N) already in
@@ -461,8 +576,8 @@ class TpuCodec(Codec):
         tile). Widths beyond chunk_bytes are split into chunk-sized launches
         (one huge Mosaic grid would materialise grid-wide buffers and
         RESOURCE_EXHAUST; bounded launches stream through the same HBM
-        working set regardless of N). This is the zero-copy path used by the
-        benchmark and the streaming encoder's overlap pipeline."""
+        working set regardless of N). The zero-copy path of the streaming
+        encoder's overlap pipeline."""
         n = data_dev.shape[1]
         if n > self.chunk_bytes:
             outs = []
@@ -488,33 +603,6 @@ class TpuCodec(Codec):
         self.launches.add("xla")
         return kernel(self._bitmat(matrix), data_dev)
 
-    def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
-        jnp = self._jax.numpy
-        out_rows, _ = matrix.shape
-        n = data.shape[1]
-
-        # One chunk/pad/slice loop for both kernels. Every chunk (tails
-        # included) is padded to an alignment multiple: zeros encode to zeros
-        # and are sliced off, and fixed widths bound the set of compiled
-        # kernel shapes (Mosaic pays seconds per new shape, and arbitrary
-        # tail widths would hand it unaligned lane dimensions).
-        align = self.pallas_tile if self.use_pallas else self.tile_bytes
-        out = np.empty((out_rows, n), dtype=np.uint8)
-        pos = 0
-        while pos < n:
-            end = min(pos + self.chunk_bytes, n)
-            piece = data[:, pos:end]
-            width = end - pos
-            if width % align:
-                padded = align * -(-width // align)
-                piece = np.pad(piece, ((0, 0), (0, padded - width)))
-            # one synchronous round trip: stage, launch, copy back
-            with trace.stage_span("ec.codec.launch", bytes=piece.nbytes):
-                res = np.asarray(self.matmul_device(matrix, jnp.asarray(piece)))
-            out[:, pos:end] = res[:, :width]
-            pos = end
-        return out
-
 
 def _device_memory_free(device) -> Optional[int]:
     stats = device.memory_stats()
@@ -526,50 +614,6 @@ def _device_memory_free(device) -> Optional[int]:
             )
         return None
     return max(0, stats["bytes_limit"] - stats["bytes_in_use"])
-
-
-def _jax_kernel_name(use_pallas: bool, interpret: bool) -> str:
-    if not use_pallas:
-        return "xla"
-    return "pallas-interpret" if interpret else "pallas"
-
-
-def _describe_jax_codec(codec, mesh_shape) -> dict:
-    """The ``ec_codec`` object of a volume server's /status for a
-    JAX-backed codec: enough for an operator (or chip_smoke.py) to assert
-    the device, the kernel and the compile cache through the normal entry
-    point, without importing JAX beside the daemon."""
-    jax = codec._jax
-    devices = codec.devices
-    first = devices[0]
-    return {
-        "backend": codec.backend,
-        "platform": first.platform,
-        "device_kind": first.device_kind,
-        "device_count": jax.device_count(),
-        "mesh": mesh_shape,
-        "kernel": codec.kernel,
-        "pallas_tile": codec.pallas_tile,
-        "launches": codec.launches.snapshot(),
-        "devices": [
-            {
-                "id": d.id,
-                "peak_bytes_in_use": (d.memory_stats() or {}).get(
-                    "peak_bytes_in_use"
-                ),
-            }
-            for d in devices
-        ],
-        "compile_cache_dir": jaxenv.compile_cache_dir(),
-        "compiles": jaxenv.compile_counts(),
-        "x64": bool(jax.config.jax_enable_x64),
-        "versions": {
-            "jax": jax.__version__,
-            "jaxlib": _dist_version("jaxlib"),
-            "libtpu": _dist_version("libtpu"),
-            "runtime": first.client.platform_version,
-        },
-    }
 
 
 @functools.lru_cache(maxsize=None)  # /status is polled; two names, ever

@@ -331,16 +331,14 @@ def _budgeted_chunk(codec, chunk: int, device_streams: int) -> int:
     ~device_streams×chunk bytes in HBM (k input rows staged + output rows
     produced). Only a quarter of the reported free pool is budgeted;
     oversized chunks are split rather than dying with RESOURCE_EXHAUSTED
-    (VERDICT r3 weak #1). Host codecs, and JAX on the CPU platform, keep no
-    allocator stats and keep the requested chunk; a TPU that reports none
-    raises in device_memory_free."""
-    if not hasattr(codec, "device_memory_free"):  # host codec
-        return chunk
+    (VERDICT r3 weak #1). A codec that reports no bound (host codecs, JAX
+    on the CPU platform) keeps the requested chunk; a TPU that reports no
+    allocator stats raises in device_memory_free."""
     free = codec.device_memory_free()
-    if free is None:  # JAX on the CPU platform
+    if free is None:
         return chunk
     cap = free // (4 * 3 * max(1, device_streams))
-    align = codec.alignment() if hasattr(codec, "alignment") else 1
+    align = codec.alignment()
     cap = max(align, (cap // align) * align)
     return min(chunk, cap)
 
@@ -352,26 +350,17 @@ def plan_encode(
     small_block_size: int = SMALL_BLOCK_SIZE,
     chunk_bytes: Optional[int] = None,
 ) -> tuple[int, list]:
-    """The encode work plan — one source of truth for write_ec_files AND
-    for callers that must know the plan up front (bench.py warms every
-    Mosaic kernel shape the timed run will launch; a drifted re-derivation
-    would compile inside the timed region and skew the published rate).
+    """The encode work plan of `write_ec_files`: the column chunk and the
+    work items (`_work_items`) that cover a .dat of ``dat_size`` bytes.
 
     Returns ``(chunk, items)``. An explicit ``chunk_bytes`` fixes the
     pipeline depth (no _depth_chunk re-split) but is still capped against
     free HBM — the caller owns the plan's shape, not its memory safety
     (rebuild_ec_files applies the same cap to explicit chunks)."""
     k = codec.data_shards
-    chunk = (
-        chunk_bytes if chunk_bytes is not None
-        else getattr(codec, "chunk_bytes", 8 * 1024 * 1024)
-    )
+    chunk = chunk_bytes if chunk_bytes is not None else codec.chunk_bytes
     chunk = _budgeted_chunk(codec, chunk, k + codec.parity_shards)
-    if (
-        chunk_bytes is None
-        and hasattr(codec, "matmul_device")
-        and chunk >= small_block_size
-    ):
+    if chunk_bytes is None and chunk >= small_block_size:
         chunk = _depth_chunk(chunk, -(-dat_size // k), small_block_size)
     items = _work_items(dat_size, k, large_block_size, small_block_size, chunk)
     return chunk, items
@@ -472,7 +461,6 @@ def write_ec_files(
     large_block_size: int = LARGE_BLOCK_SIZE,
     small_block_size: int = SMALL_BLOCK_SIZE,
     chunk_bytes: Optional[int] = None,
-    plan: Optional[tuple] = None,
     suffix: str = "",
 ) -> list[str]:
     """Generate all shard files from ``base.dat`` (WriteEcFiles, :57) and
@@ -483,41 +471,33 @@ def write_ec_files(
     path (Store.ec_encode_volume) passes ``".tmp"`` so the shard set is
     staged and only appears under its final names after the commit
     manifest is durable; the bare call writes final names directly (tools,
-    tests, bench).
+    tests).
 
-    ``plan`` — a ``(chunk, items)`` pair from :func:`plan_encode` for the
-    same volume. Callers that pre-warmed kernel shapes against a plan
-    (bench.py) pass it here verbatim; re-deriving internally could read a
-    different free-HBM figure and split chunks the warm loop never saw,
-    compiling inside the timed region. Without ``plan``, the plan is
-    derived here (and an explicit ``chunk_bytes`` is still budget-capped).
-
-    Device-backed codecs (TpuCodec, MeshCodec — anything with
-    ``matmul_device``) run a 4-leg overlap pipeline: a reader thread
-    streams column chunks off disk, the main thread stages them into HBM and
-    dispatches the (async) encode kernel, a fetch thread blocks on each
+    Every codec runs the 4-leg overlap pipeline (`_encode_pipelined`): a
+    reader thread streams column chunks off disk, the main thread stages
+    them (``codec.device_put``: into HBM, or nowhere for a host codec) and
+    dispatches the encode (``codec.matmul_device``: an async kernel launch,
+    or the host's matmul then and there), a fetch thread blocks on each
     chunk's parity (the D2H leg), and a writer thread appends the 14 shard
     files and feeds their digests, the fourteen rows of a chunk side by
     side. Disk read, H2D copy, compute, D2H and file writes for
     neighbouring chunks overlap — the reference's
     serial 256KB read→Encode→write loop (`ec_encoder.go:162-192`) turned into
-    a pipeline sized for a TPU. Host-only codecs keep the serial loop.
+    a pipeline sized for a TPU.
 
-    Either way a chunk is read ONCE, each block of the .dat straight to
-    its place in a ``(k, width)`` matrix (`_read_item`: row ``i`` is the
-    chunk's columns of shard ``i``), into a buffer of a bounded per-call
-    pool (`_ChunkBuffers`). The pipeline's buffers belong to the reader
-    until a chunk is read, then travel with the chunk through dispatch
-    and fetch to the writer, which returns each to the pool once the ten
-    data rows are in the shard files (`_encode_pipelined`); the serial
-    loop consumes a chunk before it reads the next, so one buffer serves
-    it. A chunk of zeros (a hole, or past EOF) takes no buffer.
+    A chunk is read ONCE, each block of the .dat straight to its place in
+    a ``(k, width)`` matrix (`_read_item`: row ``i`` is the chunk's columns
+    of shard ``i``), into a buffer of a bounded per-call pool
+    (`_ChunkBuffers`). A buffer belongs to the reader until a chunk is
+    read, then travels with the chunk through dispatch and fetch to the
+    writer, which returns it to the pool once the ten data rows are in the
+    shard files. A chunk of zeros (a hole, or past EOF) takes no buffer.
     """
     codec = codec or get_codec()
     k, m = codec.data_shards, codec.parity_shards
     dat = base_file_name + ".dat"
     dat_size = os.path.getsize(dat)
-    _, items = plan or plan_encode(
+    _, items = plan_encode(
         codec, dat_size, large_block_size, small_block_size, chunk_bytes
     )
 
@@ -526,41 +506,7 @@ def write_ec_files(
         for i in range(k + m)
     ])
     try:
-        if hasattr(codec, "matmul_device"):
-            _encode_pipelined(dat, items, codec, shards, dat_size)
-        else:
-            # the parity buffer is consumed (written out) before the next
-            # chunk encodes, so one buffer serves the whole stream — a fresh
-            # allocation per chunk pays first-touch page faults comparable
-            # to the native kernel's own runtime. Likewise the chunk's own.
-            parity_buf = None
-            buffers = _ChunkBuffers("ec.seal", _chunk_nbytes(items, k), count=1)
-            with open(dat, "rb") as f:
-                fd = f.fileno()
-                for item in items:
-                    faultpoints.fire("ec.encode.chunk", path=shards.name)
-                    width = _item_width(item)
-                    segments = _item_segments(fd, item, k, dat_size)
-                    data = None
-                    if segments:
-                        data = buffers.take(k, width)
-                        _read_item(fd, item, segments, data)
-                    if data is None or not data.any():
-                        # zeros encode to zeros: skip the matmul and leave
-                        # holes in the shard files (sparse sealed volumes —
-                        # preallocated space, punched deletes — stay sparse
-                        # and cheap; finish() fixes trailing sizes)
-                        shards.skip(width)
-                    else:
-                        if getattr(codec, "supports_out", False):
-                            if parity_buf is None or parity_buf.shape[1] != width:
-                                parity_buf = np.empty((m, width), dtype=np.uint8)
-                            parity = codec.encode(data, out=parity_buf)
-                        else:
-                            parity = codec.encode(data)
-                        shards.append([*data, *parity])
-                    if data is not None:
-                        buffers.give(data)
+        _encode_pipelined(dat, items, codec, shards, dat_size)
         return shards.finish(
             ec_shard_base_size(dat_size, k, large_block_size, small_block_size)
         )
@@ -573,8 +519,7 @@ def _chunk_nbytes(items, k: int) -> int:
     return k * max(map(_item_width, items), default=0)
 
 
-def _overlap_pipeline(produce, compute, consume, fetch=None,
-                      stats: Optional[dict] = None,
+def _overlap_pipeline(produce, compute, consume, fetch,
                       op: str = "ec.overlap",
                       buffers: Optional[_ChunkBuffers] = None) -> None:
     """Four-stage overlap shared by encode and rebuild: a reader thread
@@ -590,8 +535,7 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     concurrently with D2H of chunk i (the transfer directions are
     independent); folding the blocking D2H into the writer (the r4 shape)
     left dispatch serialized behind it — wall was ~1.5× the slowest stage
-    even with writes discarded. ``fetch=None`` degrades to the 3-stage
-    form for host-only callers.
+    even with writes discarded.
 
     ``buffers`` is the pool the caller's chunks live in, if they do. The
     reader owns a buffer from the moment `produce` takes it (between two
@@ -609,14 +553,9 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     around it, and the callables count the bytes they move against them
     (``trace.add_stage_bytes``); the threads run in copies of the caller's
     context, so the spans of one seal are one tree. The totals are served
-    in /status (``ec_codec.stages``).
-
-    A ``stats`` dict is this call's view of the same spans: per-stage BUSY
-    time and wall time, plus ``efficiency`` = max(stage busy) / wall — 1.0
-    means the slowest stage fully hides the others, i.e. wall ≈ max(stage)
-    rather than Σ(stages), which is the whole point vs the reference's
-    serial read→Encode→write loop (ec_encoder.go:162-192). It is filled
-    only while tracing is on (``SWEED_TRACE``)."""
+    in /status (``ec_codec.stages``): wall ≈ max(stage busy) rather than
+    Σ(stages) is the whole point vs the reference's serial
+    read→Encode→write loop (ec_encoder.go:162-192)."""
     import contextvars
     import queue
 
@@ -627,7 +566,6 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
     fetch_q: queue.Queue = queue.Queue(maxsize=1)
     write_q: queue.Queue = queue.Queue(maxsize=1)
     errors: list[BaseException] = []
-    busy = {"read": 0.0, "dispatch": 0.0, "fetch": 0.0, "write": 0.0}
 
     def fail(e: BaseException) -> None:
         errors.append(e)
@@ -636,11 +574,8 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
 
     def run_leg(leg, fn, *got):
         """One chunk through one leg, inside that leg's stage span."""
-        with trace.stage_span(f"{op}.{leg}") as span:
-            out = fn(*got)
-        if span is not None:
-            busy[leg] += span.duration  # each thread adds to its own leg
-        return out
+        with trace.stage_span(f"{op}.{leg}"):
+            return fn(*got)
 
     def reader():
         try:
@@ -689,15 +624,13 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
             target=contextvars.copy_context().run, args=(target,), daemon=True
         )
 
-    mid_q = fetch_q if fetch is not None else write_q
-    with trace.stage_span(f"{op}.pipeline", quiet=True) as whole:
+    with trace.stage_span(f"{op}.pipeline", quiet=True):
         rt = thread(reader)
         wt = thread(writer)
-        ft = thread(fetcher) if fetch is not None else None
+        ft = thread(fetcher)
         rt.start()
         wt.start()
-        if ft is not None:
-            ft.start()
+        ft.start()
         try:
             while True:
                 got = read_q.get()
@@ -706,13 +639,12 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
                 if errors:
                     continue  # keep draining so the reader can finish
                 try:
-                    mid_q.put(run_leg("dispatch", compute, got))
+                    fetch_q.put(run_leg("dispatch", compute, got))
                 except BaseException as e:
                     fail(e)
         finally:
-            mid_q.put(None)
-            if ft is not None:
-                ft.join()  # fetcher forwards its None to write_q on exit
+            fetch_q.put(None)
+            ft.join()  # fetcher forwards its None to write_q on exit
             wt.join()
             # unblock the reader if it waits for a buffer or is mid-put
             # (main loop exited early)
@@ -726,18 +658,11 @@ def _overlap_pipeline(produce, compute, consume, fetch=None,
             rt.join()
         if errors:
             raise errors[0]
-    if stats is not None and whole is not None:
-        wall = whole.duration
-        stats.update(
-            wall_s=wall,
-            **{f"{leg}_busy_s": s for leg, s in busy.items()},
-            efficiency=max(busy.values()) / wall if wall > 0 else 0.0,
-        )
 
 
 def _await(on_device) -> None:
     ready = getattr(on_device, "block_until_ready", None)
-    if ready is not None:  # a host stand-in (tests) is ready as it is
+    if ready is not None:  # a host codec's array is ready as it is
         ready()
 
 
@@ -807,7 +732,7 @@ def _encode_pipelined(dat, items, codec, shards: _HashedShards,
     the writer, last to read it (its rows go to the files and their
     digests, `_HashedShards.append`), gives it back to the pool."""
     k, m = codec.data_shards, codec.parity_shards
-    align = codec.alignment() if hasattr(codec, "alignment") else 1
+    align = codec.alignment()
     buffers = _ChunkBuffers("ec.seal", _chunk_nbytes(items, k))
 
     def read_chunk(fd, it, segments, data):
@@ -891,10 +816,7 @@ def rebuild_ec_files(
     (RebuildEcFiles / generateMissingEcFiles, :61,95). Returns generated ids."""
     codec = codec or get_codec()
     total = codec.total_shards
-    chunk = (
-        chunk_bytes if chunk_bytes is not None
-        else getattr(codec, "chunk_bytes", 8 * 1024 * 1024)
-    )
+    chunk = chunk_bytes if chunk_bytes is not None else codec.chunk_bytes
     chunk = _budgeted_chunk(codec, chunk, total)
 
     present: dict[int, str] = {}
@@ -920,37 +842,10 @@ def rebuild_ec_files(
     ins = {sid: open(p, "rb") for sid, p in present.items()}
     outs = {sid: open(base_file_name + shard_ext(sid), "wb") for sid in missing}
     try:
-        if hasattr(codec, "matmul_device"):
-            align = codec.alignment() if hasattr(codec, "alignment") else 1
-            _rebuild_pipelined(
-                codec, ins, outs, missing, shard_size,
-                _depth_chunk(chunk, shard_size, align),
-            )
-        else:
-            pos = 0
-            while pos < shard_size:
-                width = min(chunk, shard_size - pos)
-                shards: list[Optional[np.ndarray]] = [None] * total
-                zero = True
-                for sid, fh in ins.items():
-                    if _is_hole(fh.fileno(), pos, width):
-                        shards[sid] = np.zeros(width, dtype=np.uint8)
-                        continue
-                    fh.seek(pos)
-                    arr = np.frombuffer(fh.read(width), dtype=np.uint8)
-                    zero = zero and not arr.any()
-                    shards[sid] = arr
-                if zero:
-                    # all-zero columns reconstruct to zeros: keep shard
-                    # holes (sparse sealed volumes) as holes
-                    for sid in missing:
-                        outs[sid].seek(width, 1)
-                    pos += width
-                    continue
-                rebuilt = codec.reconstruct(shards)
-                for sid in missing:
-                    outs[sid].write(rebuilt[sid].tobytes())
-                pos += width
+        _rebuild_pipelined(
+            codec, ins, outs, missing, shard_size,
+            _depth_chunk(chunk, shard_size, codec.alignment()),
+        )
         for sid in missing:
             outs[sid].truncate(shard_size)
     finally:
@@ -986,9 +881,9 @@ def _rebuild_rows(codec, present_ids: list[int], missing: list[int]) -> np.ndarr
 
 
 def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
-    """Overlap disk reads, H2D staging + device matmul, and shard writes —
-    the encode pipeline's shape applied to rebuild (the serial
-    read→reconstruct→write loop leaves the device idle during IO).
+    """`rebuild_ec_files` through the overlap pipeline: disk reads, H2D
+    staging + device matmul, and shard writes of neighbouring chunks
+    overlap, as a seal's do.
 
     Row ``r`` of a chunk's ``(k, padded)`` buffer is read straight from the
     ``r``-th of the first k present shards. The buffer is not carried past
@@ -1002,7 +897,7 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
     present_ids = sorted(ins)
     first_k = present_ids[:k]
     rows = _rebuild_rows(codec, present_ids, missing)
-    align = codec.alignment() if hasattr(codec, "alignment") else 1
+    align = codec.alignment()
     widest = min(chunk, shard_size)
     buffers = _ChunkBuffers("ec.rebuild", k * -(-widest // align) * align)
 

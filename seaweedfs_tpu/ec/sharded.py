@@ -19,16 +19,9 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..stats import trace
 from ..util.jaxenv import import_jax
 from . import gf
-from .codec import (
-    Codec,
-    LaunchCounter,
-    _describe_jax_codec,
-    _device_memory_free,
-    _jax_kernel_name,
-)
+from .codec import JaxCodec, build_pallas_gf_matmul, xla_gf_matmul
 from .constants import DATA_SHARDS, PARITY_SHARDS
 
 
@@ -88,7 +81,6 @@ def make_sharded_encode(mesh, matrix: np.ndarray, process_local: bool = False):
     the multi-host layout where dp rides DCN and sp/tp ride ICI
     (docs/SCALING.md)."""
     jax = import_jax()
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     bitmat_np = gf.gf_matrix_to_bit_matrix(matrix).astype(np.int8)  # (8m, 8k)
@@ -100,26 +92,10 @@ def make_sharded_encode(mesh, matrix: np.ndarray, process_local: bool = False):
     out_sharding = NamedSharding(mesh, P("dp", None, "sp"))
 
     def spmd_encode(bitmat_slices, data):
-        # bitmat_slices: int8[tp, 8m, 8k/tp] sharded over 'tp'
-        # data: uint8[b, k, n] — but each tp rank needs its own k-bit slice;
-        # simplest correct formulation: every rank holds full k rows of data
-        # (they're replicated over 'tp'), unpacks all bits, and contracts only
-        # its slice of the bit matrix against its slice of the bits.
-        tp_idx = jax.lax.axis_index("tp")
-        bitmat_part = bitmat_slices[0]  # local slice after sharding over tp
-        b, k, n = data.shape
-        shifts = jnp.arange(8, dtype=jnp.uint8)
-        bits = (data[:, :, None, :] >> shifts[None, None, :, None]) & jnp.uint8(1)
-        bits = bits.reshape(b, k * 8, n).astype(jnp.int8)
-        rows = bitmat_part.shape[1]
-        local_bits = jax.lax.dynamic_slice_in_dim(bits, tp_idx * rows, rows, axis=1)
-        acc = jnp.einsum(
-            "ok,bkn->bon", bitmat_part, local_bits, preferred_element_type=jnp.int32
-        )
-        acc = jax.lax.psum(acc, axis_name="tp")  # combine partial GF(2) counts
-        out_bits = (acc & 1).astype(jnp.uint8).reshape(b, -1, 8, n)
-        weights = (jnp.uint8(1) << shifts)[None, None, :, None]
-        return jnp.sum(out_bits * weights, axis=2, dtype=jnp.uint32).astype(jnp.uint8)
+        # bitmat_slices: local int8[1, 8m, 8k/tp] of the stack sharded over
+        # 'tp'; data: uint8[b, k, n], replicated over 'tp' — every rank
+        # unpacks all bits and contracts its slice of them
+        return xla_gf_matmul(jax, bitmat_slices[0], data, tp_axis="tp")
 
     eight_m, eight_k = bitmat_np.shape
     bitmat_stacked = bitmat_np.reshape(eight_m, tp, eight_k // tp).transpose(1, 0, 2)
@@ -171,7 +147,7 @@ def make_sharded_encode(mesh, matrix: np.ndarray, process_local: bool = False):
     return encode_step
 
 
-class MeshCodec(Codec):
+class MeshCodec(JaxCodec):
     """Codec whose matmul runs SPMD over a jax.sharding.Mesh.
 
     Drop-in for the volume server's ``store.ec_codec``: `/admin/ec/generate`
@@ -202,47 +178,35 @@ class MeshCodec(Codec):
         pallas_tile: int = 32 * 1024,
         pallas_interpret: bool = False,
     ):
-        super().__init__(data_shards, parity_shards)
-        self._jax = import_jax()
-        self.mesh = mesh if mesh is not None else build_mesh(n_devices)
-        self.chunk_bytes = chunk_bytes
+        mesh = mesh if mesh is not None else build_mesh(n_devices)
+        self._tp = mesh.shape["tp"]
+        super().__init__(
+            data_shards, parity_shards, devices=mesh.devices.flat,
+            chunk_bytes=chunk_bytes,
+            # the fused kernel computes whole GF bytes per tile; a tp split
+            # needs int partial sums across devices, which only the XLA
+            # body expresses
+            use_pallas=use_pallas if self._tp == 1 else False,
+            pallas_tile=pallas_tile, pallas_interpret=pallas_interpret,
+        )
+        self.mesh = mesh
         # columns shard over dp×sp together; tp splits the contraction
         self._col_axes = ("dp", "sp")
-        self._n_cols_shards = self.mesh.shape["dp"] * self.mesh.shape["sp"]
-        self._tp = self.mesh.shape["tp"]
-        self.devices = list(self.mesh.devices.flat)
-        if use_pallas is None:
-            use_pallas = all(d.platform == "tpu" for d in self.devices)
-        # the fused kernel computes whole GF bytes per tile; a tp split needs
-        # int partial sums across devices, which only the XLA body expresses
-        self.use_pallas = use_pallas and self._tp == 1
-        self.pallas_tile = pallas_tile
-        self._pallas_interpret = pallas_interpret
-        self.kernel = _jax_kernel_name(self.use_pallas, pallas_interpret)
-        self.launches = LaunchCounter()
+        self._n_cols_shards = mesh.shape["dp"] * mesh.shape["sp"]
         # the newest result, kept so that /status can show which devices
         # hold its pieces: whether a launch really spread over the mesh
         self._last_out = None
-        self._jit_cache: dict = {}
-        self._bitmat_cache: dict = {}
 
     def describe(self) -> dict:
-        out = _describe_jax_codec(self, dict(self.mesh.shape))
+        out = super().describe()
         last = self._last_out
         out["last_output_devices"] = sorted(
             {s.device.id for s in last.addressable_shards}
         ) if last is not None else []
         return out
 
-    def device_memory_free(self):
-        """The tightest device's free HBM: every device holds the same
-        share of each chunk, so the fullest one bounds the chunk."""
-        free = [_device_memory_free(d) for d in self.devices]
-        return None if None in free else min(free)
-
     # -- device placement (the streaming encoder's overlap pipeline) ---------
     def alignment(self) -> int:
-        """Column widths fed to matmul_device must be multiples of this."""
         if self.use_pallas:
             # each device's local slice must be a whole number of kernel tiles
             return self._n_cols_shards * self.pallas_tile
@@ -289,18 +253,14 @@ class MeshCodec(Codec):
         fn = self._jit_cache.get(key)
         if fn is None:
             jax = self._jax
-            jnp = jax.numpy
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             col_axes = self._col_axes
-
             if self.use_pallas:
-                from .codec import build_pallas_gf_matmul
-
                 tile = self.pallas_tile
                 interpret = self._pallas_interpret
 
-                def pallas_body(bitmat, data):
+                def body(bitmat, data):
                     # data: the device-local (k, n_loc) column slice; the
                     # fused kernel runs at full single-chip rate per device,
                     # no collectives (columns are embarrassingly parallel)
@@ -309,48 +269,19 @@ class MeshCodec(Codec):
                         jax, n_out_rows, k, n_loc, tile, interpret
                     )(bitmat, data)
 
-                mapped = _shard_map(
-                    pallas_body,
-                    mesh=self.mesh,
-                    in_specs=(P(None, None), P(None, col_axes)),
-                    out_specs=P(None, col_axes),
-                )
-                fn = jax.jit(
-                    mapped,
-                    out_shardings=NamedSharding(self.mesh, P(None, col_axes)),
-                )
-                self._jit_cache[key] = fn
-                return fn
+                bitmat_spec = P(None, None)
+            else:
+                def body(bitmat_slices, data):
+                    # bitmat_slices: local (1, 8R, 8k/tp); data: local (k, n_loc)
+                    return xla_gf_matmul(
+                        jax, bitmat_slices[0], data, tp_axis="tp"
+                    )
 
-            def body(bitmat_slices, data):
-                # bitmat_slices: local (1, 8R, 8k/tp); data: local (k, n_loc)
-                tp_idx = jax.lax.axis_index("tp")
-                bitmat_part = bitmat_slices[0]
-                kk, n = data.shape
-                shifts = jnp.arange(8, dtype=jnp.uint8)
-                bits = (data[:, None, :] >> shifts[None, :, None]) & jnp.uint8(1)
-                bits = bits.reshape(kk * 8, n).astype(jnp.int8)
-                rows = bitmat_part.shape[1]
-                local_bits = jax.lax.dynamic_slice_in_dim(
-                    bits, tp_idx * rows, rows, axis=0
-                )
-                acc = jax.lax.dot_general(
-                    bitmat_part,
-                    local_bits,
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                )
-                acc = jax.lax.psum(acc, axis_name="tp")
-                out_bits = (acc & 1).astype(jnp.uint8).reshape(-1, 8, n)
-                weights = (jnp.uint8(1) << shifts)[None, :, None]
-                return jnp.sum(out_bits * weights, axis=1, dtype=jnp.uint32).astype(
-                    jnp.uint8
-                )
-
+                bitmat_spec = P("tp", None, None)
             mapped = _shard_map(
                 body,
                 mesh=self.mesh,
-                in_specs=(P("tp", None, None), P(None, col_axes)),
+                in_specs=(bitmat_spec, P(None, col_axes)),
                 out_specs=P(None, col_axes),
             )
             fn = jax.jit(
@@ -365,26 +296,4 @@ class MeshCodec(Codec):
         out = self._spmd_fn(*matrix.shape)(self._stacked_bitmat(matrix), data_dev)
         self.launches.add("pallas" if self.use_pallas else "xla")
         self._last_out = out
-        return out
-
-    def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
-        out_rows, _ = matrix.shape
-        n = data.shape[1]
-        align = self.alignment()
-        out = np.empty((out_rows, n), dtype=np.uint8)
-        pos = 0
-        while pos < n:
-            end = min(pos + self.chunk_bytes, n)
-            piece = data[:, pos:end]
-            width = end - pos
-            if width % align:
-                padded = align * -(-width // align)
-                piece = np.pad(piece, ((0, 0), (0, padded - width)))
-            # one synchronous round trip: stage, launch, copy back
-            with trace.stage_span("ec.codec.launch", bytes=piece.nbytes):
-                res = np.asarray(
-                    self.matmul_device(matrix, self.device_put(piece))
-                )
-            out[:, pos:end] = res[:, :width]
-            pos = end
         return out

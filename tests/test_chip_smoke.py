@@ -262,3 +262,29 @@ def test_native_library_is_rebuilt_when_its_stamp_does_not_match():
     with open(stamp) as f:
         assert f.read() == good
     assert os.stat(so).st_ino != before  # a new file was renamed into place
+
+
+def test_bench_py_holds_cluster_probes_only_and_imports_no_jax():
+    """The device half of bench.py is gone: importing it brings in neither
+    JAX nor the EC layer, and without a probe it prints its usage."""
+    code = (
+        "import importlib.util, json, sys\n"
+        "spec = importlib.util.spec_from_file_location('bench', 'bench.py')\n"
+        "bench = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(bench)\n"
+        "print(json.dumps({'loaded': sorted(m for m in sys.modules if m == 'jax'\n"
+        "    or m.startswith(('jax.', 'seaweedfs_tpu.ec'))),\n"
+        "  'probes': sorted(n for n in dir(bench) if n.startswith('probe_'))}))\n"
+    )
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=60, cwd=REPO)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout)
+    assert out["loaded"] == []
+    assert out["probes"] == [
+        "probe_filer_pipe", "probe_hotshard", "probe_lifecycle", "probe_meta",
+        "probe_query", "probe_serving", "probe_smallfile", "probe_sync",
+        "probe_trace"]
+    r = subprocess.run([sys.executable, "bench.py"], capture_output=True,
+                       text=True, timeout=60, cwd=REPO)
+    assert r.returncode == 1 and "--probe-serving" in r.stderr

@@ -6,13 +6,14 @@ boundary without GB files), then re-read every needle THROUGH the interval
 math + shard files and byte-compare against the .dat.
 """
 
+import hashlib
 import os
 
 import numpy as np
 import pytest
 
 from seaweedfs_tpu.ec import encoder, locate
-from seaweedfs_tpu.ec.codec import CpuCodec, NumpyCodec
+from seaweedfs_tpu.ec.codec import Codec, CpuCodec, NumpyCodec, TpuCodec
 from seaweedfs_tpu.ec.constants import shard_ext
 from seaweedfs_tpu.storage import idx
 from seaweedfs_tpu.storage.needle import VERSION3, Needle
@@ -101,10 +102,8 @@ def test_rebuild_worst_case_bit_identical(fixture_volume):
     ],
 )
 def test_rebuild_pipelined_combos_bit_identical(fixture_volume, gone):
-    """The overlap pipeline's single combined matmul must equal the
-    two-step serial reconstruct for every missing-shard shape."""
-    from seaweedfs_tpu.ec.codec import TpuCodec
-
+    """A rebuild's single combined matmul (`_rebuild_rows`) must give back
+    the sealed bytes for every missing-shard shape."""
     base, _ = fixture_volume
     codec = TpuCodec(chunk_bytes=8 * 1024, tile_bytes=1024)
     encoder.write_ec_files(base, codec, LARGE, SMALL, chunk_bytes=4096)
@@ -113,7 +112,6 @@ def test_rebuild_pipelined_combos_bit_identical(fixture_volume, gone):
         with open(base + shard_ext(sid), "rb") as f:
             orig[sid] = f.read()
         os.remove(base + shard_ext(sid))
-    assert hasattr(codec, "matmul_device")  # pipelined path engaged
     generated = encoder.rebuild_ec_files(base, codec, chunk_bytes=3000)
     assert sorted(generated) == sorted(gone)
     for sid, want in orig.items():
@@ -126,8 +124,6 @@ def test_rebuild_pipeline_error_raises_not_hangs(fixture_volume):
     not deadlock the reader on a full queue (regression: the shutdown path
     must drain both queues)."""
     import threading
-
-    from seaweedfs_tpu.ec.codec import TpuCodec
 
     base, _ = fixture_volume
     codec = TpuCodec(chunk_bytes=8 * 1024, tile_bytes=1024)
@@ -229,20 +225,13 @@ K = 10
 BLK = 4096  # one filesystem block, so that a punched segment is a real hole
 
 
-class DevNumpy(NumpyCodec):
-    """A host codec behind the device interface: the overlap pipeline and
-    its buffer pool run, no JAX needed. ``align`` pads a rebuild's chunks."""
-
-    align = 1
-
-    def alignment(self):
-        return self.align
-
-    def device_put(self, data):
-        return data
-
-    def matmul_device(self, matrix, data):
-        return self.matmul(matrix, np.asarray(data))
+# every kind of codec goes the one way through the pipeline: a host codec
+# in numpy, the native one, and a JAX codec (here the XLA formulation)
+CODECS = {
+    "numpy": NumpyCodec,
+    "cpu": CpuCodec,
+    "tpu-xla": lambda: TpuCodec(chunk_bytes=16 * 1024, tile_bytes=1024),
+}
 
 
 def old_read_item(f, item, k, dat_size):
@@ -364,15 +353,14 @@ def poisoned(monkeypatch):
     monkeypatch.setattr(encoder._ChunkBuffers, "take", poisoned_take)
 
 
-@pytest.mark.parametrize("codec_cls", [NumpyCodec, DevNumpy],
-                         ids=["serial", "pipelined"])
+@pytest.mark.parametrize("kind", sorted(CODECS))
 @pytest.mark.parametrize("case", sorted(READER_CASES))
 def test_seal_from_poisoned_buffers_matches_the_reference(
-        tmp_path, poisoned, case, codec_cls):
+        tmp_path, poisoned, case, kind):
     runs, large, small, chunk = READER_CASES[case]
     base = str(tmp_path / "v")
     image = write_dat(base + ".dat", runs)
-    codec = codec_cls()
+    codec = CODECS[kind]()
     _, items = encoder.plan_encode(codec, len(image), large, small, chunk)
     kinds = {it[0] for it in items}
     assert kinds == ({"cols"} if case == "cols" else {"rows"}), kinds
@@ -386,8 +374,9 @@ def test_seal_from_poisoned_buffers_matches_the_reference(
 
 @pytest.mark.parametrize("case", sorted(READER_CASES))
 def test_two_volumes_through_one_buffer_leave_nothing_stale(tmp_path, case):
-    """The serial loop's shape, twice through ONE pool of one buffer: first
-    a dense volume (so the buffer is full of data), then the case's."""
+    """Twice through ONE pool of one buffer, so that every chunk is read
+    over the one before it: first a dense volume (so the buffer is full of
+    data), then the case's."""
     runs, large, small, chunk = READER_CASES[case]
     size = sum(n for _, n in runs)
     first, second = str(tmp_path / "a.dat"), str(tmp_path / "b.dat")
@@ -417,20 +406,20 @@ def test_two_volumes_through_one_buffer_leave_nothing_stale(tmp_path, case):
 
 @pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
 def test_rebuild_from_poisoned_buffers_pads_the_last_chunk(
-        tmp_path, poisoned, sparse):
+        tmp_path, poisoned, monkeypatch, sparse):
     """A device codec whose launches want 256-byte multiples: every chunk's
     [width:padded] tail, and a sparse volume's hole rows, must read zeros."""
     runs = ([("data", ROW + 77), ("hole", 6 * ROW), ("data", 3 * ROW + 5)]
             if sparse else [("data", 10 * ROW + 1001)])
     base = str(tmp_path / "v")
     image = write_dat(base + ".dat", runs)
-    codec = DevNumpy()
+    codec = NumpyCodec()
     encoder.write_ec_files(base, codec, 64 * BLK, BLK, chunk_bytes=2 * BLK)
     want = reference_shards(image, 64 * BLK, BLK)
     gone = (0, 4, 9, 12)
     for sid in gone:
         os.remove(base + shard_ext(sid))
-    codec.align = 256
+    monkeypatch.setattr(codec, "alignment", lambda: 256)
     # 5000 is no multiple of 256, nor is the last chunk's width
     assert sorted(encoder.rebuild_ec_files(base, codec, chunk_bytes=5000)) == (
         list(gone))
@@ -460,7 +449,7 @@ def test_short_reads_are_read_on_not_left_stale(tmp_path, poisoned, monkeypatch)
     monkeypatch.setattr(encoder.os, "preadv", short_preadv)
     base = str(tmp_path / "v")
     image = write_dat(base + ".dat", [("data", 2 * ROW + 3 * BLK + 17)])
-    codec = DevNumpy()
+    codec = NumpyCodec()
     encoder.write_ec_files(base, codec, 64 * BLK, BLK, chunk_bytes=2 * BLK)
     want = reference_shards(image, 64 * BLK, BLK)
     os.remove(base + shard_ext(3))
@@ -519,3 +508,62 @@ def test_an_error_in_any_leg_ends_a_call_whose_reader_waits_for_a_buffer(leg):
     t.join(timeout=20)
     assert not t.is_alive(), f"pipeline hung on an error in {leg}"
     assert result == [f"injected in {leg}"]
+
+
+# -- the interface the encoder drives, on every class get_codec can return ----
+LOST = (0, 4, 9, 12)
+CONFORMANCE_VOLUMES = {
+    "dense": [("data", 12 * ROW + 3 * BLK + 1234)],
+    "sparse": [("data", ROW + 77), ("hole", 6 * ROW), ("data", 3 * ROW + 5),
+               ("hole", 2 * ROW)],
+}
+
+
+@pytest.fixture(scope="module")
+def built_codecs():
+    """Each backend name `get_codec` knows, built once (a JAX codec compiles
+    per shape). `get_codec` refuses a NAMED ``tpu`` / ``mesh`` off a TPU,
+    so on the CPU platform those two classes are built as it builds them."""
+    from seaweedfs_tpu.ec.codec import get_codec
+    from seaweedfs_tpu.ec.sharded import MeshCodec
+
+    return {
+        "numpy": get_codec("numpy"),
+        "cpu": get_codec("cpu"),
+        "tpu": TpuCodec(chunk_bytes=16 * 1024, tile_bytes=1024),
+        "mesh": MeshCodec(n_devices=4, chunk_bytes=16 * 1024),
+    }
+
+
+@pytest.mark.parametrize("volume", sorted(CONFORMANCE_VOLUMES))
+@pytest.mark.parametrize("backend", ["numpy", "cpu", "tpu", "mesh"])
+def test_every_codec_answers_the_interface_and_seals_and_rebuilds_through_it(
+        tmp_path, built_codecs, backend, volume):
+    codec = built_codecs[backend]
+    assert isinstance(codec, Codec) and codec.backend == backend
+    # the interface, as the encoder calls it: no probing
+    assert codec.chunk_bytes > 0
+    align = codec.alignment()
+    assert align >= 1
+    free = codec.device_memory_free()
+    assert free is None or free > 0
+    rng = np.random.default_rng(28)
+    piece = rng.integers(0, 256, (K, 2 * align), dtype=np.uint8)
+    staged = codec.device_put(piece)
+    assert staged.shape == piece.shape and staged.nbytes == piece.nbytes
+    parity = np.asarray(codec.matmul_device(codec.parity_rows, staged))
+    assert np.array_equal(parity, NumpyCodec().encode(piece))
+
+    base = str(tmp_path / "v")
+    image = write_dat(base + ".dat", CONFORMANCE_VOLUMES[volume])
+    sums = encoder.write_ec_files(base, codec, 64 * BLK, BLK,
+                                  chunk_bytes=4 * BLK)
+    want = reference_shards(image, 64 * BLK, BLK)
+    assert sums == [hashlib.sha256(w).hexdigest() for w in want]
+    for sid in LOST:
+        os.remove(base + shard_ext(sid))
+    assert encoder.rebuild_ec_files(base, codec, chunk_bytes=3 * BLK) == (
+        list(LOST))
+    for sid in range(14):
+        with open(base + shard_ext(sid), "rb") as f:
+            assert f.read() == want[sid], f"shard {sid} differs"

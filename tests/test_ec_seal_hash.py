@@ -1,8 +1,8 @@
 """A seal hashes its shards as it writes them (ec/encoder.py
 ``_HashedShards``): the sums `write_ec_files` hands back — the .vif's —
-are the SHA-256 of the committed shard files, from both of its loops, over
-chunked bodies, ragged tails and holes; and a seal that fails mid-way
-leaves no sum, no shard and no thread behind."""
+are the SHA-256 of the committed shard files, whatever codec runs its
+pipeline, over chunked bodies, ragged tails and holes; and a seal that
+fails mid-way leaves no sum, no shard and no thread behind."""
 
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu.ec import encoder
-from seaweedfs_tpu.ec.codec import CpuCodec, NumpyCodec
+from seaweedfs_tpu.ec.codec import CpuCodec, NumpyCodec, TpuCodec
 from seaweedfs_tpu.ec.constants import TOTAL_SHARDS, shard_ext
 from seaweedfs_tpu.server.volume_server import VolumeServer
 from seaweedfs_tpu.stats.trace import STAGES
@@ -30,21 +30,11 @@ MIB = 1 << 20
 VID = 7
 
 
-class DevNumpy(NumpyCodec):
-    """A host codec behind the device interface: `write_ec_files` takes the
-    overlap pipeline, no JAX needed (as tests/test_ec_stage_spans.py)."""
-
-    def device_put(self, data):
-        return data
-
-    def matmul_device(self, matrix, data):
-        return self.matmul(matrix, np.asarray(data))
-
-
 def make_codec(kind: str):
-    codec = {"numpy": NumpyCodec, "native": CpuCodec, "pipelined": DevNumpy}[kind]()
-    # one 10 MiB row of small blocks a chunk, for the serial loop as well
-    # (the pipeline gets there by itself: `_depth_chunk`)
+    if kind == "tpu-xla":  # the JAX codec on the CPU platform
+        return TpuCodec(chunk_bytes=MIB, tile_bytes=MIB // 16)
+    codec = {"numpy": NumpyCodec, "cpu": CpuCodec}[kind]()
+    # one 10 MiB row of small blocks a chunk (`_depth_chunk` keeps it)
     codec.chunk_bytes = MIB
     return codec
 
@@ -106,7 +96,7 @@ def keeps_holes(directory) -> bool:
 
 
 @pytest.mark.parametrize("shape", list(SHAPES))
-@pytest.mark.parametrize("kind", ["numpy", "native", "pipelined"])
+@pytest.mark.parametrize("kind", ["numpy", "cpu", "tpu-xla"])
 def test_the_vif_sums_are_the_committed_files_and_the_scrub_agrees(
         tmp_path, kind, shape):
     codec = make_codec(kind)
@@ -162,12 +152,12 @@ def test_the_bare_call_returns_the_sums_of_the_files_it_wrote(tmp_path):
         f.write(rng.integers(1, 256, 70 * blk + 17, dtype=np.uint8).tobytes())
         f.truncate(150 * blk)
     want = None
-    for codec in (NumpyCodec(), DevNumpy()):
+    for codec in (NumpyCodec(), CpuCodec()):
         sums = encoder.write_ec_files(base, codec, 1 << 30, blk,
                                       chunk_bytes=2 * blk)
         assert sums == [sha256_of(base + shard_ext(s))
                         for s in range(TOTAL_SHARDS)]
-        assert want in (None, sums)  # both loops write the same bytes
+        assert want in (None, sums)  # every codec writes the same bytes
         want = sums
     assert seal_threads() == []
 
@@ -218,7 +208,7 @@ def test_rows_hashed_side_by_side_keep_each_files_order(tmp_path):
 
 
 @pytest.mark.parametrize("fault", ["between-chunks", "in-a-row"])
-@pytest.mark.parametrize("kind", ["numpy", "pipelined"])
+@pytest.mark.parametrize("kind", ["numpy", "tpu-xla"])
 def test_a_seal_that_fails_midway_commits_nothing_and_leaves_no_thread(
         tmp_path, monkeypatch, kind, fault):
     """``between-chunks``: the fault point every chunk passes fires at the

@@ -58,17 +58,6 @@ def named(node: dict, name: str) -> list[dict]:
     return [c for c in node["children"] if c["name"] == name]
 
 
-class DevNumpy(NumpyCodec):
-    """A host codec behind the device interface: the pipeline's four legs
-    run, no JAX needed."""
-
-    def device_put(self, data):
-        return data
-
-    def matmul_device(self, matrix, data):
-        return self.matmul(matrix, np.asarray(data))
-
-
 def make_codec(kind: str):
     if kind == "numpy":
         return NumpyCodec()
@@ -143,10 +132,7 @@ def test_one_seal_in_the_stage_table(sealed):
                 for p in ("pipeline", "ecx", "commit"))
     assert 0 < parts <= delta(b, a, "ec.seal", "busy_s")
     assert delta(b, a, "ec.seal.hash", "busy_s") > 0
-    if sealed["kind"] == "numpy":
-        # a host codec keeps the serial loop: no pipeline, no legs
-        assert delta(b, a, "ec.seal.pipeline", "n") == 0
-        return
+    # every codec, a host one too, goes through the pipeline's legs
     _, items = encoder.plan_encode(sealed["codec"], sealed["dat_size"])
     assert len(items) >= 2
     assert delta(b, a, "ec.seal.pipeline", "n") == 1
@@ -187,8 +173,6 @@ def test_a_seal_is_one_tree_under_its_admin_request(sealed):
     assert max(map(end, hashes)) <= ecx["start"] + 1e-3
     assert [c["name"] for c in sorted(seal["children"], key=end)[-2:]] == [
         "ec.seal.ecx", "ec.seal.commit"]
-    if sealed["kind"] == "numpy":
-        return
     # the reader, fetch and writer threads run in copies of the seal's
     # context: their spans hang under the pipeline's
     (pipeline,) = named(seal, "ec.seal.pipeline")
@@ -413,7 +397,7 @@ def test_a_profiler_session_holds_the_stages_on_its_own_clock(tmp_path):
         store.write_volume_needle(3, Needle(
             cookie=1, id=i,
             data=rng.integers(0, 256, 40_000, dtype=np.uint8).tobytes()))
-    store._ec_codec = DevNumpy()
+    store._ec_codec = NumpyCodec()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
@@ -457,10 +441,6 @@ def test_a_seal_takes_one_buffer_a_chunk_that_carries_data(sealed):
              + delta(b, a, "ec.seal.buf.wait", "n"))
     assert taken == len(items)  # a dense volume: every chunk carries data
     assert 1 <= delta(b, a, "ec.seal.buf.new", "n") <= encoder._POOL_BUFFERS
-    if sealed["kind"] == "numpy":
-        # the serial loop reads each chunk into its one buffer
-        assert delta(b, a, "ec.seal.buf.new", "n") == 1
-        return
     assert taken == delta(b, a, "ec.seal.read", "n")
     # the stages hang beside the read spans, not inside them
     roots = tree_of(sealed["address"], sealed["seal_span"]["trace_id"])
@@ -474,8 +454,8 @@ def test_a_seal_takes_one_buffer_a_chunk_that_carries_data(sealed):
 def pooled_seal(tmp_path, monkeypatch, chunks: int, hole_chunks: int,
                 write_s: float = 0.0):
     """Seal a volume of ``chunks`` chunks (the last ``hole_chunks`` of them
-    one hole) through the pipeline with a host codec behind the device
-    interface; the stage table's delta and the most buffers ever out."""
+    one hole) through the pipeline with a host codec; the stage table's
+    delta and the most buffers ever out."""
     blk = 4096
     base = str(tmp_path / "1")
     rng = np.random.default_rng(25)
@@ -499,7 +479,7 @@ def pooled_seal(tmp_path, monkeypatch, chunks: int, hole_chunks: int,
 
     monkeypatch.setattr(encoder._ChunkBuffers, "take", counted_take)
     monkeypatch.setattr(encoder._ChunkBuffers, "give", counted_give)
-    codec = DevNumpy()
+    codec = NumpyCodec()
     _, items = encoder.plan_encode(codec, chunks * 20 * blk, 1 << 30, blk,
                                    2 * blk)
     assert len(items) == chunks and {it[0] for it in items} == {"rows"}
@@ -541,7 +521,7 @@ def test_a_rebuild_recycles_its_buffers_at_the_fetch_leg(tmp_path):
     rng = np.random.default_rng(26)
     with open(base + ".dat", "wb") as f:
         f.write(rng.integers(1, 256, 240 * blk, dtype=np.uint8).tobytes())
-    codec = DevNumpy()
+    codec = NumpyCodec()
     encoder.write_ec_files(base, codec, 1 << 30, blk, chunk_bytes=2 * blk)
     for sid in LOST:
         os.remove(base + shard_ext(sid))

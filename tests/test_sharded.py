@@ -89,14 +89,15 @@ def test_mesh_codec_matmul_and_reconstruct():
     assert all(np.array_equal(out[i], full[i]) for i in range(14))
 
 
-def test_pipelined_write_ec_files_matches_serial(tmp_path):
-    """The overlap pipeline (any codec with matmul_device) must produce the
-    same shard bytes as the serial host loop."""
+@pytest.mark.parametrize("kind", ["cpu", "tpu-xla", "mesh"])
+def test_write_ec_files_is_the_same_bytes_from_every_codec(tmp_path, kind):
+    """One path through the encoder for every codec: what a JAX codec, the
+    mesh and the native kernel write is what the numpy codec writes."""
     import glob
     import os
 
     from seaweedfs_tpu.ec import encoder
-    from seaweedfs_tpu.ec.codec import TpuCodec
+    from seaweedfs_tpu.ec.codec import CpuCodec, TpuCodec
 
     rng = np.random.default_rng(8)
     payload = rng.integers(0, 256, 50_001, dtype=np.uint8).tobytes()
@@ -106,9 +107,13 @@ def test_pipelined_write_ec_files_matches_serial(tmp_path):
         with open(b + ".dat", "wb") as f:
             f.write(payload)
 
-    tp = TpuCodec(chunk_bytes=4096, tile_bytes=4096, pallas_tile=4096)
-    assert hasattr(tp, "matmul_device")  # pipeline path
-    encoder.write_ec_files(base_a, tp, large_block_size=8192, small_block_size=512)
+    codec = {
+        "cpu": CpuCodec,
+        "tpu-xla": lambda: TpuCodec(
+            chunk_bytes=4096, tile_bytes=4096, pallas_tile=4096),
+        "mesh": lambda: sharded.MeshCodec(n_devices=4, chunk_bytes=4096),
+    }[kind]()
+    encoder.write_ec_files(base_a, codec, large_block_size=8192, small_block_size=512)
     encoder.write_ec_files(
         base_b, NumpyCodec(), large_block_size=8192, small_block_size=512
     )
