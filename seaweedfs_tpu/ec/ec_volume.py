@@ -20,8 +20,10 @@ import io
 import os
 import struct
 import threading
-from typing import Optional
+import time
+from typing import Callable, Optional
 
+from ..stats import trace
 from ..storage import idx as idx_mod
 from ..storage.needle import get_actual_size
 from ..storage.types import (
@@ -39,6 +41,21 @@ from .constants import (
     shard_ext,
 )
 from .locate import Interval, locate_data
+
+# How long what the master last said about a volume's shards is believed
+# (store_ec.go cachedLookupEcShardLocations): 11 s while fewer than
+# data_shards are known, 7 min from data_shards, 37 min with all of them
+LOCATIONS_FRESH_FEW_S = 11.0
+LOCATIONS_FRESH_ENOUGH_S = 7 * 60.0
+LOCATIONS_FRESH_ALL_S = 37 * 60.0
+
+# the clock of the table's ages, looked up at each call so a test can step it
+_clock = time.monotonic
+
+# vid -> {shard id: [holder urls]}: one /dir/lookup_ec (shard ids as the
+# wire has them, int or str). {} when the master knows no shard of the
+# volume; raises when the master cannot be asked
+ShardLocator = Callable[[int], dict]
 
 
 class NotFoundError(Exception):
@@ -136,6 +153,12 @@ class EcVolume:
         self.shards: dict[int, EcVolumeShard] = {}
         self._ecx_lock = threading.Lock()
         self._ecj_lock = threading.Lock()
+        # what the master last said about this volume's shards, and when
+        # (EcVolume.ShardLocations / ShardLocationsRefreshTime); the store's
+        # remote reads believe it for as long as the rule above allows
+        self._locations_lock = threading.Lock()
+        self._locations: dict[int, list[str]] = {}
+        self._locations_taken: Optional[float] = None
         from ..storage.commit import pending_commit
 
         if pending_commit(self.base_file_name):
@@ -188,6 +211,60 @@ class EcVolume:
     def dat_file_size(self) -> int:
         """Original .dat size proxy: k × shard size (ec_volume.go:202)."""
         return self.data_shards * self.shard_size()
+
+    # -- shard locations (store_ec.go cachedLookupEcShardLocations) -----------
+    def _locations_fresh_s(self) -> float:
+        known = len(self._locations)
+        if known < self.data_shards:
+            return LOCATIONS_FRESH_FEW_S
+        if known >= self.total_shards:
+            return LOCATIONS_FRESH_ALL_S
+        return LOCATIONS_FRESH_ENOUGH_S
+
+    def refresh_locations(
+        self, locate: ShardLocator, newer_than: Optional[float] = None
+    ) -> float:
+        """Make the location table one to believe and return when it was
+        taken: still fresh by the reference's rule or, where the caller has
+        seen the table of ``newer_than`` fail it, taken after that one. The
+        master is asked at most once — concurrent callers that find the
+        table stale wait here for one lookup, they do not each make their
+        own. A lookup that raises leaves the table in hand as it was."""
+        with self._locations_lock:
+            taken = self._locations_taken
+            if taken is not None:
+                if newer_than is not None:
+                    believed = taken > newer_than
+                else:
+                    believed = _clock() - taken < self._locations_fresh_s()
+                if believed:
+                    return taken
+            with trace.stage_span("ec.read.lookup"):
+                found = locate(self.id)
+            self._locations = {
+                int(sid): list(urls) for sid, urls in found.items() if urls
+            }
+            self._locations_taken = _clock()
+            return self._locations_taken
+
+    def locations_taken(self) -> Optional[float]:
+        """When the table in hand was taken; None before the first lookup."""
+        with self._locations_lock:
+            return self._locations_taken
+
+    def shard_holders(self, sid: int) -> list[str]:
+        """The servers the table in hand lists for one shard."""
+        with self._locations_lock:
+            return list(self._locations.get(sid, ()))
+
+    def forget_shard_holder(self, sid: int, url: str) -> None:
+        """Drop a holder that failed an ask (store_ec.go forgetShardId)."""
+        with self._locations_lock:
+            left = [u for u in self._locations.get(sid, ()) if u != url]
+            if left:
+                self._locations[sid] = left
+            else:
+                self._locations.pop(sid, None)
 
     # -- .ecx search (ec_volume.go:210-235) ----------------------------------
     def find_needle_from_ecx(self, needle_id: int) -> tuple[int, int]:

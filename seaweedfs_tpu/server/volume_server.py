@@ -40,7 +40,7 @@ from ..storage.needle import (
     FLAG_HAS_NAME,
     Needle,
 )
-from ..storage.store import Store
+from ..storage.store import RemoteShards, Store
 from ..storage.volume import DeletedError, NotFoundError, volume_file_name
 from ..util import faultpoints, glog
 from ..util.parsers import tolerant_uint
@@ -118,7 +118,9 @@ class VolumeServer:
             ec_backend=ec_backend,
             needle_map_kind=needle_map_kind,
         )
-        self.store.remote_shard_reader = self._remote_shard_reader
+        self.store.remote_shards = RemoteShards(
+            locate=self._locate_ec_shards, fetch=self._fetch_ec_shard
+        )
         # hot-needle RAM cache tier (util/needle_cache.py): byte budget
         # from SWEED_NCACHE (0 = off), resizable live via POST /admin/ncache
         from ..util.needle_cache import NeedleCache
@@ -135,28 +137,32 @@ class VolumeServer:
         # in every heartbeat so its fleet scheduler sees mesh membership
         self.mesh_info: Optional[dict] = None
 
-    # -- remote EC shard read via master shard lookup ------------------------
-    def _remote_shard_reader(self, vid, shard_id, offset, size):
-        # the master is asked on every ask: who holds this shard now
-        with trace.stage_span("ec.read.lookup"):
-            r = http_json(
-                "GET", f"http://{self.master_url}/dir/lookup_ec?volumeId={vid}"
+    # -- the store's two asks of the cluster for a shard it does not hold ----
+    def _locate_ec_shards(self, vid: int) -> dict:
+        r = http_json(
+            "GET", f"http://{self.master_url}/dir/lookup_ec?volumeId={vid}"
+        )
+        status = r.get("_status")
+        if status == 404:
+            # master_server._h_lookup_ec: it knows no shard of the volume
+            return {}
+        if status:
+            raise IOError(
+                f"/dir/lookup_ec of volume {vid}: {status} {r.get('error', '')}"
             )
-        holders = r.get("shard_id_locations", {}).get(str(shard_id)) or r.get(
-            "shard_id_locations", {}
-        ).get(shard_id, [])
-        me = f"{self.host}:{self.port}"
-        for holder in holders:
-            if holder == me:
-                continue
-            status, data = http_bytes(
-                "GET",
-                f"http://{holder}/admin/ec/shard_read?volume={vid}"
-                f"&shard={shard_id}&offset={offset}&size={size}",
+        return r.get("shard_id_locations", {})
+
+    def _fetch_ec_shard(self, holder, vid, shard_id, offset, size) -> bytes:
+        status, data = http_bytes(
+            "GET",
+            f"http://{holder}/admin/ec/shard_read?volume={vid}"
+            f"&shard={shard_id}&offset={offset}&size={size}",
+        )
+        if status != 200:
+            raise IOError(
+                f"{holder} answered {status} for shard {vid}.{shard_id}"
             )
-            if status == 200 and len(data) == size:
-                return data
-        return None
+        return data
 
     # -- data plane ----------------------------------------------------------
     def _parse_fid_path(self, path: str):

@@ -250,10 +250,11 @@ class StageTable:
     and ``slept_s`` of back-off where it retries. Stages close on request
     threads and on the encode pipeline's threads at once, so the rows are
     kept under one lock. A reader takes two snapshots and subtracts.
-    A stage that asks another server also sums ``ok`` (asks answered) and
-    ``ok_s`` (seconds inside the attempts that were answered)."""
+    A stage that asks another server also sums ``ok`` (asks answered),
+    ``ok_s`` (seconds inside the attempts that were answered) and
+    ``absent`` (asks the shard-location table answered "nowhere")."""
 
-    SUMMED_TAGS = ("bytes", "failed", "slept_s", "ok", "ok_s")
+    SUMMED_TAGS = ("bytes", "failed", "slept_s", "ok", "ok_s", "absent")
 
     def __init__(self):
         self._lock = make_lock("StageTable._lock")

@@ -4,9 +4,10 @@ Mirrors `weed/storage/store.go` + `store_ec.go`: volume CRUD across
 DiskLocations, heartbeat stat collection with delta queues for the master
 stream, and the EC read path with on-the-fly reconstruction:
 
-    local shard read → remote shard fetch (injected callback; the volume
-    server wires this to gRPC in the cluster layer) → reconstruction from
-    ≥k sibling shards via the EC codec (TPU/CPU) — store_ec.go:122-375.
+    local shard read → remote shard fetch (from a holder the EC volume's
+    shard-location table lists; the volume server wires the lookup and the
+    fetch, `RemoteShards`) → reconstruction from ≥k sibling shards via the
+    EC codec (TPU/CPU) — store_ec.go:122-375.
 """
 
 from __future__ import annotations
@@ -15,13 +16,13 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
 from ..ec.codec import Codec, get_codec
 from ..ec.constants import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, TOTAL_SHARDS, shard_ext
-from ..ec.ec_volume import EcVolume, NeedsShardError
+from ..ec.ec_volume import EcVolume, NeedsShardError, ShardLocator
 from ..ec.ec_volume import NotFoundError as EcNotFoundError
 from ..stats import heat, trace
 from ..util import faultpoints, glog, jaxenv
@@ -33,8 +34,17 @@ from .ttl import EMPTY_TTL, TTL, read_ttl
 from .volume import NotFoundError, Volume
 from ..util.locks import make_lock, make_rlock
 
-# remote_reader(vid, shard_id, offset, size) -> bytes | None
-RemoteShardReader = Callable[[int, int, int, int], Optional[bytes]]
+
+class RemoteShards(NamedTuple):
+    """What a store asks of its cluster to read a shard it does not hold:
+    who holds the volume's shards, and one range from ONE named holder. The
+    store owns the rest — the table each EC volume keeps of ``locate``'s
+    answer, how long it is believed, which holder is asked, the retries."""
+
+    locate: ShardLocator
+    # (holder url, vid, shard id, offset, size) -> the range; raises on a
+    # holder that refuses, times out or does not have the shard
+    fetch: Callable[[str, int, int, int, int], bytes]
 
 
 class Store:
@@ -69,7 +79,7 @@ class Store:
         self._ec_codec: Optional[Codec] = None
         self._codec_lock = make_lock("Store._codec_lock")
         self._ec_backend = ec_backend
-        self.remote_shard_reader: Optional[RemoteShardReader] = None
+        self.remote_shards: Optional[RemoteShards] = None
         # native turbo data plane (native/turbo.py); set by the volume
         # server when it owns the public port through the engine
         self.turbo_engine = None
@@ -458,23 +468,41 @@ class Store:
             sid, soff = interval.to_shard_id_and_offset(
                 LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, ev.data_shards
             )
-            # 1. remote shard holder (wired to gRPC by the volume server)
-            data = self._remote_shard_read(ev.id, sid, soff, interval.size)
+            # the location table this read found in hand, if any
+            believed = ev.locations_taken()
+            # 1. a server the master lists as holding the shard
+            data = self._remote_shard_read(ev, sid, soff, interval.size)
             if data is not None:
                 return data
             # 2. degraded mode: reconstruct from sibling shards
-            return self._recover_interval(ev, sid, soff, interval.size)
+            return self._recover_interval(
+                ev, sid, soff, interval.size, believed
+            )
 
     def _remote_shard_read(
-        self, vid: int, sid: int, offset: int, size: int
+        self,
+        ev: EcVolume,
+        sid: int,
+        offset: int,
+        size: int,
+        newer_than: Optional[float] = None,
     ) -> Optional[bytes]:
-        """Remote shard fetch with bounded retry/backoff/deadline
-        (store_ec.go readRemoteEcShardInterval, hardened). A flaky peer
-        gets ``remote_fetch_attempts`` tries with exponential backoff; a
-        dead or wedged one costs at most ``remote_fetch_timeout_s`` before
-        the caller falls through to reconstruction. Returns None when the
-        range is unobtainable remotely."""
-        if self.remote_shard_reader is None:
+        """One range of a shard that is not local, from a server the
+        volume's location table lists for it (store_ec.go
+        readRemoteEcShardInterval, hardened). The table is the master's
+        answer, believed for as long as the reference believes it: a shard
+        it does not list (or lists only here) is nowhere, and the ask ends
+        at once — no attempt, no sleep, no lookup. A LISTED holder that
+        fails is a fault: it gets ``remote_fetch_attempts`` tries with
+        exponential backoff inside ``remote_fetch_timeout_s``, each failure
+        forgets the holder and has the next try refresh the table first, so
+        a shard that moved is found where it now is and one whose only
+        holder died is nowhere on the second look. A lookup that fails is a
+        fault too, never "nowhere". ``newer_than``: do not believe a table
+        taken at or before that instant. Returns None when the range is
+        unobtainable remotely."""
+        remote = self.remote_shards
+        if remote is None:
             return None
         from ..util.retry import TRANSIENT, RetryError, RetryPolicy, retry_call
 
@@ -484,25 +512,61 @@ class Store:
             cap_s=max(1.0, self.remote_fetch_backoff_s * 8),
             deadline_s=self.remote_fetch_timeout_s,
         )
+        me = f"{self.ip}:{self.port}"
         # the whole ask, sleeps included: attempts that raised and the
         # back-off slept between them are summed into the stage table; an
         # ask that was answered adds 1 to ``ok``, the range to ``bytes`` and
-        # the answered attempt's own wall (lookup + fetch) to ``ok_s``
+        # the answered attempt's own wall (table + fetch) to ``ok_s``; one
+        # the table answered "nowhere" adds 1 to ``absent``
         with trace.stage_span(
             "ec.read.remote", sid=sid, failed=0, slept_s=0.0, ok=0,
-            ok_s=0.0, bytes=0,
+            ok_s=0.0, bytes=0, absent=0,
         ) as span:
+
+            def _holders() -> list[str]:
+                nonlocal newer_than
+                try:
+                    taken = ev.refresh_locations(remote.locate, newer_than)
+                except Exception as e:  # noqa: BLE001 — any failed lookup
+                    # the table in hand stays in use; without a holder in
+                    # it the master's silence is a fault, not an answer
+                    holders = [u for u in ev.shard_holders(sid) if u != me]
+                    if not holders:
+                        raise
+                    glog.warning(
+                        "ec volume %d: shard lookup failed (%s); reading "
+                        "shard %d by the table in hand", ev.id, e, sid,
+                    )
+                    return holders
+                # should a holder of this table fail, the next try wants
+                # a table taken after it
+                newer_than = taken
+                return [u for u in ev.shard_holders(sid) if u != me]
 
             def _fetch():
                 t = time.perf_counter()
                 try:
-                    faultpoints.fire("ec.read.remote-fetch")
-                    data = self.remote_shard_reader(vid, sid, offset, size)
-                    if data is None or len(data) != size:
-                        # a short range is a failed attempt, not a success
-                        raise IOError(
-                            f"short/empty remote range for {vid}.{sid}"
-                        )
+                    holders = _holders()
+                    if not holders:
+                        if span is not None:
+                            span.tags["absent"] += 1
+                        return None
+                    for holder in holders:
+                        try:
+                            faultpoints.fire("ec.read.remote-fetch")
+                            data = remote.fetch(holder, ev.id, sid, offset, size)
+                            if len(data) == size:
+                                break
+                            # a short range is a failed attempt, not a success
+                            why = IOError(
+                                f"short/empty range of {ev.id}.{sid} "
+                                f"from {holder}"
+                            )
+                        except Exception as e:  # noqa: BLE001 — nothing is poison, below
+                            why = e
+                        ev.forget_shard_holder(sid, holder)
+                    else:
+                        raise why
                 except Exception:
                     if span is not None:
                         span.tags["failed"] += 1
@@ -518,7 +582,7 @@ class Store:
                     span.tags["slept_s"] += delay
                 glog.warning(
                     "remote shard %d.%d fetch attempt %d failed: %s",
-                    vid, sid, attempt, e,
+                    ev.id, sid, attempt, e,
                 )
 
             try:
@@ -526,8 +590,9 @@ class Store:
                     _fetch,
                     policy=policy,
                     # every failure mode here (peer down, timeout, short
-                    # read, injected fault) heals the same way: try again,
-                    # then fall through to reconstruction — nothing is poison
+                    # read, master unreachable, injected fault) heals the
+                    # same way: try again, then fall through to
+                    # reconstruction — nothing is poison
                     classify=lambda e: TRANSIENT,
                     on_retry=_on_retry,
                 )
@@ -535,10 +600,19 @@ class Store:
                 return None
 
     def _recover_interval(
-        self, ev: EcVolume, missing_shard: int, offset: int, size: int
+        self,
+        ev: EcVolume,
+        missing_shard: int,
+        offset: int,
+        size: int,
+        believed: Optional[float] = None,
     ) -> bytes:
         """Fetch the same byte range from ≥k sibling shards and RS-decode
-        (recoverOneRemoteEcShardInterval, store_ec.go:322)."""
+        (recoverOneRemoteEcShardInterval, store_ec.go:322). ``believed``:
+        when the location table the read found in hand was taken. If that
+        table is still the one in hand when fewer than k siblings were
+        reached, it is taken anew once and the siblings it called "nowhere"
+        are asked for again: no read fails on an old answer."""
         codec = self.ec_codec
         # quiet, as the decode below: a slow recovery is named by the leaf
         # stage that was slow (an ask, the local reads, the launch)
@@ -549,32 +623,54 @@ class Store:
             shards: list[Optional[np.ndarray]] = [None] * ev.total_shards
             have = 0
             local_s, local_bytes = 0.0, 0
+            absent: list[int] = []
+
+            def ask(sid: int, newer_than: Optional[float] = None) -> bool:
+                t = time.perf_counter()
+                buf = self._remote_shard_read(ev, sid, offset, size, newer_than)
+                if buf is None:  # never short: that is a failed ask
+                    return False
+                # one sibling fetched from the server that holds it
+                trace.record_stage(
+                    "ec.recover.remote", time.perf_counter() - t,
+                    sid=sid, bytes=size,
+                )
+                shards[sid] = np.frombuffer(buf, dtype=np.uint8)
+                return True
+
             for sid in range(ev.total_shards):
                 if sid == missing_shard:
                     continue
                 local = ev.shards.get(sid)
-                buf = None
                 if local is not None:
                     t = time.perf_counter()
                     buf = local.read_at(offset, size)
                     local_s += time.perf_counter() - t
                     local_bytes += len(buf) if buf is not None else 0
-                else:
-                    t = time.perf_counter()
-                    buf = self._remote_shard_read(ev.id, sid, offset, size)
-                    if buf is not None:  # never short: that is a failed ask
-                        # one sibling fetched from the server that holds it
-                        trace.record_stage(
-                            "ec.recover.remote", time.perf_counter() - t,
-                            sid=sid, bytes=size,
-                        )
-                if buf is not None and len(buf) == size:
-                    shards[sid] = np.frombuffer(buf, dtype=np.uint8)
+                    if buf is not None and len(buf) == size:
+                        shards[sid] = np.frombuffer(buf, dtype=np.uint8)
+                        have += 1
+                elif ask(sid):
                     have += 1
+                else:
+                    absent.append(sid)
                 if have >= ev.data_shards:
                     break
             # the local siblings' reads of this recovery, taken together
             trace.record_stage("ec.recover.local", local_s, bytes=local_bytes)
+            if (
+                have < ev.data_shards
+                and believed is not None
+                and ev.locations_taken() == believed
+            ):
+                # every "nowhere" came from a table older than this read:
+                # one refresh, and the siblings it did not list are asked
+                # for again
+                for sid in absent:
+                    if ask(sid, newer_than=believed):
+                        have += 1
+                    if have >= ev.data_shards:
+                        break
             if have < ev.data_shards:
                 raise EcNotFoundError(
                     f"volume {ev.id} shard {missing_shard}: only {have} "

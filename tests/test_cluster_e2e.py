@@ -15,7 +15,7 @@ from seaweedfs_tpu.ec.codec import CpuCodec
 from seaweedfs_tpu.ec.constants import shard_ext
 from seaweedfs_tpu.storage.file_id import FileId
 from seaweedfs_tpu.storage.needle import Needle
-from seaweedfs_tpu.storage.store import Store
+from seaweedfs_tpu.storage.store import RemoteShards, Store
 
 
 class MiniCluster:
@@ -166,22 +166,17 @@ def test_ec_encode_spread_and_read(cluster, tmp_path):
     assert len(ec["shard_id_locations"]) == 14
 
     # read: each store can serve needles using its local shards + remote
-    # fetch routed through the master's shard locations
-    def remote_reader_for(my_url):
-        def remote_reader(vid_, sid, off, size):
-            holders = ec["shard_id_locations"].get(sid, [])
-            for h in holders:
-                if h == my_url:
-                    continue
-                ev = cluster.stores[h].find_ec_volume(vid_)
-                if ev and sid in ev.shards:
-                    return ev.shards[sid].read_at(off, size)
-            return None
+    # fetch from the holders the master lists (the seam a volume server
+    # wires, here without HTTP: the master's lookup, a holder's shard file)
+    def locate(vid_):
+        return cluster.master.lookup_ec_volume(vid_)["shard_id_locations"]
 
-        return remote_reader
+    def fetch(holder, vid_, sid, off, size):
+        return cluster.stores[holder].find_ec_volume(vid_).shards[sid].read_at(
+            off, size)
 
     reader_store = cluster.stores[urls[1]]
-    reader_store.remote_shard_reader = remote_reader_for(urls[1])
+    reader_store.remote_shards = RemoteShards(locate=locate, fetch=fetch)
     for i, want in blobs.items():
         n = Needle(id=i)
         reader_store.read_volume_needle(vid, n)

@@ -25,7 +25,7 @@ import pytest
 from seaweedfs_tpu.ec.constants import TOTAL_SHARDS, shard_ext
 from seaweedfs_tpu.storage.disk_location import DiskLocation
 from seaweedfs_tpu.storage.needle import Needle
-from seaweedfs_tpu.storage.store import Store
+from seaweedfs_tpu.storage.store import RemoteShards, Store
 from seaweedfs_tpu.storage.volume import Volume
 from seaweedfs_tpu.util import faultpoints
 
@@ -65,7 +65,7 @@ if op == "encode":
     v = build()
     v.sync()
     v.close()
-    from seaweedfs_tpu.storage.store import Store
+    from seaweedfs_tpu.storage.store import RemoteShards, Store
     store = Store([workdir], ec_backend="numpy")
     store.ec_encode_volume(1)
     store.close()
@@ -371,19 +371,25 @@ def ec_only_dir(tmp_path):
     return str(tmp_path), base, blobs
 
 
+def peer_holds_shard_0(base, fetched):
+    """The seam a volume server wires (``RemoteShards``): the master lists
+    one peer for shard 0, and the peer serves it from the file set aside."""
+
+    def fetch(holder, vid, sid, off, size):
+        fetched.append((holder, sid))
+        with open(base + ".remote00", "rb") as f:
+            f.seek(off)
+            return f.read(size)
+
+    return RemoteShards(locate=lambda vid: {0: ["peer:1"]}, fetch=fetch)
+
+
 def test_remote_fetch_retries_through_transient_faults(ec_only_dir):
     directory, base, blobs = ec_only_dir
     store = Store([directory], ec_backend="numpy")
     store.remote_fetch_backoff_s = 0.001
-
-    def reader(vid, sid, off, size):
-        if sid == 0:
-            with open(base + ".remote00", "rb") as f:
-                f.seek(off)
-                return f.read(size)
-        return None
-
-    store.remote_shard_reader = reader
+    fetched = []
+    store.remote_shards = peer_holds_shard_0(base, fetched)
     faultpoints.arm("ec.read.remote-fetch", "io-error", count=2)
     try:
         n = Needle(id=1)
@@ -391,24 +397,27 @@ def test_remote_fetch_retries_through_transient_faults(ec_only_dir):
         assert n.data == blobs[1]
         # first two attempts hit the injected EIO, the third succeeded
         assert faultpoints.hits("ec.read.remote-fetch") == 2
+        assert fetched == [("peer:1", 0)]
     finally:
         faultpoints.reset()
         store.close()
 
 
 def test_remote_fetch_exhausts_then_reconstructs(ec_only_dir):
-    """A permanently failing peer costs remote_fetch_attempts tries, then
-    the read falls through to RS reconstruction from local shards."""
+    """A LISTED peer that fails for good costs remote_fetch_attempts tries,
+    then the read falls through to RS reconstruction from local shards."""
     directory, base, blobs = ec_only_dir
     store = Store([directory], ec_backend="numpy")
     store.remote_fetch_backoff_s = 0.001
-    store.remote_shard_reader = lambda vid, sid, off, size: None
+    fetched = []
+    store.remote_shards = peer_holds_shard_0(base, fetched)
     faultpoints.arm("ec.read.remote-fetch", "io-error", count=0)
     try:
         n = Needle(id=2)
         store.read_volume_needle(9, n)
         assert n.data == blobs[2]
         assert faultpoints.hits("ec.read.remote-fetch") == store.remote_fetch_attempts
+        assert fetched == []  # every attempt died at the fault point
     finally:
         faultpoints.reset()
         store.close()

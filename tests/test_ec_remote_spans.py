@@ -3,10 +3,11 @@ servers in this process (CPU codec), one volume sealed and spread by
 ``commands.ec_encode`` itself, the seal's source stopped. What the program
 does is held to the plain reference (``benchmark/reference_spread.py``: the
 plan, where a needle's bytes lie, its bytes from any ten shard files), and
-what it leaves in the stage table (``ec.read.lookup``, ``ok`` / ``bytes`` on
-``ec.read.remote``, ``ec.shard.serve``, ``ec.recover.remote``,
+what it leaves in the stage table (``ec.read.lookup``, ``ok`` / ``bytes`` /
+``absent`` on ``ec.read.remote``, ``ec.shard.serve``, ``ec.recover.remote``,
 ``ec.spread.copy``) is counted. Beside ``test_ec_stage_spans.py``, whose
-remote reads all fail; here they are answered."""
+remote reads all fail; here they are answered — and the shards that were on
+the stopped server are answered "nowhere" by the survivors' location tables."""
 
 from __future__ import annotations
 
@@ -231,7 +232,9 @@ def test_a_healthy_remote_read_counts_lookup_fetch_and_serve(spread):
     assert delta(before, after, "ec.read.remote", "bytes") == nbytes
     assert 0 < delta(before, after, "ec.read.remote", "ok_s") <= delta(
         before, after, "ec.read.remote", "busy_s")
-    assert delta(before, after, "ec.read.lookup", "n") == asks
+    # the table is the survivor's, taken once: at most by this GET
+    assert delta(before, after, "ec.read.lookup", "n") <= 1
+    assert delta(before, after, "ec.read.remote", "absent") == 0
     assert delta(before, after, "ec.shard.serve", "n") == asks
     assert delta(before, after, "ec.shard.serve", "bytes") == nbytes
     assert delta(before, after, "ec.recover", "n") == 0
@@ -254,8 +257,11 @@ def test_a_recovery_fetches_its_live_siblings_remotely(spread):
     assert delta(before, after, "ec.recover.remote", "bytes") == siblings * lost_bytes[0]
     assert delta(before, after, "ec.recover.remote", "busy_s") > 0
     # the ask before the recovery and siblings 4, 8, 12 (or 0) inside it:
-    # four asks nobody answers, three attempts each — the policy as it is
-    assert delta(before, after, "ec.read.remote", "failed") == 12
+    # four asks the table answers "nowhere" — no attempt, no sleep
+    assert delta(before, after, "ec.read.remote", "absent") == 4
+    assert delta(before, after, "ec.read.remote", "failed") == 0
+    assert delta(before, after, "ec.read.remote", "slept_s") == 0
+    assert delta(before, after, "ec.read.lookup", "n") <= 1
     assert delta(before, after, "ec.read.remote", "ok") == siblings + need["remote"]
     assert delta(before, after, "ec.shard.serve", "n") == siblings + need["remote"]
     tree = assemble_tree(http_json(
@@ -269,14 +275,69 @@ def test_a_recovery_fetches_its_live_siblings_remotely(spread):
 
     seen = [n for root in tree for n in names(root, [])]
     assert seen.count("ec.recover.remote") == siblings
-    assert "ec.read.lookup" in seen and "ec.recover.decode" in seen
+    assert seen.count("ec.read.remote") == 4 + siblings + need["remote"]
+    assert "ec.recover.decode" in seen
+
+
+def test_lookups_at_the_master_fall_and_answered_remote_reads_do_not(spread):
+    """Every needle from one survivor, twice: what the reference says it
+    takes — a range from its holder for every piece on a live shard that is
+    not local, six or seven siblings and four "nowhere" for every piece on
+    the dead server's — and not one lookup at the master."""
+    url = spread.survivors[2]
+    siblings = K - len(spread.plan[url])
+    ok = absent = 0
+    for i in range(len(spread.loaded.fids)):
+        need = reference_spread.needs(*spread.layout.extent[i], K, SMALL,
+                                      spread.plan, url, spread.dead)
+        ok += need["remote"] + siblings * need["lost"]
+        absent += 4 * need["lost"]
+    assert ok > 0 and absent > 0
+    get(url, spread.loaded.fids[0])  # the survivor has its table from here on
+    before = STAGES.snapshot()
+    for _ in range(2):
+        for i, fid in enumerate(spread.loaded.fids):
+            body, _ = get(url, fid)
+            assert hashlib.sha256(body).hexdigest() == spread.loaded.sums[i], fid
+    after = STAGES.snapshot()
+    gets = 2 * len(spread.loaded.fids)
+    assert delta(before, after, "ec.read.lookup", "n") == 0  # was ~8.75 a GET
+    assert delta(before, after, "ec.read.remote", "ok") == 2 * ok
+    assert delta(before, after, "ec.shard.serve", "n") == 2 * ok
+    assert delta(before, after, "ec.read.remote", "absent") == 2 * absent
+    assert delta(before, after, "ec.read.remote", "failed") == 0
+    assert delta(before, after, "ec.read.remote", "slept_s") == 0
+    assert delta(before, after, "ec.read.remote", "ok") / gets > 1
+
+
+def test_a_survivor_that_took_its_table_before_the_loss_repairs_it(spread):
+    """A table that still lists the stopped server: the first ask for one of
+    its shards fails once, forgets it, takes the table anew and finds the
+    shard nowhere — one refresh, and the read is served."""
+    url = spread.survivors[0]
+    vs = next(v for v in spread.servers if f"{v.host}:{v.port}" == url)
+    ev = vs.store.find_ec_volume(spread.loaded.vid)
+    i, need = pick(spread, url, lost=1, remote_min=0)
+    get(url, spread.loaded.fids[i])
+    with ev._locations_lock:  # as the master answered before the stop
+        for x in spread.plan[spread.dead]:
+            ev._locations[x] = [spread.dead]
+    before = STAGES.snapshot()
+    body, _ = get(url, spread.loaded.fids[i])
+    after = STAGES.snapshot()
+    assert hashlib.sha256(body).hexdigest() == spread.loaded.sums[i]
+    assert delta(before, after, "ec.read.remote", "failed") == 1
+    assert delta(before, after, "ec.read.lookup", "n") == 1
+    assert delta(before, after, "ec.read.remote", "absent") == 4
+    assert all(ev.shard_holders(x) == [] for x in spread.plan[spread.dead])
 
 
 def test_status_serves_the_new_stages(spread):
     table = http_json("GET", f"http://{spread.survivors[2]}/status")[
         "ec_codec"]["stages"]
     assert set(NEW_STAGES) <= set(table)
-    assert {"ok", "ok_s", "bytes", "failed", "slept_s"} <= set(table["ec.read.remote"])
+    assert {"ok", "ok_s", "bytes", "failed", "slept_s", "absent"} <= set(
+        table["ec.read.remote"])
     assert set(table["ec.read.lookup"]) == {"n", "busy_s"}
     assert set(table["ec.shard.serve"]) == {"n", "busy_s", "bytes"}
     assert set(table["ec.recover.remote"]) == {"n", "busy_s", "bytes"}

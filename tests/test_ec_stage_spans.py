@@ -28,7 +28,7 @@ from seaweedfs_tpu.shell import commands
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.trace import RING, STAGES, assemble_tree
 from seaweedfs_tpu.storage.needle import Needle
-from seaweedfs_tpu.storage.store import Store
+from seaweedfs_tpu.storage.store import RemoteShards, Store
 from seaweedfs_tpu.util import retry
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -214,7 +214,9 @@ def test_a_degraded_get_is_one_tree_down_to_the_launch(sealed):
     remote = named(recover, "ec.read.remote")
     assert 1 <= len(remote) <= 3
     assert {r["tags"]["sid"] for r in remote} <= set(LOST)
-    assert all(r["tags"]["failed"] == 3 for r in remote)
+    # nobody holds them and the location table says so: none was attempted
+    assert all(r["tags"]["absent"] == 1 and r["tags"]["failed"] == 0
+               and r["tags"]["slept_s"] == 0 for r in remote)
     (local,) = named(recover, "ec.recover.local")
     # ten shards are left, all here: each gave the interval's range
     assert local["tags"]["bytes"] == 10 * recover["tags"]["size"]
@@ -257,37 +259,61 @@ def test_remote_reads_count_attempts_that_raised_and_the_back_off_slept(
         tmp_path, monkeypatch):
     # full jitter draws from [0, d]: take d itself, so the delays are known
     monkeypatch.setattr(retry.random, "uniform", lambda lo, hi: hi)
-    store = Store([str(tmp_path)], ec_backend="numpy",
+    store = Store([str(tmp_path)], ec_backend="numpy")
+    store.add_volume(7)
+    store.write_volume_needle(7, Needle(cookie=1, id=1, data=b"n" * 500))
+    store.ec_encode_volume(7)
+    store.close()
+    store = Store([str(tmp_path)], ec_backend="numpy",  # mounts the EC volume
                   remote_fetch_attempts=3, remote_fetch_backoff_s=0.002)
+    ev = store.find_ec_volume(7)
     asked = []
 
-    def nobody_holds_it(vid, sid, offset, size):
-        asked.append(sid)
-        raise ConnectionError("no server holds this shard")
+    def nobody_answers(holder, vid, sid, offset, size):
+        asked.append((holder, sid))
+        raise ConnectionError(f"{holder} is down")
 
-    store.remote_shard_reader = nobody_holds_it
-    before = STAGES.snapshot()
+    # the master lists a holder for four shards, and the holder is down
     calls = 4
+    store.remote_shards = RemoteShards(
+        locate=lambda vid: {sid: ["down:1"] for sid in range(calls)},
+        fetch=nobody_answers,
+    )
+    before = STAGES.snapshot()
     for sid in range(calls):
-        assert store._remote_shard_read(7, sid, 0, 64) is None
+        assert store._remote_shard_read(ev, sid, 0, 64) is None
     after = STAGES.snapshot()
-    assert len(asked) == 3 * calls
+    assert asked == [("down:1", sid) for sid in range(calls) for _ in range(3)]
     assert delta(before, after, "ec.read.remote", "n") == calls
     assert delta(before, after, "ec.read.remote", "failed") == 3 * calls
+    assert delta(before, after, "ec.read.remote", "absent") == 0
     # two sleeps a call: base, then twice the base
     slept = delta(before, after, "ec.read.remote", "slept_s")
     assert slept == pytest.approx(calls * (0.002 + 0.004))
     assert delta(before, after, "ec.read.remote", "busy_s") >= slept
-    # a reader that answers leaves a call with nothing failed or slept
-    store.remote_shard_reader = lambda vid, sid, offset, size: b"x" * size
-    assert store._remote_shard_read(7, 0, 0, 64) == b"x" * 64
+    # every failure had the next try take the table anew: the first ask's
+    # three tries and the later asks' second and third
+    assert delta(before, after, "ec.read.lookup", "n") == 3 + 2 * (calls - 1)
+    # a holder that answers leaves a call with nothing failed or slept
+    store.remote_shards = store.remote_shards._replace(
+        fetch=lambda holder, vid, sid, offset, size: b"x" * size)
+    assert store._remote_shard_read(ev, 0, 0, 64) == b"x" * 64
     last = STAGES.snapshot()
     assert delta(after, last, "ec.read.remote", "n") == 1
     assert delta(after, last, "ec.read.remote", "failed") == 0
+    assert delta(after, last, "ec.read.remote", "ok") == 1
+    # a shard the table does not list is nowhere: a span, and nothing in it
+    assert store._remote_shard_read(ev, 9, 0, 64) is None
+    nowhere = STAGES.snapshot()
+    assert delta(last, nowhere, "ec.read.remote", "n") == 1
+    assert delta(last, nowhere, "ec.read.remote", "absent") == 1
+    assert delta(last, nowhere, "ec.read.remote", "failed") == 0
+    assert delta(last, nowhere, "ec.read.remote", "slept_s") == 0
+    assert delta(last, nowhere, "ec.read.lookup", "n") == 0
     # and a store no volume server wired asks nobody: no span
-    store.remote_shard_reader = None
-    assert store._remote_shard_read(7, 0, 0, 64) is None
-    assert delta(last, STAGES.snapshot(), "ec.read.remote", "n") == 0
+    store.remote_shards = None
+    assert store._remote_shard_read(ev, 0, 0, 64) is None
+    assert delta(nowhere, STAGES.snapshot(), "ec.read.remote", "n") == 0
     store.close()
 
 

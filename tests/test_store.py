@@ -12,7 +12,8 @@ from seaweedfs_tpu.ec.ec_volume import EcVolume, rebuild_ecx_file
 from seaweedfs_tpu.ec.ec_volume import DeletedError as EcDeletedError
 from seaweedfs_tpu.storage.disk_location import DiskLocation, parse_volume_base_name
 from seaweedfs_tpu.storage.needle import Needle
-from seaweedfs_tpu.storage.store import Store
+from seaweedfs_tpu.stats.trace import STAGES
+from seaweedfs_tpu.storage.store import RemoteShards, Store
 from seaweedfs_tpu.storage.volume import NotFoundError
 
 
@@ -177,27 +178,33 @@ def test_ec_heartbeat_bits(ec_store):
 
 
 def test_remote_shard_reader_hook(ec_store):
-    """Missing local shard + injected remote reader → no reconstruction."""
+    """Missing local shard + a wired cluster that lists a holder for it →
+    read from the holder, no reconstruction; the lookup is made once."""
     directory, base, blobs = ec_store
     # steal shard 2 away to simulate a remote holder
     remote_path = base + ".remote02"
     os.rename(base + shard_ext(2), remote_path)
     store = Store([directory], ec_backend="cpu")
 
-    calls = []
+    lookups, calls = [], []
 
-    def remote_reader(vid, sid, off, size):
-        calls.append((vid, sid))
-        if sid == 2:
-            with open(remote_path, "rb") as f:
-                f.seek(off)
-                return f.read(size)
-        return None
+    def locate(vid):
+        lookups.append(vid)
+        return {2: ["holder:1"]}
 
-    store.remote_shard_reader = remote_reader
+    def fetch(holder, vid, sid, off, size):
+        calls.append((holder, vid, sid))
+        with open(remote_path, "rb") as f:
+            f.seek(off)
+            return f.read(size)
+
+    store.remote_shards = RemoteShards(locate=locate, fetch=fetch)
+    before = STAGES.snapshot().get("ec.recover", {}).get("n", 0)
     for i, want in blobs.items():
         n = Needle(id=i)
         store.read_volume_needle(10, n)
         assert n.data == want
-    assert any(sid == 2 for _, sid in calls)
+    assert calls and set(calls) == {("holder:1", 10, 2)}
+    assert lookups == [10]  # the table is kept on the EC volume
+    assert STAGES.snapshot().get("ec.recover", {}).get("n", 0) == before
     store.close()
