@@ -29,11 +29,11 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-from .. import fixture, reference, reference_spread, stages, stats
+from .. import fixture, reference, reference_spread, stages
 from ..cluster import Cluster
 from ..daemon import get_json
 from ..harness import Check, Run, say, sha256_file
-from .open_loop_get import arrivals, backlog_growth, request_list
+from .open_loop_get import arrivals, latencies, request_list
 
 
 def targets(n: int, servers: int, seed: int) -> np.ndarray:
@@ -426,14 +426,7 @@ def measure(run: Run, state: dict) -> dict:
              for k in ("recoveries", "remote_reads")}
     say(f"[cluster] GETs by survivor {by_server}; {share['recoveries']:.1%} "
         f"recover, {share['remote_reads']:.1%} read a live remote shard")
-    lat_ms = [r["latency_s"] * 1e3 for r in log]
-    if not run.rehearsal:  # a rehearsal prints no latency
-        say(f"[readings] {n} GETs, p50 {stats.median(lat_ms):.2f} ms, "
-            f"max {max(lat_ms):.2f} ms, backlog growth "
-            f"{backlog_growth(log, seconds):.3f}")
-    end_to_end = {"get_p50_ms": stats.median(lat_ms), "get_p95_ms": None}
-    if not run.rehearsal:
-        end_to_end["get_p95_ms"] = stats.percentile(lat_ms, 95)
+    end_to_end, summary = latencies(run, log, seconds)
     dead = [state["codecs"][state["source"]]]
     return {
         "attempted": n,
@@ -441,6 +434,7 @@ def measure(run: Run, state: dict) -> dict:
         "setup_s": setup_s,
         "window_s": window_s,
         "end_to_end": end_to_end,
+        "summary": summary,
         "counts": {"gets": n, "shapes_warmed": shapes,
                    "servers": cluster.n, "survivors": len(survivors)},
         "readings": {

@@ -8,10 +8,12 @@ write-back does not land in the next cycle's clock. A cycle that has begun
 when the window runs out is finished and counted. The run's rates are taken
 over ALL of the window's seals and ALL of its rebuilds: bytes sealed over the
 seconds spent sealing, bytes rebuilt over the seconds spent rebuilding, so a
-stall in any operation shows. The medians of the per-operation readings, and
-the share of the window outside both clocks, stand beside them as per-layer
-metrics (``client.seal_rate_p50``, ``client.rebuild_rate_p50``,
-``client.untimed_share``).
+stall in any operation shows. Beside them, on every run's ``[readings]`` line
+and as per-layer metrics, stand the medians of the per-operation readings
+(``client.seal_rate_p50``, ``client.rebuild_rate_p50``), the operations that
+took more than twice the median of their kind (``client.stalled_ops``: what
+tells a stalled run from a slow one) and the share of the window outside both
+clocks (``client.untimed_share``).
 """
 
 from __future__ import annotations
@@ -19,9 +21,11 @@ from __future__ import annotations
 import os
 import time
 
+from .. import stats
 from ..harness import Run, say
 
 MB = 1e6
+STALLED = 2.0  # an operation is stalled beyond this many medians of its kind
 
 
 def cycle(run: Run, lost: list[int], log: list[dict]) -> None:
@@ -59,6 +63,36 @@ def rates(dat_bytes: int, seal_s: list[float], rebuild_s: list[float]) -> dict:
         return {"seal_rate": None, "rebuild_rate": None}
     return {"seal_rate": dat_bytes * len(seal_s) / MB / sum(seal_s),
             "rebuild_rate": dat_bytes * len(rebuild_s) / MB / sum(rebuild_s)}
+
+
+def median_rate(dat_bytes: int, seconds: list[float]):
+    """MB/s of a window's median operation of one kind: the steadier
+    statistic beside a rate, which carries every stall. None for a window
+    without one."""
+    if not seconds:
+        return None
+    return stats.median(dat_bytes / s / MB for s in seconds)
+
+
+def stalled_ops(seal_s: list[float], rebuild_s: list[float]):
+    """The window's seals and rebuilds that took more than twice the median
+    of their kind. None for a window without an operation."""
+    if not seal_s and not rebuild_s:
+        return None
+    return sum(
+        sum(s > STALLED * stats.median(kind) for s in kind)
+        for kind in (seal_s, rebuild_s) if kind
+    )
+
+
+def beside(dat_bytes: int, seal_s: list[float], rebuild_s: list[float]) -> dict:
+    """What stands beside the rates on every run's line, under the names of
+    the per-layer metrics that read the same."""
+    return {
+        "client.seal_rate_p50": median_rate(dat_bytes, seal_s),
+        "client.rebuild_rate_p50": median_rate(dat_bytes, rebuild_s),
+        "client.stalled_ops": stalled_ops(seal_s, rebuild_s),
+    }
 
 
 def run_cell(run: Run) -> dict:
@@ -122,17 +156,21 @@ def run_cell(run: Run) -> dict:
     seal_s = [r["seal_s"] for r in done]
     rebuild_s = [r["rebuild_s"] for r in done]
     end_to_end = rates(run.dat_bytes, seal_s, rebuild_s)
+    summary = beside(run.dat_bytes, seal_s, rebuild_s)
     if done and not run.rehearsal:  # a rehearsal prints no rate
         say(f"[readings] seal_rate {end_to_end['seal_rate']:.2f} MB/s over "
             f"{len(done)} seals in {sum(seal_s):.3f} s; rebuild_rate "
             f"{end_to_end['rebuild_rate']:.2f} MB/s over {len(done)} rebuilds "
-            f"in {sum(rebuild_s):.3f} s; window {window_s:.3f} s")
+            f"in {sum(rebuild_s):.3f} s; " + "; ".join(
+                f"{name} {value:.6g}" for name, value in summary.items()
+            ) + f"; window {window_s:.3f} s")
     return {
         "attempted": 2 * len(done) + failed,
         "failed": failed,
         "setup_s": setup_s,
         "window_s": window_s,
         "end_to_end": end_to_end,
+        "summary": {} if run.rehearsal else summary,
         "counts": {"seals": len(done), "rebuilds": len(done)},
         "readings": {"seal_s": seal_s, "rebuild_s": rebuild_s,
                      "dat_bytes": run.dat_bytes},

@@ -160,6 +160,32 @@ def backlog_growth(log: list[dict], seconds: float) -> float:
     return med(0.75 * seconds, seconds) / med(0.25 * seconds, 0.5 * seconds)
 
 
+TAILS = (90, 95, 99)  # every run reads all three; BENCHMARK.json names one
+
+
+def latencies(run: Run, log: list[dict], seconds: float) -> tuple[dict, dict]:
+    """(the window's latencies under the names an end-to-end metric may
+    have, what stands beside them on the run's line): the median and each
+    tail over ALL of the window's GETs, a tail left out where fewer than ten
+    samples lie beyond it. A rehearsal prints no latency."""
+    lat_ms = [r["latency_s"] * 1e3 for r in log]
+    end_to_end = {"get_p50_ms": stats.median(lat_ms)}
+    for p in TAILS:
+        end_to_end[f"get_p{p}_ms"] = stats.percentile_or_none(lat_ms, p)
+    summary = dict(
+        end_to_end, gets=len(log), max_ms=max(lat_ms),
+        backlog_growth=backlog_growth(log, seconds),
+        sched_lag_p99_ms=stats.percentile_or_none(
+            [r["lag_s"] * 1e3 for r in log], 99, min_beyond=1),
+    )
+    if run.rehearsal:
+        return end_to_end, {}
+    say("[readings] " + "; ".join(
+        f"{name} {value:.6g}" for name, value in summary.items()
+        if value is not None))
+    return end_to_end, summary
+
+
 def run_cell(run: Run) -> dict:
     mix = run.mix
     seconds = run.args.seconds
@@ -201,20 +227,14 @@ def run_cell(run: Run) -> dict:
                 failed + warm_failed)
     run.status_check(before, after)
 
-    lat_ms = [r["latency_s"] * 1e3 for r in log]
-    if not run.rehearsal:  # a rehearsal prints no latency
-        say(f"[readings] {n} GETs, p50 {stats.median(lat_ms):.2f} ms, "
-            f"max {max(lat_ms):.2f} ms, backlog growth "
-            f"{backlog_growth(log, seconds):.3f}")
-    end_to_end = {"get_p50_ms": stats.median(lat_ms), "get_p95_ms": None}
-    if not run.rehearsal:
-        end_to_end["get_p95_ms"] = stats.percentile(lat_ms, 95)
+    end_to_end, summary = latencies(run, log, seconds)
     return {
         "attempted": n,
         "failed": failed,
         "setup_s": setup_s,
         "window_s": window_s,
         "end_to_end": end_to_end,
+        "summary": summary,
         "counts": {"gets": n, "shapes_warmed": shapes},
         "readings": {"gets": log},
         "status": {"before": before, "after": after},

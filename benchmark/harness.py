@@ -8,6 +8,7 @@ import hashlib
 import json
 import os
 import shutil
+import sys
 import time
 import urllib.error
 from concurrent.futures import ThreadPoolExecutor
@@ -46,10 +47,15 @@ class Check:
     def correct(self) -> bool:
         return all(value <= limit for _, value, limit in self.rows)
 
-    def report(self) -> None:
+    def report(self) -> dict:
+        """Each number compared beside its limit: as the run's last lines
+        on standard error, and returned for the result's line."""
         for name, value, limit in self.rows:
             verdict = "ok" if value <= limit else "FAILED"
-            say(f"[compare] {name}: {value} (limit {limit}) {verdict}")
+            print(f"[compare] {name}: {value} (limit {limit}) {verdict}",
+                  file=sys.stderr, flush=True)
+        return {name: {"value": value, "limit": limit}
+                for name, value, limit in self.rows}
 
 
 class Run:

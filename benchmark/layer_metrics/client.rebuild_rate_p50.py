@@ -7,6 +7,7 @@ SOURCE = "host_clock"
 
 
 def read(ctx):
-    from benchmark.layers import load_reader
+    from benchmark.generators.maintain_cycle import median_rate
 
-    return load_reader("client.seal_rate_p50").read(ctx, "rebuild_s")
+    client = ctx["client"]
+    return median_rate(client.get("dat_bytes"), client.get("rebuild_s") or [])

@@ -1,18 +1,15 @@
 """Median over the window's seals of ``.dat`` bytes over the wall of that
 seal: the steadier statistic beside ``seal_rate``, which is taken over all
-seals and so carries every stall. The two apart say a stall was there."""
+seals and so carries every stall. The two apart say a stall was there, and
+``client.stalled_ops`` counts them."""
 LAYER = "client"
 UNIT = "MB/s"
 MOVES = "seal_rate"
 SOURCE = "host_clock"
-WALLS = "seal_s"
 
 
-def read(ctx, walls=WALLS):
-    from benchmark import stats
+def read(ctx):
+    from benchmark.generators.maintain_cycle import median_rate
 
     client = ctx["client"]
-    seconds = client.get(walls) or []
-    if not seconds:
-        return None
-    return stats.median(client["dat_bytes"] / s / 1e6 for s in seconds)
+    return median_rate(client.get("dat_bytes"), client.get("seal_s") or [])

@@ -2,7 +2,7 @@
 record of where it sent each): a third when the draw is even."""
 LAYER = "client"
 UNIT = "%"
-MOVES = "get_p95_ms"
+MOVES = "get_p90_ms"
 SOURCE = "host_clock"
 
 

@@ -2,7 +2,7 @@
 warm window makes none, whether the compiler or the cache would answer."""
 LAYER = "codec"
 UNIT = "count"
-MOVES = "get_p95_ms"
+MOVES = "get_p50_ms"
 SOURCE = "program_counter"
 
 

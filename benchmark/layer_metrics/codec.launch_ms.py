@@ -3,7 +3,7 @@
 read pays per call."""
 LAYER = "codec"
 UNIT = "ms"
-MOVES = "get_p95_ms"
+MOVES = "get_p50_ms"
 SOURCE = "program_span"
 
 

@@ -1,9 +1,12 @@
-"""``/dir/lookup_ec`` calls at the master (``n`` of ``ec.read.lookup``, one
-per attempt of every ask) per GET of the window, summed over the survivors:
-the load the read path puts on the one master."""
+"""``/dir/lookup_ec`` calls at the master (``n`` of ``ec.read.lookup``) per
+GET of the window, summed over the survivors: the load the read path puts on
+the one master. Since ISSUE 29 it is one call per REFRESH of a volume's
+shard-location table (``EcVolume.refresh_locations``: a table gone stale, or
+a listed holder that failed), 0.0 in a window whose tables were taken in
+warm-up; before, one per attempt of every ask."""
 LAYER = "master"
 UNIT = "count"
-MOVES = "get_p95_ms"
+MOVES = "get_p90_ms"
 SOURCE = "program_span"
 
 

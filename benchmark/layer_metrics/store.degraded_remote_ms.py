@@ -3,7 +3,7 @@
 and one per missing sibling inside it), per ``ec.recover``."""
 LAYER = "store / commit"
 UNIT = "ms"
-MOVES = "get_p95_ms"
+MOVES = "get_p50_ms"
 SOURCE = "program_span"
 
 

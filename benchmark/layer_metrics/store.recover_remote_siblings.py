@@ -3,7 +3,7 @@
 decode needs, those that were not on the recovering server's own disks."""
 LAYER = "store / commit"
 UNIT = "count"
-MOVES = "get_p95_ms"
+MOVES = "get_p90_ms"
 SOURCE = "program_span"
 
 

@@ -2,10 +2,10 @@
 needle has an interval on a lost data shard: the reader who waits for
 ``_recover_interval``. ``get_p50_ms`` follows the healthy path wherever
 fewer than half the GETs recover; this is the median a change to the
-recovery moves, and the body of the tail ``get_p95_ms`` reads."""
+recovery moves, and the body of the tail ``get_p90_ms`` reads."""
 LAYER = "store / commit"
 UNIT = "ms"
-MOVES = "get_p95_ms"
+MOVES = "get_p50_ms"
 SOURCE = "host_clock"
 
 

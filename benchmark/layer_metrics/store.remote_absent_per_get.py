@@ -4,7 +4,7 @@ lookup) per GET of the window: how often a degraded read believes the
 master instead of asking again. 0 from a program that keeps no table."""
 LAYER = "store / commit"
 UNIT = "count"
-MOVES = "get_p95_ms"
+MOVES = "get_p50_ms"
 SOURCE = "program_span"
 
 

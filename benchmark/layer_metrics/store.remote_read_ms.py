@@ -1,7 +1,8 @@
 """Mean wall of one answered remote shard read at the asker (``ok_s`` over
 ``ok`` of ``ec.read.remote``): the attempt that was answered alone — the
-lookup at the master and the range from the holder — without the failed
-attempts and the back-off an ask may have spent before it."""
+shard-location table (since ISSUE 29 a lookup at the master only when the
+table is stale) and the range from the holder — without any earlier attempt
+or sleep."""
 LAYER = "store / commit"
 UNIT = "ms"
 MOVES = "get_p50_ms"

@@ -1,5 +1,10 @@
-"""Share of a seal (``ec.seal``) spent re-reading the 14 staged shards and
-hashing them (``ec.seal.hash``): what hashing while writing can take out."""
+"""``busy_s`` of ``ec.seal.hash`` over ``busy_s`` of ``ec.seal``. Since PR 27
+``ec.seal.hash`` is the seconds inside one shard's running SHA-256 (fourteen
+records a seal), spent on the writer's pool WHILE the rows are written: the
+threads' seconds summed, overlapped with the pipeline, over the seal's wall.
+It is no serial share and can pass 100%: it says the hashing engaged and what
+it costs in cores. The serial tail is ``store.seal_tail_share``. (Before
+PR 27: the share of a seal spent reading the staged shards back to hash them.)"""
 LAYER = "store / commit"
 UNIT = "%"
 MOVES = "seal_rate"

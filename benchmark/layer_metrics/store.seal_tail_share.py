@@ -1,6 +1,8 @@
 """Share of a seal (``Store.ec_encode_volume``) spent after
-``write_ec_files`` has returned: re-read + SHA-256 of 14 shards, fsync,
-manifest, renames."""
+``write_ec_files`` has returned. Since PR 27 that is the ``.ecx`` and the
+commit alone (``.vif``, fsyncs, manifest, renames): the shards are hashed as
+they are written, inside the pipeline. Before, it held the read back and
+SHA-256 of the 14 shards too."""
 LAYER = "store / commit"
 UNIT = "%"
 MOVES = "seal_rate"

@@ -4,10 +4,12 @@
     python3 benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The last line of standard output is the result: one JSON object with
-``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and
-``breakdown`` when traced). Everything else — each number compared beside
-its limit, every in-run reading — is on earlier lines and in
-``chiprun_out/benchmark/<cell>-s<seed>-t<trace>/``.
+``correct``, ``attempted``, ``failed``, ``metrics`` and ``device``
+(``breakdown`` when traced), then ``readings`` (what the generator read
+beside the metrics: the tails, the in-run medians, the stalled operations) and, last,
+``compared``: each number compared beside its limit, which are also the
+last lines on standard error. Every in-run reading is on earlier lines and
+in ``chiprun_out/benchmark/<cell>-s<seed>-t<trace>/``.
 
 This process never imports JAX: the daemon child owns the chip.
 """
@@ -100,7 +102,6 @@ def main() -> int:
         json.dump({"cell": cell["name"], "seed": args.seed,
                    "setup_s": out["setup_s"], "window_s": out["window_s"],
                    "reference_s": run.reference_s, **out["readings"]}, f)
-    run.check.report()
     codec = out["status"]["after"]
     device = run.device_block(codec)
     line: dict = {
@@ -137,6 +138,9 @@ def main() -> int:
             metrics["rehearsal." + name] = {"value": n, "unit": "count"}
     line["metrics"] = metrics
     line["device"] = device
+    if out["summary"]:  # what stands beside the metrics, traced or not
+        line["readings"] = out["summary"]
+    line["compared"] = run.check.report()  # last on stderr, last in the line
     print(json.dumps(line), flush=True)
     return 0
 

@@ -31,6 +31,15 @@ def percentile(values, p: float, min_beyond: int = 10) -> float:
     return float(ordered[rank - 1])
 
 
+def percentile_or_none(values, p: float, min_beyond: int = 10):
+    """``percentile``, or None where it is refused: the reading is then
+    left out of the line, as a reader's that found nothing to read."""
+    try:
+        return percentile(values, p, min_beyond)
+    except ValueError:
+        return None
+
+
 def iqr_share(values) -> float:
     """Distance between the first and third quartile as a share of the
     median — the spread a bound is set from (``statistics.quantiles``)."""

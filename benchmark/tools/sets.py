@@ -7,10 +7,14 @@ process at ``run_seconds``:
         --seeds 2147480001,2147480002,... [--control wrong-codec] [--seconds S]
 
 Result lines go to ``chiprun_out/sets/<cell>.<tag><set>.jsonl`` (read them
-with ``spread.py``), and each run's in-run readings, where they are few (a
-maintain window's seals and rebuilds), to ``...<set>.readings.jsonl``: the
-sets share their seeds, so the second would overwrite the first's run
-directories. The tail of a run that printed no result is shown.
+with ``spread.py``). A line carries, under ``readings``, what a later session
+needs to derive a bound again without the chip: a read window's p50 / p90 /
+p95 / p99, its backlog growth and the generator's lag; a maintain window's
+in-run medians and stalled operations. Each run's in-run readings, where they are few
+(a maintain window's seals and rebuilds; a read window's GETs are megabytes),
+go to ``...<set>.readings.jsonl``: the sets share their seeds, so the second
+would overwrite the first's run directories. The tail of a run that printed
+no result is shown.
 """
 
 from __future__ import annotations
@@ -71,11 +75,14 @@ def main() -> None:
                     keep_readings(args, seed, path)
                     got = json.loads(last)
                     shown = {k: round(v["value"], 3) for k, v in got["metrics"].items()}
+                    beside = {k: round(v, 3) for k, v in
+                              got.get("readings", {}).items() if v is not None}
                     print(f"set {s} seed {seed}: correct={got['correct']} "
                           f"failed={got['failed']}/{got['attempted']} {shown} "
-                          f"wall {wall:.0f} s", flush=True)
+                          f"{beside} wall {wall:.0f} s", flush=True)
                     if not got["correct"]:
-                        print("\n".join(l for l in lines if "[compare]" in l))
+                        print({k: v for k, v in got["compared"].items()
+                               if v["value"] > v["limit"]}, flush=True)
                 else:
                     print(f"set {s} seed {seed}: exit {r.returncode}, no "
                           f"result\n{r.stdout[-1500:]}\n{r.stderr[-1500:]}",

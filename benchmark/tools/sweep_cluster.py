@@ -10,8 +10,9 @@ per rate over the survivors, each with its own stratified request list and
 its own draw of servers, warmed first. One JSON line per rate: offered and
 completed GET/s, p50 / p95, the backlog growth (median latency of the last
 quarter over the second quarter), the drain after the last arrival, and
-what the survivors did per GET. The knee is the highest rate whose backlog
-does not grow; the cell's rate is 0.6 of it. Every GET of every window goes
+what the survivors did per GET. The knee is the highest rate with no
+growing window at or beneath it; the cell's rate lies between 0.6 and 0.8 of
+it (``--refine N``: a second pass of N rates above it, as in ``sweep.py``). Every GET of every window goes
 to ``chiprun_out/benchmark/sweep-*.json``.
 """
 
@@ -30,7 +31,8 @@ from benchmark import stats  # noqa: E402
 from benchmark.generators import cluster_open_loop_get as cg  # noqa: E402
 from benchmark.harness import Run  # noqa: E402
 from benchmark.run import load_json, named  # noqa: E402
-from benchmark.tools.sweep import pct  # noqa: E402
+from benchmark.generators.open_loop_get import backlog_growth  # noqa: E402
+from benchmark.tools.sweep import passes, say_knee  # noqa: E402
 
 
 def main() -> None:
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--rates", required=True)
     ap.add_argument("--seconds", type=float, default=15.0)
+    ap.add_argument("--refine", type=int, default=0)
     ap.add_argument("--rehearsal", action="store_true")
     args = ap.parse_args()
     args.trace, args.control = 0, ""
@@ -48,13 +51,13 @@ def main() -> None:
     cfg = load_json(named(bench["configs"], cell["config"], "config")["file"])
     mix = load_json("benchmark", "traffic", cell["traffic"] + ".json")
     run = Run(args, time.monotonic(), cell, cfg, mix)
-    dump = {}
+    dump, rows = {}, []
     try:
         with cg.cluster_of(run) as cluster:
             state = cg.prepare(run, cluster)
             survivors = state["survivors"]
             print(json.dumps({"setup_s": run.setup_seconds()}), flush=True)
-            for step, rate in enumerate(float(r) for r in args.rates.split(",")):
+            for step, rate in enumerate(passes(args, rows)):
                 seed = args.seed + step
                 n = max(1, round(rate * args.seconds))
                 picked = cg.request_list(run.loaded, n, seed)
@@ -83,11 +86,12 @@ def main() -> None:
                     "failed": sum(not r["ok"] for r in log),
                     "rate_completed": n / last_done,
                     "p50_ms": stats.median(lat),
-                    "p95_ms": pct(lat, 95, 3),
+                    "p95_ms": stats.percentile_or_none(lat, 95, 3),
                     "max_ms": max(lat),
-                    "backlog_growth": cg.backlog_growth(log, args.seconds),
+                    "backlog_growth": backlog_growth(log, args.seconds),
                     "drain_s": last_done - float(due[-1]),
-                    "lag_p99_ms": pct([r["lag_s"] * 1e3 for r in log], 99, 1),
+                    "lag_p99_ms": stats.percentile_or_none(
+                        [r["lag_s"] * 1e3 for r in log], 99, 1),
                     "recovering_share": sum(r["recoveries"] > 0 for r in log) / n,
                     "recovering_p50_ms": stats.median(
                         [r["latency_s"] * 1e3 for r in log if r["recoveries"]] or [0.0]),
@@ -96,7 +100,9 @@ def main() -> None:
                     - before["compiles"]["requests"],
                 }
                 print(json.dumps(row), flush=True)
+                rows.append(row)
                 dump[str(rate)] = log
+            say_knee(rows)
     finally:
         run.cleanup()
     out = os.path.join(ROOT, "chiprun_out", "benchmark",

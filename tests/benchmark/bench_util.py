@@ -40,7 +40,15 @@ def run_cell(workload: str, seed: int, *extra: str, root: str = ROOT,
 def assert_contract_line(line: dict) -> None:
     assert line is not None
     assert LINE_KEYS <= set(line), line
-    assert set(line) <= LINE_KEYS | {"breakdown"}
+    assert set(line) <= LINE_KEYS | {"breakdown", "readings", "compared"}
+    # each number compared beside its limit, under a key that comes last
+    assert list(line)[-1] == "compared" and line["compared"]
+    for name, row in line["compared"].items():
+        assert set(row) == {"value", "limit"}, (name, row)
+    assert line["correct"] == all(
+        row["value"] <= row["limit"] for row in line["compared"].values())
+    # a CPU rehearsal's line carries no reading beside its counts
+    assert "readings" not in line
     assert isinstance(line["correct"], bool)
     assert line["attempted"] > 0 and line["failed"] >= 0
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(line["device"])

@@ -103,22 +103,53 @@ def listed(cell):
     return [m["name"] for m in NEW if cell in m["workloads"]]
 
 
+@pytest.fixture(scope="module")
+def rehearsed():
+    """One traced rehearsal a read cell, shared by the cases that hold it
+    to one thing each."""
+    runs = {}
+
+    def run(cell):
+        if cell not in runs:
+            runs[cell] = run_cell(cell, 2_147_483_400 + len(cell), trace=1,
+                                  seconds=3)
+        return runs[cell]
+
+    return run
+
+
+@pytest.mark.parametrize("held", ["no_attempt_fails", "the_table_answers"])
 @pytest.mark.parametrize("cell", ["warm1.read-degraded", "warm1.read-1lost"])
-def test_rehearsed_read_cell_counts_asks_for_shards_nobody_holds(cell):
-    rc, line, out = run_cell(cell, 2_147_483_400 + len(cell), trace=1, seconds=3)
+def test_rehearsed_read_cell_counts_asks_for_shards_nobody_holds(
+        cell, held, rehearsed):
+    """Since ISSUE 29 an ask for a shard nobody holds is answered "nowhere"
+    by the EC volume's shard-location table: counted, never attempted."""
+    rc, line, out = rehearsed(cell)
     assert rc == 0, out[-3000:]
     assert_contract_line(line)
     assert line["correct"] is True, out[-3000:]
-    # a count, so a rehearsal prints it: three attempts for every ask
-    failed = line["metrics"]["store.remote_failed_per_get"]
-    launches = line["metrics"]["codec.launches_per_read"]["value"]
+    m = {k: v["value"] for k, v in line["metrics"].items()}
+    launches = m["codec.launches_per_read"]
+    assert launches > 0
+    if held == "no_attempt_fails":
+        # a count, so a rehearsal prints it: no attempt is made, none fails
+        assert line["metrics"]["store.remote_failed_per_get"] == {
+            "value": 0, "unit": "count"}
+        for name in listed(cell):
+            if name != "store.remote_failed_per_get":
+                assert f"[layer] {name}: read" in out, out[-3000:]
+                assert name not in line["metrics"]
+        return
+    # a recovery is one launch: the ask before it and, in read-degraded,
+    # the three lost siblings inside it — all "nowhere"
     asks = 4 if cell == "warm1.read-degraded" else 1
-    assert failed["unit"] == "count" and launches > 0
-    assert failed["value"] == pytest.approx(3 * asks * launches)
-    for name in listed(cell):
-        if name != "store.remote_failed_per_get":
-            assert f"[layer] {name}: read" in out, out[-3000:]
-            assert name not in line["metrics"]
+    absent = line["metrics"]["store.remote_absent_per_get"]
+    assert absent["unit"] == "count"
+    assert absent["value"] == pytest.approx(asks * launches)
+    for name in ("store.degraded_remote_ms", "store.degraded_decode_ms",
+                 "codec.launch_ms"):  # a number still, with no failed ask in it
+        assert f"[layer] {name}: read" in out, out[-3000:]
+        assert name not in line["metrics"]  # a rehearsal prints counts only
 
 
 def test_rehearsed_maintain_cell_reads_the_pipelines_legs_and_the_link():
@@ -130,3 +161,10 @@ def test_rehearsed_maintain_cell_reads_the_pipelines_legs_and_the_link():
     for name in names:  # read from /status, and kept off a rehearsal's line
         assert f"[layer] {name}: read" in out, out[-3000:]
         assert name not in line["metrics"]
+    # the client's three: the medians are rates, the stalled operations a
+    # count (ISSUE 31); the rates themselves are over all of the window
+    for name in ("client.seal_rate_p50", "client.rebuild_rate_p50"):
+        assert f"[layer] {name}: read" in out, out[-3000:]
+        assert name not in line["metrics"]
+    assert line["metrics"]["client.stalled_ops"]["unit"] == "count"
+    assert "client.seal_rate_total" not in out

@@ -217,23 +217,96 @@ def test_percentile_refuses_a_tail_with_fewer_than_ten_samples_beyond():
     assert stats.percentile(values, 99, min_beyond=1) == 198
 
 
-def test_a_rate_is_taken_over_all_operations_so_one_stall_shows():
-    from benchmark.generators.maintain_cycle import rates
+def maintain_ctx(seal_s, rebuild_s, window_s=60.0):
+    client = {"dat_bytes": 1_000_000_000, "seal_s": seal_s,
+              "rebuild_s": rebuild_s, "window_s": window_s}
+    return {"client": client, "trace": None}
 
+
+def test_a_rate_is_taken_over_all_operations_so_one_stall_shows():
+    """One seal three times the others: the rate falls by the share computed
+    here, the median beside it does not move, and the stall is counted."""
+    from benchmark.generators.maintain_cycle import beside, rates
+
+    read = lambda name, ctx: layers.load_reader(name).read(ctx)  # noqa: E731
     steady = rates(1_000_000_000, [5.0] * 7, [2.0] * 7)
     assert steady == {"seal_rate": 200.0, "rebuild_rate": 500.0}
-    stalled = rates(1_000_000_000, [5.0] * 6 + [12.0], [2.0] * 7)
-    assert stalled["seal_rate"] == pytest.approx(7000 / 42.0)  # 166.7, not 200
+    calm = maintain_ctx([5.0] * 7, [2.0] * 7)
+    assert read("client.seal_rate_p50", calm) == 200.0
+    assert read("client.rebuild_rate_p50", calm) == 500.0
+    assert read("client.stalled_ops", calm) == 0
+
+    seal_s = [5.0] * 6 + [15.0]
+    stalled = rates(1_000_000_000, seal_s, [2.0] * 7)
+    # 7 seals in 45 s for 35: the rate is down by 10/45 of itself
+    assert stalled["seal_rate"] == pytest.approx(7000 / 45.0)  # 155.6, not 200
+    assert stalled["seal_rate"] == pytest.approx(200.0 * (1 - 10 / 45))
     assert stalled["rebuild_rate"] == 500.0
     assert rates(1, [], []) == {"seal_rate": None, "rebuild_rate": None}
     # the median beside it does not move, and the two apart say a stall was there
-    client = {"dat_bytes": 1_000_000_000, "seal_s": [5.0] * 6 + [12.0],
-              "rebuild_s": [2.0] * 7, "window_s": 60.0}
-    ctx = {"client": client, "trace": None}
-    assert layers.load_reader("client.seal_rate_p50").read(ctx) == 200.0
-    assert layers.load_reader("client.rebuild_rate_p50").read(ctx) == 500.0
-    assert layers.load_reader("client.untimed_share").read(ctx) == pytest.approx(
-        100 * (1 - 56.0 / 60.0))
+    ctx = maintain_ctx(seal_s, [2.0] * 7)
+    assert read("client.seal_rate_p50", ctx) == 200.0
+    assert read("client.rebuild_rate_p50", ctx) == 500.0
+    assert read("client.stalled_ops", ctx) == 1
+    assert read("client.untimed_share", ctx) == pytest.approx(100 * (1 - 59.0 / 60.0))
+    # what every run's line carries, under the readers' own names
+    line = beside(1_000_000_000, seal_s, [2.0] * 7)
+    assert sorted(line) == ["client.rebuild_rate_p50", "client.seal_rate_p50",
+                            "client.stalled_ops"]
+    for name in line:
+        assert line[name] == read(name, ctx)
+
+
+def test_an_operation_is_stalled_beyond_twice_the_median_of_its_own_kind():
+    from benchmark.generators.maintain_cycle import stalled_ops
+
+    # twice the median is the line: 10.0 is on it, 4.1 and 9.0 are over theirs
+    assert stalled_ops([5.0] * 6 + [10.0], [2.0] * 5 + [4.1, 9.0]) == 2
+    assert stalled_ops([5.0] * 6 + [10.1], [2.0] * 7) == 1
+    # a rebuild as long as a seal is stalled, a seal as long as a seal is not
+    assert stalled_ops([5.0] * 7, [2.0] * 6 + [5.0]) == 1
+    assert stalled_ops([], []) is None
+    # a thin window is still read, and so are the medians beside the rate
+    thin = maintain_ctx([5.0] * 3 + [11.0], [2.0] * 4)
+    assert layers.load_reader("client.stalled_ops").read(thin) == 1
+    assert layers.load_reader("client.seal_rate_p50").read(thin) == 200.0
+    assert layers.load_reader("client.seal_rate_p50").read(maintain_ctx([], [])) is None
+
+
+def test_every_tail_is_read_and_one_with_fewer_than_ten_beyond_is_left_out():
+    import argparse
+
+    run = argparse.Namespace(rehearsal=True)
+    log = [{"latency_s": 0.001 * (i + 1), "lag_s": 0.0, "due": 0.05 * i}
+           for i in range(999)]
+    got, summary = open_loop_get.latencies(run, log, 50.0)
+    assert summary == {}  # a rehearsal prints no latency
+    assert got["get_p50_ms"] == pytest.approx(500.0)
+    assert got["get_p90_ms"] == pytest.approx(900.0)
+    assert got["get_p95_ms"] == pytest.approx(950.0)
+    assert got["get_p99_ms"] is None  # 999 samples: nine beyond the 99th
+    got, _ = open_loop_get.latencies(run, log + [dict(log[0])], 50.0)
+    assert got["get_p99_ms"] == pytest.approx(989.0)  # ten beyond it
+    assert stats.percentile_or_none(range(1, 200), 95.1) is None
+    assert stats.percentile_or_none(range(1, 201), 95) == 190
+    # the reader that keeps the old tail in sight refuses the same way
+    reader = layers.load_reader("client.get_p95_ms")
+    gets = [{"latency_s": 0.001 * (i + 1)} for i in range(200)]
+    assert reader.read({"client": {"gets": gets}}) == pytest.approx(190.0)
+    assert reader.read({"client": {"gets": gets[:180]}}) is None  # 9 beyond
+    assert reader.read({"client": {}}) is None
+    tail = next(m for m in BENCH["end_to_end"] if m["name"].startswith("get_p9"))
+    assert tail["name"] == "get_p90_ms"
+    # in the cell whose tail the check read too wide for any bound, the
+    # same percentile is a per-layer reading and the median the end-to-end
+    # latency: every read cell's readers name the one all three report
+    assert "warm1.read-degraded" not in tail["workloads"]
+    p90 = layers.load_reader("client.get_p90_ms")
+    assert p90.read({"client": {"gets": gets}}) == pytest.approx(180.0)
+    assert p90.read({"client": {"gets": gets[:90]}}) is None  # 9 beyond
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == "client.get_p90_ms")
+    assert entry["workloads"] == ["warm1.read-degraded"]
+    assert reader.MOVES == p90.MOVES == entry["moves"] == "get_p50_ms"
 
 
 def test_the_recovering_readers_median_is_read_apart_from_the_healthy():
@@ -266,6 +339,79 @@ def test_spread_is_the_quartile_distance_over_the_median():
     values = [100, 101, 102, 103, 104, 105]
     q = __import__("statistics").quantiles(values, n=4)
     assert stats.iqr_share(values) == pytest.approx((q[2] - q[0]) / 102.5)
+
+
+# -- the builder's tools: spreads, the null check, the knee -----------------------
+def result_line(value, p50=None, name="seal_rate"):
+    line = {"correct": True, "metrics": {name: {"value": value, "unit": "MB/s"}}}
+    if p50 is not None:
+        line["readings"] = {"client.seal_rate_p50": p50}
+    return line
+
+
+def test_the_spread_leaves_out_the_farthest_run_where_that_narrows_it():
+    from benchmark.tools import spread
+
+    calm = [100, 101, 102, 103, 104, 105]
+    assert spread.trimmed_spread(calm) == pytest.approx(stats.iqr_share(calm[:5])
+                                                        * 102 / 102.5)
+    one_far = [100, 101, 102, 103, 104, 160]
+    q = __import__("statistics").quantiles(one_far[:5], n=4)
+    assert spread.trimmed_spread(one_far) == pytest.approx((q[2] - q[0]) / 102.5)
+    assert spread.trimmed_spread(one_far) < 0.5 * stats.iqr_share(one_far)
+    # the readings of a line are read like its metrics, on the same runs
+    runs = [result_line(650 - 9 * i, 700 + i) for i in range(6)]
+    assert spread.values_of(runs, "readings.client.seal_rate_p50") == [
+        700 + i for i in range(6)]
+    assert spread.values_of(runs, "seal_rate")[-1] == 605
+    assert spread.values_of(runs, "readings.never") == []
+
+
+def test_two_sets_of_the_same_seeds_are_read_as_parent_against_change():
+    from benchmark.tools import spread
+
+    bounds = {"end_to_end": [
+        {"name": "seal_rate", "bound": 0.05},
+        {"name": "setup_s", "bound": 0.25}]}
+
+    def runs(values, setups):
+        return [dict(result_line(v), metrics={
+            "seal_rate": {"value": v}, "setup_s": {"value": s}})
+            for v, s in zip(values, setups)]
+
+    a = runs([700, 702, 704, 706, 708, 710], [60, 20, 21, 20, 21, 20])
+    b = runs([701, 703, 705, 707, 709, 640], [22, 21, 22, 21, 22, 21])
+    rate, setup = spread.null_check([a, b], bounds)
+    assert rate["median"] == [705, 704] and rate["resolved"] is True
+    assert rate["medians_apart"] == pytest.approx(1 / 705)
+    assert max(rate["spread"]) < 0.025  # the run at 640 is the one left out
+    # set-up: each side's first run left out, the median alone, worse only
+    assert setup["median"] == [20, 21] and setup["resolved"] is True
+    assert "spread" not in setup
+    far = runs([760, 762, 764, 766, 768, 770], [22] * 6)
+    assert spread.null_check([a, far], bounds)[0]["resolved"] is False
+
+
+def test_the_knee_is_the_highest_rate_with_no_growing_window_beneath_it():
+    from benchmark.tools import sweep
+
+    def row(rate, growth, drain=0.01):
+        return {"rate_offered": rate, "backlog_growth": growth, "drain_s": drain}
+
+    rows = [row(60, 0.9), row(90, 1.0), row(120, 1.1), row(150, 1.6),
+            row(180, 1.0), row(210, 1.2, drain=2.5)]
+    # a window that grew is believed, whatever the windows above it read
+    assert sweep.knee(rows) == (120, 150)
+    assert sweep.refine_rates(rows, 4) == [126.0, 132.0, 138.0, 144.0]
+    assert sweep.refine_rates(rows, 0) == []
+    # the second pass's windows count like the first's: 132 grew, 138 did not
+    second = rows + [row(126, 1.11), row(132, 1.96), row(138, 0.53), row(144, 0.94)]
+    assert sweep.knee(second) == (126, 132)
+    # a queue that takes seconds to drain grew, whatever its medians say
+    assert sweep.knee([row(60, 0.9), row(90, 1.0, drain=2.5)]) == (60, 90)
+    assert sweep.knee(rows[:3]) == (120, None)  # never reached: sweep higher
+    assert sweep.refine_rates(rows[:3], 4) == []
+    assert sweep.knee([row(60, 2.0), row(90, 3.0)]) == (None, 60)
 
 
 # -- the plain reference and the layout math ----------------------------------
@@ -376,6 +522,39 @@ def test_cell_names_a_traffic_file_a_generator_reads(cell):
     named = [m["name"] for m in BENCH["end_to_end"]
              if "workloads" not in m or cell["name"] in m["workloads"]]
     assert "setup_s" in named and len(named) >= 2
+
+
+@pytest.mark.parametrize("metric", BENCH["end_to_end"], ids=lambda m: m["name"])
+def test_end_to_end_metric_has_a_bound_and_cells_that_report_it(metric):
+    assert 0.01 <= metric["bound"] <= 0.25
+    assert metric["source"] in ("host_clock", "device_trace")
+    cells = [w["name"] for w in BENCH["workloads"]]
+    if metric["name"] == "setup_s":  # every cell's, so it lists none
+        assert "workloads" not in metric and metric["bound"] == 0.25
+        return
+    assert metric["workloads"] and set(metric["workloads"]) <= set(cells)
+    # the rates are the maintain cells', the latencies the read cells';
+    # the tail only where its runs hold half a bound (PERF.md section 2)
+    kind = "maintain" if metric["name"].endswith("_rate") else "read-"
+    want = [c for c in cells if "." + kind in c]
+    if metric["name"] == "get_p90_ms":
+        want.remove("warm1.read-degraded")
+    assert metric["workloads"] == want
+
+
+def test_the_medians_and_the_stalled_count_stand_beside_the_rates():
+    names = [m["name"] for m in BENCH["per_layer"]]
+    at = names.index("client.seal_rate_p50")
+    assert names[at:at + 3] == ["client.seal_rate_p50",
+                                "client.rebuild_rate_p50", "client.stalled_ops"]
+    for m in BENCH["per_layer"][at:at + 3]:
+        assert m["workloads"] == ["warm1.maintain", "mesh4.maintain"]
+        assert layers.load_reader(m["name"]).MOVES == m["moves"]
+    # a rate is over all of a window's operations: no reader repeats it
+    for name in ("client.seal_rate_total", "client.rebuild_rate_total"):
+        assert name not in names
+        with pytest.raises(FileNotFoundError):
+            layers.load_reader(name)
 
 
 def test_read_cells_share_one_rate_written_as_a_number():
