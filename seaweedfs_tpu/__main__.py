@@ -74,6 +74,25 @@ def cmd_master(args):
     _wait_forever()
 
 
+def _ec_geometry(text: str):
+    from .ec.constants import Geometry
+
+    try:
+        return Geometry.parse(text)
+    except ValueError as e:
+        raise argparse.ArgumentTypeError(str(e)) from None
+
+
+def _add_ec_geometry(parser) -> None:
+    parser.add_argument(
+        "-ec.geometry", dest="ec_geometry", type=_ec_geometry, default="10+4",
+        metavar="k+m",
+        help="the Reed-Solomon code of the volumes THIS server seals: k data "
+             "+ m parity shards, k+m <= 32 (default 10+4). A sealed volume "
+             "keeps its own (its .vif), whatever its holders seal at",
+    )
+
+
 def cmd_volume(args):
     from .server.volume_server import VolumeServer
 
@@ -94,6 +113,7 @@ def cmd_volume(args):
         max_volume_count=args.max,
         pulse_seconds=args.pulse,
         ec_backend=args.ec_backend or None,
+        ec_geometry=args.ec_geometry,
         needle_map_kind=args.index,
         jwt_signing_key=sec["jwt_signing_key"],
         jwt_read_key=sec["jwt_read_key"],
@@ -120,6 +140,7 @@ def cmd_server(args):
         master_url=ms.url,
         max_volume_count=args.max,
         ec_backend=args.ec_backend or None,
+        ec_geometry=args.ec_geometry,
     ).start()
     parts = [f"master {ms.url}", f"volume {vs.host}:{vs.port}"]
     if args.filer or args.s3 or args.webdav:
@@ -1266,6 +1287,7 @@ def main(argv=None):
                    choices=["memory", "dense", "sqlite", "sorted"],
                    help="needle map kind (weed volume -index memory|leveldb)")
     v.add_argument("-ec.backend", dest="ec_backend", default="", choices=["", "tpu", "cpu", "numpy", "mesh"])
+    _add_ec_geometry(v)
     v.add_argument("-ec.chip", dest="ec_chip", type=int, default=None,
                    help="the one chip of this host that is this server's "
                         "(0-based): several volume servers on a multi-chip "
@@ -1285,6 +1307,7 @@ def main(argv=None):
     s.add_argument("-dir", default="./data")
     s.add_argument("-max", type=int, default=7)
     s.add_argument("-ec.backend", dest="ec_backend", default="")
+    _add_ec_geometry(s)
     s.add_argument("-filer", action="store_true",
                    help="also run a filer (command/server.go -filer)")
     s.add_argument("-filer.port", dest="filer_port", type=int, default=8888)

@@ -130,7 +130,9 @@ class Master:
         # instant EC-shard deltas (master_grpc_server.go:83-98 incremental
         # branch): register/unregister only the changed shard bits
         for m in hb.get("new_ec_shards", []):
-            self.topo.register_ec_shards(m["id"], dn, m.get("ec_index_bits", 0))
+            self.topo.register_ec_shards(
+                m["id"], dn, m.get("ec_index_bits", 0), m.get("geometry", "")
+            )
         for m in hb.get("deleted_ec_shards", []):
             self.topo.unregister_ec_shards(
                 m["id"], dn, m.get("ec_index_bits", ~0)
@@ -263,6 +265,9 @@ class Master:
         by_shard = self.topo.lookup_ec_shards(vid)
         return {
             "volume_id": vid,
+            # the shell learns a volume's shard count here, never from a
+            # constant
+            "geometry": self.topo.lookup_ec_geometry(vid),
             "shard_id_locations": {
                 sid: [dn.url() for dn in nodes] for sid, nodes in by_shard.items()
             },

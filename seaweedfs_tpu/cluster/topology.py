@@ -13,6 +13,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from ..ec.constants import DEFAULT_GEOMETRY
 from ..storage.replica_placement import ReplicaPlacement
 from ..storage.ttl import TTL
 from ..util.locks import make_rlock
@@ -226,6 +227,9 @@ class Topology(Node):
         self.layouts: dict[tuple[str, str, str], "VolumeLayout"] = {}
         # vid → set of DataNode holding EC shards: vid → {shard_id → [nodes]}
         self.ec_shard_locations: dict[int, dict[int, list[DataNode]]] = {}
+        # vid -> the "k+m" its holders report (their .vif); a volume whose
+        # holders name none is RS(10,4)
+        self.ec_geometry: dict[int, str] = {}
         self.max_volume_id = 0
 
     # -- tree building -------------------------------------------------------
@@ -362,6 +366,8 @@ class Topology(Node):
             for s in shards:  # OR-merge: one entry per disk location
                 vid = s["id"]
                 incoming[vid] = incoming.get(vid, 0) | s.get("ec_index_bits", 0)
+                if s.get("geometry"):
+                    self.ec_geometry[vid] = s["geometry"]
                 h = s.get("read_heat", 0.0)
                 if h > heat.get(vid, 0.0):
                     heat[vid] = h
@@ -402,9 +408,14 @@ class Topology(Node):
                 by_shard.pop(sid, None)
         if not by_shard:
             self.ec_shard_locations.pop(vid, None)
+            self.ec_geometry.pop(vid, None)
 
-    def register_ec_shards(self, vid: int, dn: DataNode, bits: int) -> None:
+    def register_ec_shards(
+        self, vid: int, dn: DataNode, bits: int, geometry: str = ""
+    ) -> None:
         with self._lock:
+            if geometry:
+                self.ec_geometry[vid] = geometry
             self._set_ec_shards(vid, dn, dn.ec_shards.get(vid, 0) | bits)
             dn.ec_shards[vid] = dn.ec_shards.get(vid, 0) | bits
 
@@ -416,6 +427,11 @@ class Topology(Node):
                 dn.ec_shards[vid] = remaining
             else:
                 dn.ec_shards.pop(vid, None)
+
+    def lookup_ec_geometry(self, vid: int) -> str:
+        """``k+m`` of an EC volume as its holders report it."""
+        with self._lock:
+            return self.ec_geometry.get(vid) or str(DEFAULT_GEOMETRY)
 
     def lookup_ec_shards(self, vid: int) -> dict[int, list[DataNode]]:
         with self._lock:

@@ -1,4 +1,5 @@
-"""Erasure coding: RS(10,4) over GF(2^8), TPU-native.
+"""Erasure coding: Reed-Solomon over GF(2^8) — RS(10,4) by default, the
+geometry a volume was sealed at otherwise (`constants.Geometry`) — TPU-native.
 
 The reference erasure-codes sealed volumes with klauspost/reedsolomon
 (`weed/storage/erasure_coding/ec_encoder.go`). Here the same code — identical

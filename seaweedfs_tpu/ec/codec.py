@@ -10,7 +10,10 @@ regardless of where they were computed. Mirrors the reference's use of
 
 `Codec` is the whole interface the file-level encoder (ec/encoder.py)
 uses: the shard counts and matrices, ``chunk_bytes``, ``alignment()``,
-``device_put``, ``matmul_device`` and ``device_memory_free()``. A host
+``device_put``, ``matmul_device`` and ``device_memory_free()``. A process
+builds ONE codec (`get_codec`); a volume sealed at another geometry is
+served by that codec's view at it (`Codec.at`): the same devices, caches and
+launch counts under another matrix. A host
 codec (`NumpyCodec`, `CpuCodec`) implements ``matmul`` and inherits the
 rest: its "device" is the host's memory. The JAX codecs (`TpuCodec` here,
 `MeshCodec` in ec/sharded.py) share `JaxCodec` and express the GF(2^8)
@@ -23,6 +26,7 @@ gf.gf_matrix_to_bit_matrix.
 
 from __future__ import annotations
 
+import copy
 import functools
 import os
 from typing import Optional, Sequence
@@ -33,7 +37,7 @@ from ..stats import trace
 from ..util import jaxenv
 from ..util.locks import make_lock
 from . import gf
-from .constants import DATA_SHARDS, PARITY_SHARDS
+from .constants import DATA_SHARDS, PARITY_SHARDS, Geometry
 
 
 class Codec:
@@ -46,11 +50,39 @@ class Codec:
     chunk_bytes = 8 * 1024 * 1024
 
     def __init__(self, data_shards: int = DATA_SHARDS, parity_shards: int = PARITY_SHARDS):
-        self.data_shards = data_shards
-        self.parity_shards = parity_shards
-        self.total_shards = data_shards + parity_shards
+        self._set_geometry(data_shards, parity_shards)
+        # this codec at every geometry asked of it (`at`), itself among them
+        self._views: dict[Geometry, Codec] = {self.geometry: self}
+        self._views_lock = make_lock("Codec._views_lock")
+
+    def _set_geometry(self, data_shards: int, parity_shards: int) -> None:
+        """All a codec holds that is tied to a geometry: a few hundred
+        bytes of host-side bookkeeping."""
+        self.geometry = Geometry(data_shards, parity_shards)
         self.matrix = gf.build_matrix(data_shards, self.total_shards)
         self.parity_rows = self.matrix[data_shards:]
+
+    data_shards = property(lambda self: self.geometry.data_shards)
+    parity_shards = property(lambda self: self.geometry.parity_shards)
+    total_shards = property(lambda self: self.geometry.total_shards)
+
+    def at(self, data_shards: int, parity_shards: int) -> "Codec":
+        """This codec at another geometry: a view that owns the matrix and
+        the shard counts and SHARES everything a process has one of — the
+        devices, the jit and bit-matrix caches, the launch counts, the
+        kernel's prep tables. Built once a geometry and kept, so a server
+        that seals at 12+4 reads and rebuilds the 10+4 volumes it holds
+        through one chip and one ``ec_codec`` of /status."""
+        geometry = Geometry(data_shards, parity_shards).checked()
+        with self._views_lock:
+            view = self._views.get(geometry)
+            if view is None:
+                # shallow: every attribute but the geometry's is the same
+                # object in the view as here
+                view = copy.copy(self)
+                view._set_geometry(*geometry)
+                self._views[geometry] = view
+        return view
 
     # -- backend hooks -------------------------------------------------------
     def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
@@ -240,21 +272,30 @@ class CpuCodec(Codec):
 
 
 class LaunchCounter:
-    """Device launches by kernel name. Launches come from the encode
-    pipeline's dispatch thread and from request threads doing degraded
-    reads at once, so the count is kept under a lock."""
+    """Device launches by kernel name and by the geometry of the codec
+    view that launched. Launches come from the encode pipeline's dispatch
+    thread and from request threads doing degraded reads at once, so the
+    counts are kept under a lock."""
 
     def __init__(self):
         self._lock = make_lock("LaunchCounter._lock")
         self._n = {"pallas": 0, "xla": 0}
+        self._by_geometry: dict[Geometry, int] = {}
 
-    def add(self, kernel: str) -> None:
+    def add(self, kernel: str, geometry: Geometry) -> None:
         with self._lock:
             self._n[kernel] += 1
+            self._by_geometry[geometry] = self._by_geometry.get(geometry, 0) + 1
 
     def snapshot(self) -> dict:
         with self._lock:
             return dict(self._n)
+
+    def by_geometry(self) -> dict:
+        """``{"10+4": n, "12+4": n}``: each geometry that has launched;
+        the counts add up to `snapshot`'s."""
+        with self._lock:
+            return {str(g): n for g, n in self._by_geometry.items()}
 
 
 def build_pallas_gf_matmul(jax, n_out_rows: int, k: int, n_cols: int,
@@ -395,6 +436,7 @@ class JaxCodec(Codec):
             "kernel": self.kernel,
             "pallas_tile": self.pallas_tile,
             "launches": self.launches.snapshot(),
+            "geometries": self.launches.by_geometry(),
             "devices": [
                 {
                     "id": d.id,
@@ -597,10 +639,10 @@ class TpuCodec(JaxCodec):
                     f"{self.pallas_tile}: pad to alignment() first"
                 )
             fn = self._pallas_fused(matrix.shape[0], matrix.shape[1], n)
-            self.launches.add("pallas")
+            self.launches.add("pallas", self.geometry)
             return fn(self._bitmat(matrix, planewise=True), data_dev)
         kernel = self._kernel(*matrix.shape)
-        self.launches.add("xla")
+        self.launches.add("xla", self.geometry)
         return kernel(self._bitmat(matrix), data_dev)
 
 

@@ -8,7 +8,8 @@ and the .dat size is recovered from the highest .ecx entry end. Backing
 VolumeEcShardsToVolume rpc.
 
 Missing data shards are first regenerated from parity through the codec
-(`encoder.rebuild_ec_files`), so any ≥10 present shards decode.
+(`encoder.rebuild_ec_files`), so any k present shards decode. The geometry
+is the volume's own (its .vif, `encoder.volume_geometry`).
 """
 
 from __future__ import annotations
@@ -28,8 +29,9 @@ from ..storage.types import (
     needle_map_entry_size,
     size_is_valid,
 )
-from .constants import DATA_SHARDS, LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, shard_ext
-from .encoder import rebuild_ec_files
+from .codec import get_codec
+from .constants import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, shard_ext
+from .encoder import rebuild_ec_files, volume_geometry
 
 _COPY_CHUNK = 8 * 1024 * 1024
 
@@ -100,14 +102,15 @@ def write_idx_file_from_ec_index(
 def write_dat_file(
     base_file_name: str,
     dat_size: int,
+    data_shards: int,
     large_block_size: int = LARGE_BLOCK_SIZE,
     small_block_size: int = SMALL_BLOCK_SIZE,
 ) -> None:
-    """Re-interleave the 10 data shards into the original .dat
-    (WriteDatFile, ec_decoder.go:153): full 1GB rows round-robin, then
-    1MB small-block rows for the tail."""
+    """Re-interleave the volume's ``data_shards`` data shards into the
+    original .dat (WriteDatFile, ec_decoder.go:153): full 1GB rows
+    round-robin, then 1MB small-block rows for the tail."""
     inputs = [
-        open(base_file_name + shard_ext(s), "rb") for s in range(DATA_SHARDS)
+        open(base_file_name + shard_ext(s), "rb") for s in range(data_shards)
     ]
     try:
         with open(base_file_name + ".dat", "wb") as dat:
@@ -154,7 +157,7 @@ def write_dat_file(
             # DECODER (WriteDatFile, ec_decoder.go:172) uses >= — a real
             # boundary bug that silently corrupts exact-multiple volumes;
             # verified empirically with scaled block sizes, so we diverge.
-            while remaining > DATA_SHARDS * large_block_size:
+            while remaining > data_shards * large_block_size:
                 for src in inputs:
                     copy_n(src, large_block_size)
                     remaining -= large_block_size
@@ -174,17 +177,20 @@ def write_dat_file(
 def decode_to_volume(
     base_file_name: str, offset_size: int = OFFSET_SIZE, codec=None
 ) -> int:
-    """Shards → .dat + .idx; regenerates missing data shards first (with
-    the caller's codec — a cpu-configured server must not fall back to the
-    tpu default). Returns the reconstructed .dat size."""
+    """Shards → .dat + .idx at the geometry the volume's .vif records;
+    regenerates missing data shards first (with the caller's codec — a
+    cpu-configured server must not fall back to the tpu default). Returns
+    the reconstructed .dat size."""
+    geometry = volume_geometry(base_file_name)
     missing_data = [
         s
-        for s in range(DATA_SHARDS)
+        for s in range(geometry.data_shards)
         if not os.path.exists(base_file_name + shard_ext(s))
     ]
     if missing_data:
+        codec = (codec or get_codec()).at(*geometry)
         rebuild_ec_files(base_file_name, codec)
     dat_size = find_dat_file_size(base_file_name, offset_size)
-    write_dat_file(base_file_name, dat_size)
+    write_dat_file(base_file_name, dat_size, geometry.data_shards)
     write_idx_file_from_ec_index(base_file_name, offset_size)
     return dat_size

@@ -3,8 +3,9 @@
 Mirrors `weed/storage/erasure_coding/ec_volume.go`, `ec_shard.go`,
 `ec_volume_delete.go`:
 
-- an EC volume is the set of locally-present shard files (.ec00‥.ec13) plus
-  the .ecx sorted index (binary-searched per lookup) and the .ecj deletion
+- an EC volume is the set of locally-present shard files (.ec00‥.ec13 at
+  RS(10,4); as many as the geometry its .vif records has shards) plus the
+  .ecx sorted index (binary-searched per lookup) and the .ecj deletion
   journal;
 - a needle read locates (offset, size) in .ecx, maps the byte range to
   shard intervals (dat size = k × shard size), and reads whichever shards
@@ -34,10 +35,9 @@ from ..storage.types import (
     size_is_valid,
 )
 from .constants import (
-    DATA_SHARDS,
     LARGE_BLOCK_SIZE,
+    MAX_TOTAL_SHARDS,
     SMALL_BLOCK_SIZE,
-    TOTAL_SHARDS,
     shard_ext,
 )
 from .locate import Interval, locate_data
@@ -68,8 +68,9 @@ class DeletedError(Exception):
 
 class EcShardsError(Exception):
     """The local shard set is not safe to serve: shard sizes disagree (a
-    torn write survived) or an encode commit is still pending for this
-    volume. Mounting anyway would serve a half-consistent stripe view."""
+    torn write survived), an encode commit is still pending for this
+    volume, or its .vif names another code than its shard files show.
+    Mounting anyway would serve a half-consistent stripe view."""
 
 
 def search_sorted_index(
@@ -138,18 +139,21 @@ class EcVolume:
         vid: int,
         version: int = 3,
         offset_size: int = OFFSET_SIZE,
-        data_shards: int = DATA_SHARDS,
-        total_shards: int = TOTAL_SHARDS,
     ):
         from ..storage.volume import volume_file_name
+        from .encoder import volume_geometry
 
         self.collection = collection
         self.id = vid
         self.version = version
         self.offset_size = offset_size
-        self.data_shards = data_shards
-        self.total_shards = total_shards
         self.base_file_name = volume_file_name(directory, collection, vid)
+        # the code the volume was sealed at: its .vif says (it came with
+        # the shards), and one that does not is RS(10,4)
+        try:
+            self.geometry = volume_geometry(self.base_file_name)
+        except ValueError as e:
+            raise EcShardsError(f"volume {vid}: {e}") from e
         self.shards: dict[int, EcVolumeShard] = {}
         self._ecx_lock = threading.Lock()
         self._ecj_lock = threading.Lock()
@@ -179,7 +183,21 @@ class EcVolume:
             self._ecx.close()
             raise
 
+    data_shards = property(lambda self: self.geometry.data_shards)
+    total_shards = property(lambda self: self.geometry.total_shards)
+
     def _load_shards(self) -> None:
+        # shards that came without their .vif (a hand-made copy) would be
+        # located with the default's k: intervals of another volume's shape
+        beyond = [
+            sid for sid in range(self.total_shards, MAX_TOTAL_SHARDS)
+            if os.path.exists(self.base_file_name + shard_ext(sid))
+        ]
+        if beyond:
+            raise EcShardsError(
+                f"volume {self.id} has shard files {beyond} beyond its "
+                f"geometry {self.geometry}: the .vif must come with the shards"
+            )
         for sid in range(self.total_shards):
             path = self.base_file_name + shard_ext(sid)
             if os.path.exists(path) and sid not in self.shards:

@@ -38,6 +38,7 @@ from .codec import Codec, get_codec
 from .constants import (
     LARGE_BLOCK_SIZE,
     SMALL_BLOCK_SIZE,
+    Geometry,
     shard_ext,
 )
 
@@ -372,8 +373,9 @@ class _HashedShards:
     (`save_volume_info`), taken while a chunk's rows are in memory instead
     of by reading the staged files back.
 
-    The rows of a chunk are independent — fourteen files, fourteen digests
-    — and ``write`` and ``update`` both release the interpreter lock, so a
+    The rows of a chunk are independent — a file and a digest a shard,
+    fourteen at RS(10,4), sixteen at RS(12,4) — and ``write`` and
+    ``update`` both release the interpreter lock, so a
     pool that lives as long as the call takes them side by side, a row a
     task; the caller's thread waits for all of a chunk's rows, so a file
     and its digest see the chunks in order, and a recycled buffer is free
@@ -478,8 +480,8 @@ def write_ec_files(
     them (``codec.device_put``: into HBM, or nowhere for a host codec) and
     dispatches the encode (``codec.matmul_device``: an async kernel launch,
     or the host's matmul then and there), a fetch thread blocks on each
-    chunk's parity (the D2H leg), and a writer thread appends the 14 shard
-    files and feeds their digests, the fourteen rows of a chunk side by
+    chunk's parity (the D2H leg), and a writer thread appends the k+m shard
+    files and feeds their digests, the rows of a chunk side by
     side. Disk read, H2D copy, compute, D2H and file writes for
     neighbouring chunks overlap — the reference's
     serial 256KB read→Encode→write loop (`ec_encoder.go:162-192`) turned into
@@ -989,16 +991,23 @@ def save_volume_info(
     version: int = 3,
     replication: str = "",
     shard_sums: "list[str] | None" = None,
+    geometry: "Geometry | None" = None,
 ) -> None:
     """jsonpb-style VolumeInfo (pb/volume_info.go:56 SaveVolumeInfo).
 
     ``shard_sums`` (sha256 hex per shard id, written at encode time) gives
     the background scrub a ground truth for shard integrity: RS encoding is
     deterministic, so a rebuilt shard hashes identically and the sums stay
-    valid across rebuilds and copies (the .vif travels with the shards)."""
+    valid across rebuilds and copies (the .vif travels with the shards).
+    ``geometry`` (``data_shards`` / ``parity_shards``) is the code the
+    volume was sealed at: with the shards it travels to every holder, and
+    every later read, rebuild, copy and decode takes it from here
+    (`volume_geometry`)."""
     info = {"files": [], "version": version, "replication": replication}
     if shard_sums is not None:
         info["shard_sums"] = shard_sums
+    if geometry is not None:
+        info["data_shards"], info["parity_shards"] = geometry
     with open(file_name, "w") as f:
         f.write(json.dumps(info, indent=2))
 
@@ -1008,6 +1017,12 @@ def load_volume_info(file_name: str) -> dict:
         return {"files": [], "version": 0, "replication": ""}
     with open(file_name) as f:
         return json.load(f)
+
+
+def volume_geometry(base_file_name: str) -> Geometry:
+    """The geometry of the EC volume at ``base_file_name``, as its .vif
+    records it; RS(10,4) where the .vif names none or is not there."""
+    return Geometry.of_volume_info(load_volume_info(base_file_name + ".vif"))
 
 
 def ec_shard_base_size(

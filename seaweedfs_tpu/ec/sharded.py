@@ -194,12 +194,13 @@ class MeshCodec(JaxCodec):
         self._col_axes = ("dp", "sp")
         self._n_cols_shards = mesh.shape["dp"] * mesh.shape["sp"]
         # the newest result, kept so that /status can show which devices
-        # hold its pieces: whether a launch really spread over the mesh
-        self._last_out = None
+        # hold its pieces: whether a launch really spread over the mesh.
+        # One slot, the same list in every geometry's view (Codec.at)
+        self._last_out = [None]
 
     def describe(self) -> dict:
         out = super().describe()
-        last = self._last_out
+        last = self._last_out[0]
         out["last_output_devices"] = sorted(
             {s.device.id for s in last.addressable_shards}
         ) if last is not None else []
@@ -294,6 +295,6 @@ class MeshCodec(JaxCodec):
     def matmul_device(self, matrix: np.ndarray, data_dev):
         """(R×k) @ (k×N) on mesh-resident data; N % alignment() == 0."""
         out = self._spmd_fn(*matrix.shape)(self._stacked_bitmat(matrix), data_dev)
-        self.launches.add("pallas" if self.use_pallas else "xla")
-        self._last_out = out
+        self.launches.add("pallas" if self.use_pallas else "xla", self.geometry)
+        self._last_out[0] = out
         return out

@@ -30,7 +30,7 @@ import time
 from typing import Optional
 
 from ..ec import encoder
-from ..ec.constants import TOTAL_SHARDS, shard_ext
+from ..ec.constants import DEFAULT_GEOMETRY, Geometry, shard_ext
 from ..ec.ec_volume import EcVolume
 from ..stats import trace
 from ..storage.file_id import parse_needle_id_cookie
@@ -83,6 +83,7 @@ class VolumeServer:
         max_volume_count: int = 7,
         pulse_seconds: float = 5.0,
         ec_backend: Optional[str] = None,
+        ec_geometry: Geometry = DEFAULT_GEOMETRY,
         needle_map_kind: str = "dense",
         jwt_signing_key: str = "",
         jwt_read_key: str = "",
@@ -116,6 +117,7 @@ class VolumeServer:
             port=port,
             public_url=public_url or f"{host}:{port}",
             ec_backend=ec_backend,
+            ec_geometry=ec_geometry,
             needle_map_kind=needle_map_kind,
         )
         self.store.remote_shards = RemoteShards(
@@ -942,9 +944,10 @@ class VolumeServer:
 
     def _h_ec_generate(self, h, path, q, body):
         """VolumeEcShardsGenerate (volume_grpc_erasure_coding.go:39): mark
-        readonly, stripe to 14 shards with the TPU/CPU codec, write
-        .ecx/.vif — staged and committed atomically so a crash mid-encode
-        can never leave a half-visible shard set (Store.ec_encode_volume)."""
+        readonly, stripe to the k+m shards of this server's -ec.geometry
+        (14 by default) with the TPU/CPU codec, write .ecx/.vif — staged and
+        committed atomically so a crash mid-encode can never leave a
+        half-visible shard set (Store.ec_encode_volume)."""
         vid = _q_req_uint(q, "volume")
         v = self.store.find_volume(vid)
         nbytes = v.size() if v is not None else 0
@@ -966,7 +969,10 @@ class VolumeServer:
         base = self._find_base(vid)
         if base is None:
             return 404, {"error": "ec volume not found"}
-        generated = encoder.rebuild_ec_files(base, self.store.ec_codec)
+        # at the volume's own geometry, whatever this server seals at
+        generated = encoder.rebuild_ec_files(
+            base, self.store.ec_codec.at(*encoder.volume_geometry(base))
+        )
         from ..ec.ec_volume import rebuild_ecx_file
 
         rebuild_ecx_file(base)
@@ -986,10 +992,12 @@ class VolumeServer:
         base = volume_file_name(loc.directory, collection, vid)
         copied = []
         exts = [shard_ext(s) for s in shard_ids]
-        if q.get("copy_ecx", "true") == "true":
-            exts += [".ecx"]
+        # the .vif before the .ecx: a directory scan finds an EC volume by
+        # its .ecx and reads the geometry off the .vif beside it
         if q.get("copy_vif", "true") == "true":
             exts += [".vif"]
+        if q.get("copy_ecx", "true") == "true":
+            exts += [".ecx"]
         from ..storage.commit import atomic_write
 
         # one pull of a spread (or a gather): every file fetched and staged
@@ -1145,7 +1153,7 @@ class VolumeServer:
         collection = ev.collection if ev else q.get("collection", "")
         for loc in self.store.locations:
             loc.unload_ec_volume(vid)
-        for s in range(TOTAL_SHARDS):
+        for s in range(encoder.volume_geometry(base).total_shards):
             try:
                 os.remove(base + shard_ext(s))
             except FileNotFoundError:
@@ -1175,7 +1183,7 @@ class VolumeServer:
         ev.refresh_shards()
         sids = ev.shard_ids()
         self.store.queue_new_ec_shards(
-            vid, ev.collection, sum(1 << s for s in sids)
+            vid, ev.collection, sum(1 << s for s in sids), ev.geometry
         )
         return 200, {"shards": sids}
 
@@ -1218,7 +1226,8 @@ class VolumeServer:
                 vid, collection, sum(1 << s for s in removed)
             )
         if base and not any(
-            os.path.exists(base + shard_ext(s)) for s in range(TOTAL_SHARDS)
+            os.path.exists(base + shard_ext(s))
+            for s in range(encoder.volume_geometry(base).total_shards)
         ):
             # last shard gone: the index + deletion journal go with it
             # (VolumeEcShardsDelete removes .ecx/.ecj when none remain)

@@ -16,7 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
-from ..ec.constants import TOTAL_SHARDS
+from ..ec.constants import DEFAULT_GEOMETRY, Geometry
 from ..server.http_util import http_json
 
 
@@ -93,8 +93,18 @@ class CommandEnv:
         return [l["url"] for l in r.get("locations", [])]
 
     def ec_shard_locations(self, vid: int) -> dict[int, list[str]]:
+        return self.ec_volume(vid)[1]
+
+    def ec_volume(self, vid: int) -> tuple[Geometry, dict[int, list[str]]]:
+        """One /dir/lookup_ec: the volume's geometry as its holders report
+        it (RS(10,4) from a master that names none) and who holds each
+        shard."""
         r = http_json("GET", f"http://{self.master}/dir/lookup_ec?volumeId={vid}")
-        return {
+        geometry = (
+            Geometry.parse(r["geometry"]) if r.get("geometry")
+            else DEFAULT_GEOMETRY
+        )
+        return geometry, {
             int(sid): urls
             for sid, urls in r.get("shard_id_locations", {}).items()
         }
@@ -254,9 +264,9 @@ def ec_encode(
     collection: Optional[str] = None,
     delete_original: bool = True,
 ) -> dict:
-    """command_ec_encode.go:92 doEcEncode: mark readonly → generate 14
-    shards on the source server → spread across servers → register → drop
-    the plain volume."""
+    """command_ec_encode.go:92 doEcEncode: mark readonly → generate the
+    shards on the source server (as many as ITS -ec.geometry has: 14 by
+    default) → spread across servers → register → drop the plain volume."""
     locations = env.volume_locations(vid)
     if not locations:
         raise RuntimeError(f"volume {vid} not found")
@@ -272,7 +282,7 @@ def ec_encode(
     if r.get("error"):
         raise RuntimeError(f"generate: {r['error']}")
     return _spread_and_finish(env, vid, collection, source, locations,
-                              delete_original)
+                              delete_original, r["shards"])
 
 
 def _spread_and_finish(
@@ -282,10 +292,11 @@ def _spread_and_finish(
     source: str,
     locations: list[str],
     delete_original: bool,
+    generated: list[int],
 ) -> dict:
-    """Post-generate half of doEcEncode: spread the 14 shards round-robin,
-    mount everywhere, drop the plain volume."""
-    plan = _spread_plan(env, source)
+    """Post-generate half of doEcEncode: spread the shards the source
+    ``generated`` round-robin, mount everywhere, drop the plain volume."""
+    plan = _spread_plan(env, source, generated)
     for target, shard_ids in plan.items():
         if target == source or not shard_ids:
             continue
@@ -365,21 +376,24 @@ def ec_encode_fleet(
         source = jobs[vid].get("server") or locations[vid][0]
         out["volumes"].append(
             _spread_and_finish(env, vid, collections[vid], source,
-                               locations[vid], delete_original)
+                               locations[vid], delete_original,
+                               jobs[vid]["shards"])
         )
     return out
 
 
-def _spread_plan(env: CommandEnv, source: str) -> dict[str, list[int]]:
+def _spread_plan(
+    env: CommandEnv, source: str, shard_ids: list[int]
+) -> dict[str, list[int]]:
     """Round-robin balanced distribution (balancedEcDistribution,
-    command_ec_encode.go:209): spread 14 shards across all servers, source
-    keeps its share."""
+    command_ec_encode.go:209): spread the volume's shards (14 at RS(10,4),
+    16 at RS(12,4)) across all servers, source keeps its share."""
     nodes = sorted(n["url"] for n in env.data_nodes())
     if source in nodes:  # source first so it keeps the remainder share
         nodes.remove(source)
         nodes.insert(0, source)
     plan: dict[str, list[int]] = {n: [] for n in nodes}
-    for sid in range(TOTAL_SHARDS):
+    for sid in sorted(shard_ids):
         plan[nodes[sid % len(nodes)]].append(sid)
     return plan
 
@@ -388,12 +402,12 @@ def ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     """command_ec_rebuild.go:57: find missing shards, pick the node with the
     most free room as rebuilder, copy enough sibling shards there, rebuild,
     mount, then drop the copied-in temporaries."""
-    by_shard = env.ec_shard_locations(vid)
+    geometry, by_shard = env.ec_volume(vid)
     present = set(by_shard)
-    missing = sorted(set(range(TOTAL_SHARDS)) - present)
+    missing = sorted(set(range(geometry.total_shards)) - present)
     if not missing:
         return {"volume": vid, "rebuilt": []}
-    if len(present) < 10:
+    if len(present) < geometry.data_shards:
         raise RuntimeError(
             f"volume {vid}: only {len(present)} shards survive, cannot rebuild"
         )
@@ -409,7 +423,7 @@ def ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     needed = [sid for sid in sorted(present - local)]
     copied_in = []
     for sid in needed:
-        if len(local) + len(copied_in) >= 10:
+        if len(local) + len(copied_in) >= geometry.data_shards:
             break
         src = by_shard[sid][0]
         r = http_json(
@@ -921,7 +935,8 @@ def volume_server_evacuate(
     for s in st.get("ec", []):
         vid = s["id"]
         sids = [
-            i for i in range(TOTAL_SHARDS) if s["ec_index_bits"] & (1 << i)
+            i for i in range(s["ec_index_bits"].bit_length())
+            if s["ec_index_bits"] & (1 << i)
         ]
         target = min(counts, key=counts.get)
         counts[target] += 1  # spread successive shard groups across nodes

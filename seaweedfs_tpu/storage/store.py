@@ -21,7 +21,13 @@ from typing import Callable, NamedTuple, Optional
 import numpy as np
 
 from ..ec.codec import Codec, get_codec
-from ..ec.constants import LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE, TOTAL_SHARDS, shard_ext
+from ..ec.constants import (
+    DEFAULT_GEOMETRY,
+    LARGE_BLOCK_SIZE,
+    SMALL_BLOCK_SIZE,
+    Geometry,
+    shard_ext,
+)
 from ..ec.ec_volume import EcVolume, NeedsShardError, ShardLocator
 from ..ec.ec_volume import NotFoundError as EcNotFoundError
 from ..stats import heat, trace
@@ -55,6 +61,7 @@ class Store:
         port: int = 8080,
         public_url: str = "",
         ec_backend: Optional[str] = None,
+        ec_geometry: Geometry = DEFAULT_GEOMETRY,
         needle_map_kind: str = "dense",
         remote_fetch_attempts: int = 3,
         remote_fetch_backoff_s: float = 0.05,
@@ -79,6 +86,9 @@ class Store:
         self._ec_codec: Optional[Codec] = None
         self._codec_lock = make_lock("Store._codec_lock")
         self._ec_backend = ec_backend
+        # the code THIS server seals at (-ec.geometry); a volume it holds
+        # is read, rebuilt and decoded at the volume's own (its .vif)
+        self.ec_geometry = ec_geometry
         self.remote_shards: Optional[RemoteShards] = None
         # native turbo data plane (native/turbo.py); set by the volume
         # server when it owns the public port through the engine
@@ -270,10 +280,13 @@ class Store:
             self.new_volumes.append(self._volume_message(v))
         self.delta_event.set()
 
-    def queue_new_ec_shards(self, vid: int, collection: str, bits: int) -> None:
+    def queue_new_ec_shards(
+        self, vid: int, collection: str, bits: int, geometry: Geometry
+    ) -> None:
         with self._lock:
             self.new_ec_shards.append(
-                {"id": vid, "collection": collection, "ec_index_bits": bits}
+                {"id": vid, "collection": collection, "ec_index_bits": bits,
+                 "geometry": str(geometry)}
             )
         self.delta_event.set()
 
@@ -367,8 +380,10 @@ class Store:
 
     # -- EC encode: crash-safe two-phase commit ------------------------------
     def ec_encode_volume(self, vid: int) -> list[int]:
-        """Stripe a sealed volume into 14 shards + .ecx + .vif with an
-        all-or-nothing commit (VolumeEcShardsGenerate, hardened).
+        """Stripe a sealed volume into the k+m shards of this server's
+        geometry (14 at RS(10,4), 16 at RS(12,4)) + .ecx + .vif, which
+        records the geometry, with an all-or-nothing commit
+        (VolumeEcShardsGenerate, hardened).
 
         Every output is written to a ``.tmp`` staging name; files are
         fsync'd, a commit manifest is written atomically, and only then do
@@ -388,8 +403,9 @@ class Store:
             trace.add_stage_bytes(os.path.getsize(base + ".dat"))
             from ..ec import encoder
 
+            codec = self.ec_codec.at(*self.ec_geometry)
             sc = StagedCommit(base, "ec.encode")
-            for sid in range(TOTAL_SHARDS):
+            for sid in range(codec.total_shards):
                 sc.stage(base + shard_ext(sid))
             sc.stage(base + ".ecx")
             vif_tmp = sc.stage(base + ".vif")
@@ -397,9 +413,7 @@ class Store:
                 # per-shard sha256 for the .vif: the scrub thread's
                 # integrity ground truth (RS is deterministic — rebuilds
                 # hash identically), taken of each shard as it is written
-                sums = encoder.write_ec_files(
-                    base, self.ec_codec, suffix=".tmp"
-                )
+                sums = encoder.write_ec_files(base, codec, suffix=".tmp")
                 with trace.stage_span("ec.seal.ecx", quiet=True):
                     encoder.write_sorted_file_from_idx(base, ext=".ecx.tmp")
                 # fsync, manifest, renames: the guarantee itself
@@ -409,12 +423,13 @@ class Store:
                         version=v.version,
                         replication=str(v.super_block.replica_placement),
                         shard_sums=sums,
+                        geometry=codec.geometry,
                     )
                     sc.commit()
             except BaseException:
                 sc.abort()
                 raise
-            return list(range(TOTAL_SHARDS))
+            return list(range(codec.total_shards))
 
     # -- scrub findings (consumed by cluster/lifecycle.py via heartbeats) ----
     def report_corrupt_needle(self, vid: int, nid: int) -> None:
@@ -613,7 +628,7 @@ class Store:
         table is still the one in hand when fewer than k siblings were
         reached, it is taken anew once and the siblings it called "nowhere"
         are asked for again: no read fails on an old answer."""
-        codec = self.ec_codec
+        codec = self.ec_codec.at(*ev.geometry)
         # quiet, as the decode below: a slow recovery is named by the leaf
         # stage that was slow (an ask, the local reads, the launch)
         with trace.stage_span(
@@ -729,6 +744,7 @@ class Store:
                         "id": ev.id,
                         "collection": ev.collection,
                         "ec_index_bits": sum(1 << sid for sid in ev.shard_ids()),
+                        "geometry": str(ev.geometry),
                         "read_heat": round(h.value(), 3) if h else 0.0,
                         "corrupt_shards": sorted(
                             self.corrupt_shards.get(ev.id, ())

@@ -61,12 +61,14 @@ def build(vid=1):
         v.write_needle(Needle(cookie=7, id=i, data=payload(i)))
     return v
 
-if op == "encode":
+if op.startswith("encode"):  # "encode", or "encode-12+4": at that geometry
     v = build()
     v.sync()
     v.close()
+    from seaweedfs_tpu.ec.constants import Geometry
     from seaweedfs_tpu.storage.store import RemoteShards, Store
-    store = Store([workdir], ec_backend="numpy")
+    geometry = Geometry.parse(op.partition("-")[2] or "10+4")
+    store = Store([workdir], ec_backend="numpy", ec_geometry=geometry)
     store.ec_encode_volume(1)
     store.close()
 elif op == "vacuum":
@@ -143,9 +145,10 @@ def assert_no_staging_litter(tmp_path):
     assert not litter, f"staging files survived recovery: {litter}"
 
 
-def assert_encode_invariant(tmp_path):
+def assert_encode_invariant(tmp_path, total=TOTAL_SHARDS):
     """Fully plain-readable always (encode never touches the .dat), and the
-    EC side is all-or-nothing: 14 shards + .ecx + .vif readable, or none."""
+    EC side is all-or-nothing: all ``total`` shards (14; 16 at RS(12,4))
+    + .ecx + .vif readable, or none."""
     loc = reload_location(tmp_path)
     try:
         assert_no_staging_litter(tmp_path)
@@ -158,9 +161,11 @@ def assert_encode_invariant(tmp_path):
         base = v.file_name()
         shards = [f for f in os.listdir(tmp_path) if re.match(r"1\.ec\d\d$", f)]
         if os.path.exists(base + ".ecx"):
-            assert len(shards) == TOTAL_SHARDS, f"torn shard set: {sorted(shards)}"
+            assert len(shards) == total, f"torn shard set: {sorted(shards)}"
             assert os.path.exists(base + ".vif")
             assert 1 in loc.ec_volumes, "complete shard set failed to mount"
+            # the geometry came back from the .vif, on a default server
+            assert loc.ec_volumes[1].total_shards == total
         else:
             assert shards == [], f"shards with no index: {sorted(shards)}"
     finally:
@@ -256,6 +261,7 @@ def assert_tier_invariant(tmp_path):
 
 INVARIANTS = {
     "encode": assert_encode_invariant,
+    "encode-12+4": lambda tmp_path: assert_encode_invariant(tmp_path, total=16),
     "vacuum": assert_vacuum_invariant,
     "tier": assert_tier_invariant,
 }
@@ -295,16 +301,19 @@ FAST_MATRIX = [
     ("vacuum", "vacuum.rename=crash"),
     ("tier", "tier.upload.committed=crash"),
     ("tier", "tier.download.manifest=crash"),
+    # sixteen shards: between their fsyncs and the manifest, and after it
+    ("encode-12+4", "ec.encode.staged=crash"),
+    ("encode-12+4", "ec.encode.manifest=crash"),
 ]
 
 
-@pytest.mark.parametrize("op", ["encode", "vacuum", "tier"])
+@pytest.mark.parametrize("op", ["encode", "vacuum", "tier", "encode-12+4"])
 def test_child_completes_without_faults(tmp_path, op):
     """Harness sanity: with nothing armed each transition runs to the end —
     so a matrix pass means the faults fired, not that the op never ran."""
     run_child(tmp_path, op, expect_crash=False)
     INVARIANTS[op](tmp_path)
-    if op == "encode":
+    if op.startswith("encode"):
         assert os.path.exists(tmp_path / "1.ecx")
     if op == "vacuum":
         loc = reload_location(tmp_path)

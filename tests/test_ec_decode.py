@@ -144,7 +144,8 @@ def test_decode_exact_multiple_boundary(tmp_path):
             chunk_bytes=small,
         )
         ec_decoder.write_dat_file(
-            base, dat_size, large_block_size=large, small_block_size=small
+            base, dat_size, DATA_SHARDS, large_block_size=large,
+            small_block_size=small,
         )
         got = open(base + ".dat", "rb").read()
         assert got == payload, f"round-trip broke at dat_size={dat_size}"

@@ -71,7 +71,7 @@ def test_spread_plan_is_the_plain_references(n, source, monkeypatch):
     env = commands.CommandEnv(master="unused:1")
     monkeypatch.setattr(commands.CommandEnv, "data_nodes",
                         lambda self: [{"url": u} for u in reversed(urls)])
-    got = commands._spread_plan(env, urls[source])
+    got = commands._spread_plan(env, urls[source], range(TOTAL_SHARDS))
     want = reference_spread.spread_plan(urls, urls[source], TOTAL_SHARDS)
     assert got == want
     assert want[urls[source]][0] == 0  # the source keeps shard 0

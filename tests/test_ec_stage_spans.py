@@ -242,7 +242,12 @@ def test_a_degraded_get_is_one_tree_down_to_the_launch(sealed):
 def test_the_kill_switch_records_nothing_and_status_has_no_stages(
         sealed, monkeypatch):
     monkeypatch.setenv("SWEED_TRACE", "0")
-    table, ring = STAGES.snapshot(), RING.stats()["added"]
+    # a handler's span closes AFTER its reply is on the wire, and heartbeats
+    # are requests too: let what began before the switch was thrown end
+    table, ring = None, None
+    while (table, ring) != (STAGES.snapshot(), RING.stats()["added"]):
+        table, ring = STAGES.snapshot(), RING.stats()["added"]
+        time.sleep(0.05)
     for fid, want in list(sealed["blobs"].items())[:6]:
         with urllib.request.urlopen(
                 f"http://{sealed['address']}/{fid}") as resp:

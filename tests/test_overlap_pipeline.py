@@ -133,7 +133,10 @@ def test_four_leg_overlap_hides_dispatch_behind_fetch():
 
     stats = staged("ec.synthetic", lambda: _overlap_pipeline(
         produce, compute, consume, fetch=fetch, op="ec.synthetic"))
-    serial = n * (tc + tf)
+    # what the two legs took one after the other: the sleeps as they came
+    # out (a loaded machine oversleeps), never less than as they were asked
+    serial = max(n * (tc + tf),
+                 stats["dispatch_busy_s"] + stats["fetch_busy_s"])
     assert stats["fetch_busy_s"] >= n * tf * 0.9
     assert stats["wall_s"] < 0.9 * serial, stats
     assert stats["efficiency"] >= 0.7, stats
