@@ -417,8 +417,9 @@ class AioHTTPServer:
         self.host, self.port = host, port
         self.server_address = (host, port)
         self._ssl = ssl_context
+        self._workers = _aio_workers()
         self._pool = ThreadPoolExecutor(
-            max_workers=_aio_workers(), thread_name_prefix="aio-worker"
+            max_workers=self._workers, thread_name_prefix="aio-worker"
         )
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
@@ -473,6 +474,10 @@ class AioHTTPServer:
 
     def inflight_count(self) -> int:
         return len(self._conns)
+
+    def handler_count(self) -> int:
+        """Bridged handlers this server runs at once (its worker pool)."""
+        return self._workers
 
     def overloaded(self) -> bool:
         wm = serving_watermark()

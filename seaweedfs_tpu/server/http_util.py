@@ -181,6 +181,15 @@ class _ServingState:
                 pass
         return total
 
+    def handler_count(self) -> int:
+        """The most requests one of this process's servers runs handlers
+        for at once: the aio core's worker pool. 0 where nothing bounds
+        them (the threads core starts a thread a connection) or no server
+        is up. What a pool that works for the handlers sizes itself by."""
+        with self._lock:
+            servers = list(self._servers)
+        return max((s.handler_count() for s in servers), default=0)
+
     def note_rejected(self) -> None:
         with self._lock:
             self._rejected += 1
@@ -867,6 +876,9 @@ class _TrackingThreadingHTTPServer(ThreadingHTTPServer):
     def inflight_count(self) -> int:
         with self._conns_lock:
             return len(self._live_conns)
+
+    def handler_count(self) -> int:
+        return 0  # a thread a connection: nothing bounds the handlers
 
     def overloaded(self) -> bool:
         wm = serving_watermark()

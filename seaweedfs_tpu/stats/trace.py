@@ -252,9 +252,14 @@ class StageTable:
     kept under one lock. A reader takes two snapshots and subtracts.
     A stage that asks another server also sums ``ok`` (asks answered),
     ``ok_s`` (seconds inside the attempts that were answered) and
-    ``absent`` (asks the shard-location table answered "nowhere")."""
+    ``absent`` (asks the shard-location table answered "nowhere"); one that
+    sends several asks side by side sums ``width`` (asks started together)
+    and ``spares`` (asks made after one of them failed)."""
 
-    SUMMED_TAGS = ("bytes", "failed", "slept_s", "ok", "ok_s", "absent")
+    SUMMED_TAGS = (
+        "bytes", "failed", "slept_s", "ok", "ok_s", "absent", "width",
+        "spares",
+    )
 
     def __init__(self):
         self._lock = make_lock("StageTable._lock")

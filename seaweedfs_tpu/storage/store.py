@@ -12,10 +12,12 @@ stream, and the EC read path with on-the-fly reconstruction:
 
 from __future__ import annotations
 
+import contextvars
 import os
 import threading
 import time
 from collections import deque
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, NamedTuple, Optional
 
 import numpy as np
@@ -51,6 +53,45 @@ class RemoteShards(NamedTuple):
     # (holder url, vid, shard id, offset, size) -> the range; raises on a
     # holder that refuses, times out or does not have the shard
     fetch: Callable[[str, int, int, int, int], bytes]
+
+
+class _SiblingWorkers:
+    """The store's long-lived threads for the remote siblings of degraded
+    reads. The HTTP keep-alive pool is thread-local (``server/http_util``
+    ``_pool_local``): a thread born for one recovery would dial every
+    holder anew, one that lives as long as the store keeps a warm socket a
+    peer. ``size`` asks run at once; an ask beyond them is refused, not
+    queued, and its recovery makes it on its own thread — never behind
+    another recovery's."""
+
+    def __init__(self, size: int):
+        self._pool = ThreadPoolExecutor(
+            max_workers=size, thread_name_prefix="ec-sibling"
+        )
+        self._free = threading.BoundedSemaphore(size)
+
+    def submit(self, ctx: contextvars.Context, fn, *args) -> Optional[Future]:
+        """``fn(*args)`` on a worker, inside a copy of ``ctx`` (the
+        request's trace parent and deadline reach it; a Context is entered
+        by one thread at a time, hence a copy a task). None when every
+        worker is taken, or the store was closed."""
+        if not self._free.acquire(blocking=False):
+            return None
+        try:
+            return self._pool.submit(self._run, ctx.copy(), fn, args)
+        except RuntimeError:  # shut down: Store.close came first
+            self._free.release()
+            return None
+
+    def _run(self, ctx: contextvars.Context, fn, args):
+        try:
+            return ctx.run(fn, *args)
+        finally:
+            self._free.release()
+
+    def close(self) -> None:
+        # asks in flight end inside remote_fetch_timeout_s; none is queued
+        self._pool.shutdown(wait=True)
 
 
 class Store:
@@ -90,6 +131,8 @@ class Store:
         # is read, rebuilt and decoded at the volume's own (its .vif)
         self.ec_geometry = ec_geometry
         self.remote_shards: Optional[RemoteShards] = None
+        # made by the first recovery that has a remote sibling to fetch
+        self._sibling_workers: Optional[_SiblingWorkers] = None
         # native turbo data plane (native/turbo.py); set by the volume
         # server when it owns the public port through the engine
         self.turbo_engine = None
@@ -614,6 +657,22 @@ class Store:
             except RetryError:
                 return None
 
+    def _siblings(self, ev: EcVolume) -> _SiblingWorkers:
+        workers = self._sibling_workers  # sweedlint: ok lock-discipline GIL-atomic reference read; written once, under the lock below
+        if workers is None:
+            # sized by what can be seen: a recovery asks at most k siblings
+            # at once, and the serving core runs so many handlers — hence
+            # recoveries — at once (0: nothing bounds them, or no server)
+            from ..server.http_util import SERVING
+
+            with self._lock:
+                if self._sibling_workers is None:
+                    self._sibling_workers = _SiblingWorkers(
+                        max(ev.data_shards, SERVING.handler_count())
+                    )
+                workers = self._sibling_workers
+        return workers
+
     def _recover_interval(
         self,
         ev: EcVolume,
@@ -622,13 +681,20 @@ class Store:
         size: int,
         believed: Optional[float] = None,
     ) -> bytes:
-        """Fetch the same byte range from ≥k sibling shards and RS-decode
-        (recoverOneRemoteEcShardInterval, store_ec.go:322). ``believed``:
-        when the location table the read found in hand was taken. If that
-        table is still the one in hand when fewer than k siblings were
-        reached, it is taken anew once and the siblings it called "nowhere"
-        are asked for again: no read fails on an old answer."""
+        """Fetch the same byte range from k sibling shards and RS-decode
+        (recoverOneRemoteEcShardInterval, store_ec.go:322). The siblings
+        are PLANNED along the shard ids — local, listed on another server
+        by the volume's location table, or nowhere (asked at once, and
+        passed over) — until k are chosen; then the local ones are read on
+        this thread while the listed ones are fetched side by side on the
+        store's workers, k - local asks and no more. A sibling that fails
+        sends the plan on along the ids for a spare. ``believed``: when the
+        location table the read found in hand was taken. If that table is
+        still the one in hand when fewer than k siblings were reached, it
+        is taken anew once and the siblings it called "nowhere" are asked
+        for again: no read fails on an old answer."""
         codec = self.ec_codec.at(*ev.geometry)
+        me = f"{self.ip}:{self.port}"
         # quiet, as the decode below: a slow recovery is named by the leaf
         # stage that was slow (an ask, the local reads, the launch)
         with trace.stage_span(
@@ -640,39 +706,93 @@ class Store:
             local_s, local_bytes = 0.0, 0
             absent: list[int] = []
 
-            def ask(sid: int, newer_than: Optional[float] = None) -> bool:
+            def ask(sid: int, newer_than: Optional[float] = None):
                 t = time.perf_counter()
                 buf = self._remote_shard_read(ev, sid, offset, size, newer_than)
                 if buf is None:  # never short: that is a failed ask
-                    return False
+                    return None
                 # one sibling fetched from the server that holds it
                 trace.record_stage(
                     "ec.recover.remote", time.perf_counter() - t,
                     sid=sid, bytes=size,
                 )
-                shards[sid] = np.frombuffer(buf, dtype=np.uint8)
-                return True
+                return np.frombuffer(buf, dtype=np.uint8)
 
-            for sid in range(ev.total_shards):
-                if sid == missing_shard:
-                    continue
-                local = ev.shards.get(sid)
-                if local is not None:
+            def got(sid: int, range_: Optional[np.ndarray]) -> None:
+                nonlocal have
+                if range_ is None:
+                    absent.append(sid)
+                else:
+                    shards[sid] = range_
+                    have += 1
+
+            walk = (s for s in range(ev.total_shards) if s != missing_shard)
+            t_fan: Optional[float] = None  # when the first ask was sent
+            width = spares = 0
+            first = True
+            while True:
+                # the plan: on along the ids until what is in hand and what
+                # is chosen make k, where a walk whose every ask succeeds
+                # would stop
+                local: list[tuple[int, object]] = []
+                listed: list[int] = []
+                while have + len(local) + len(listed) < ev.data_shards:
+                    sid = next(walk, None)
+                    if sid is None:
+                        break
+                    shard = ev.shards.get(sid)
+                    if shard is not None:
+                        local.append((sid, shard))
+                    elif any(u != me for u in ev.shard_holders(sid)):
+                        listed.append(sid)
+                    else:
+                        # nowhere by the table in hand: the ask ends at
+                        # once, unless it found the table stale and the
+                        # new one lists the shard
+                        got(sid, ask(sid))
+                if not local and not listed:
+                    break
+                # the listed side by side on the store's workers; one no
+                # worker is free for is made here, as all were before
+                flying: list[tuple[int, Future]] = []
+                mine: list[int] = []
+                if listed:
+                    if t_fan is None:
+                        t_fan = time.perf_counter()
+                    workers = self._siblings(ev)
+                    # this request's trace parent and deadline, for them
+                    ctx = contextvars.copy_context()
+                    for sid in listed:
+                        fut = workers.submit(ctx, ask, sid)
+                        if fut is None:
+                            mine.append(sid)
+                        else:
+                            flying.append((sid, fut))
+                    if first:
+                        width = len(flying)
+                    else:
+                        spares += len(listed)
+                first = False
+                for sid, shard in local:
                     t = time.perf_counter()
-                    buf = local.read_at(offset, size)
+                    buf = shard.read_at(offset, size)
                     local_s += time.perf_counter() - t
                     local_bytes += len(buf) if buf is not None else 0
                     if buf is not None and len(buf) == size:
-                        shards[sid] = np.frombuffer(buf, dtype=np.uint8)
-                        have += 1
-                elif ask(sid):
-                    have += 1
-                else:
-                    absent.append(sid)
-                if have >= ev.data_shards:
-                    break
+                        got(sid, np.frombuffer(buf, dtype=np.uint8))
+                for sid in mine:
+                    got(sid, ask(sid))
+                for sid, fut in flying:
+                    got(sid, fut.result())
             # the local siblings' reads of this recovery, taken together
             trace.record_stage("ec.recover.local", local_s, bytes=local_bytes)
+            if t_fan is not None:
+                # the wall from the first ask sent to the last range in
+                # hand; the asks' own seconds (ec.read.remote) overlap
+                trace.record_stage(
+                    "ec.recover.fanout", time.perf_counter() - t_fan,
+                    width=width, spares=spares,
+                )
             if (
                 have < ev.data_shards
                 and believed is not None
@@ -681,9 +801,8 @@ class Store:
                 # every "nowhere" came from a table older than this read:
                 # one refresh, and the siblings it did not list are asked
                 # for again
-                for sid in absent:
-                    if ask(sid, newer_than=believed):
-                        have += 1
+                for sid in tuple(absent):
+                    got(sid, ask(sid, newer_than=believed))
                     if have >= ev.data_shards:
                         break
             if have < ev.data_shards:
@@ -754,5 +873,8 @@ class Store:
         return {"ip": self.ip, "port": self.port, "ec_shards": ec_shards}
 
     def close(self) -> None:
+        workers = self._sibling_workers  # sweedlint: ok lock-discipline GIL-atomic reference read; written once, under Store._lock
+        if workers is not None:
+            workers.close()
         for loc in self.locations:
             loc.close()
