@@ -86,10 +86,13 @@ def _ec_geometry(text: str):
 def _add_ec_geometry(parser) -> None:
     parser.add_argument(
         "-ec.geometry", dest="ec_geometry", type=_ec_geometry, default="10+4",
-        metavar="k+m",
-        help="the Reed-Solomon code of the volumes THIS server seals: k data "
-             "+ m parity shards, k+m <= 32 (default 10+4). A sealed volume "
-             "keeps its own (its .vif), whatever its holders seal at",
+        metavar="k+m|k+l+g",
+        help="the code of the volumes THIS server seals: Reed-Solomon, k "
+             "data + m parity shards (default 10+4), or a local "
+             "reconstruction code, k data + l local + g global parity "
+             "shards (12+2+2: a shard lost alone is rebuilt from the six "
+             "others of its local group); at most 32 shards. A sealed "
+             "volume keeps its own (its .vif), whatever its holders seal at",
     )
 
 

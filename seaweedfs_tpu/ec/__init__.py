@@ -1,5 +1,6 @@
 """Erasure coding: Reed-Solomon over GF(2^8) — RS(10,4) by default, the
-geometry a volume was sealed at otherwise (`constants.Geometry`) — TPU-native.
+geometry a volume was sealed at otherwise (`constants.Geometry`: RS(k, m), or
+a local reconstruction code, LRC(12,2,2)) — TPU-native.
 
 The reference erasure-codes sealed volumes with klauspost/reedsolomon
 (`weed/storage/erasure_coding/ec_encoder.go`). Here the same code — identical

@@ -1,9 +1,13 @@
-"""RS codecs: encode/reconstruct with pluggable backends
-(tpu | mesh | cpu | numpy).
+"""Erasure codecs: encode/reconstruct with pluggable backends
+(tpu | mesh | cpu | numpy), and the one read-set planner (`read_plan`).
 
-All backends compute the same function — GF(2^8) matmul with the
-klauspost-compatible matrix (gf.build_matrix) — so shard bytes are identical
-regardless of where they were computed. Mirrors the reference's use of
+All backends compute the same function — GF(2^8) matmul with the code's
+matrix (`code_matrix`: klauspost's for RS(k, m), gf.build_matrix; the local
+reconstruction code's, gf.lrc_matrix) — so shard bytes are identical
+regardless of where they were computed. What a rebuild or a degraded read
+has to read follows from the matrix too: `read_plan` solves over the rows
+of the shards that are present, and says no where they do not decode.
+Mirrors the reference's use of
 `reedsolomon.Encoder` (Encode/Reconstruct/ReconstructData — call sites
 `weed/storage/erasure_coding/ec_encoder.go:179,270`,
 `weed/storage/store_ec.go:367`).
@@ -29,7 +33,7 @@ from __future__ import annotations
 import copy
 import functools
 import os
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -38,6 +42,105 @@ from ..util import jaxenv
 from ..util.locks import make_lock
 from . import gf
 from .constants import DATA_SHARDS, PARITY_SHARDS, Geometry
+
+
+class Undecodable(ValueError):
+    """The shards that are left do not determine the shards that are
+    wanted: the loss is refused, never answered."""
+
+
+class ReadPlan(NamedTuple):
+    """What rebuilds a set of wanted shards: ``read``, the shards to read,
+    ascending; ``matrix``, (wanted × read), whose product with the read
+    shards' bytes is the wanted shards' bytes, in the order they were
+    asked; ``local``, whether the read set lies inside the wanted shards'
+    own local groups."""
+
+    read: tuple[int, ...]
+    matrix: np.ndarray
+    local: bool
+
+
+@functools.lru_cache(maxsize=None)  # a handful of geometries a process
+def code_matrix(geometry: Geometry) -> np.ndarray:
+    """The (k + m) × k encode matrix of a geometry, identity on top:
+    klauspost's inverted Vandermonde for RS(k, m), `gf.lrc_matrix` for a
+    local reconstruction code. Shared and read-only."""
+    k, local = geometry.data_shards, geometry.local_parity_shards
+    if local:
+        matrix = gf.lrc_matrix(k, local, geometry.global_parity_shards)
+    else:
+        matrix = gf.build_matrix(k, geometry.total_shards)
+    matrix.setflags(write=False)
+    return matrix
+
+
+@functools.lru_cache(maxsize=4096)  # a degraded read plans every interval
+def read_plan(geometry: Geometry, wanted: tuple[int, ...],
+              present: tuple[int, ...]) -> ReadPlan:
+    """THE read-set planner, of a rebuild, of a degraded read and of the
+    shell's gather alike: the fewest of the ``present`` shards whose rows
+    of the code's matrix span the rows of the ``wanted`` ones, and the
+    matrix over them. Raises `Undecodable` when no set of them does.
+
+    The present shards are walked — the other members of the wanted
+    shards' local groups first, then the rest in the order given (a caller
+    puts what is cheap to read first) — and one is kept if its row is
+    independent of those kept before, until the wanted rows lie in the
+    span. The kept rows are independent, so each wanted row is ONE
+    combination of them, and the shards with a coefficient in any are the
+    one smallest subset of the kept that will do: the read set. A shard
+    lost alone in its local group is so rebuilt from the group's others
+    (six of LRC(12,2,2)'s sixteen) and everything else from at most k. For
+    RS(k, m) any k rows are independent and no coefficient of an inverse
+    of them is zero: the answer is the first k present and the rows
+    klauspost's Reconstruct inverts, to the byte."""
+    matrix = code_matrix(geometry)
+    k, n = matrix.shape[1], matrix.shape[0]
+    mt = gf.get_mul_table()
+    groups = {s for w in wanted for s in geometry.local_group(w)}
+    walk = [s for s in present if s in groups] + [
+        s for s in present if s not in groups
+    ]
+    # every row carried as [its k coefficients | the combination of shards'
+    # rows it stands for]; the basis is kept reduced (a pivot's column is
+    # zero in every other row, in the residuals too)
+    basis: list[tuple[int, np.ndarray]] = []  # (pivot column, row)
+    residual = np.zeros((len(wanted), k + n), dtype=np.uint8)
+    residual[:, :k] = matrix[list(wanted)]
+    for sid in walk:
+        if not residual[:, :k].any():
+            break
+        if sid in wanted:
+            continue
+        row = np.zeros(k + n, dtype=np.uint8)
+        row[:k] = matrix[sid]
+        row[k + sid] = 1
+        for col, kept in basis:
+            if row[col]:
+                row ^= mt[row[col], kept]
+        lead = np.flatnonzero(row[:k])
+        if not len(lead):
+            continue  # in the span of those kept: it brings nothing
+        col = int(lead[0])
+        row = mt[gf.gal_inverse(int(row[col])), row]
+        basis = [
+            (c, kept ^ mt[kept[col], row] if kept[col] else kept)
+            for c, kept in basis
+        ] + [(col, row)]
+        for r in residual:
+            if r[col]:
+                r ^= mt[r[col], row]
+    if residual[:, :k].any():
+        raise Undecodable(
+            f"ec geometry {geometry}: shards {sorted(present)} do not "
+            f"determine shards {sorted(wanted)}"
+        )
+    over = residual[:, k:]
+    read = tuple(int(s) for s in np.flatnonzero(over.any(axis=0)))
+    rows = np.ascontiguousarray(over[:, list(read)])
+    rows.setflags(write=False)
+    return ReadPlan(read, rows, groups.issuperset(read))
 
 
 class Codec:
@@ -55,25 +158,30 @@ class Codec:
         self._views: dict[Geometry, Codec] = {self.geometry: self}
         self._views_lock = make_lock("Codec._views_lock")
 
-    def _set_geometry(self, data_shards: int, parity_shards: int) -> None:
+    def _set_geometry(self, data_shards: int, parity_shards: int,
+                      local_parity_shards: int = 0) -> None:
         """All a codec holds that is tied to a geometry: a few hundred
         bytes of host-side bookkeeping."""
-        self.geometry = Geometry(data_shards, parity_shards)
-        self.matrix = gf.build_matrix(data_shards, self.total_shards)
+        self.geometry = Geometry(data_shards, parity_shards, local_parity_shards)
+        self.matrix = code_matrix(self.geometry)
         self.parity_rows = self.matrix[data_shards:]
 
     data_shards = property(lambda self: self.geometry.data_shards)
     parity_shards = property(lambda self: self.geometry.parity_shards)
     total_shards = property(lambda self: self.geometry.total_shards)
 
-    def at(self, data_shards: int, parity_shards: int) -> "Codec":
-        """This codec at another geometry: a view that owns the matrix and
-        the shard counts and SHARES everything a process has one of — the
-        devices, the jit and bit-matrix caches, the launch counts, the
-        kernel's prep tables. Built once a geometry and kept, so a server
-        that seals at 12+4 reads and rebuilds the 10+4 volumes it holds
-        through one chip and one ``ec_codec`` of /status."""
-        geometry = Geometry(data_shards, parity_shards).checked()
+    def at(self, data_shards: int, parity_shards: int,
+           local_parity_shards: int = 0) -> "Codec":
+        """This codec at another geometry (``codec.at(*geometry)``): a view
+        that owns the matrix and the shard counts and SHARES everything a
+        process has one of — the devices, the jit and bit-matrix caches,
+        the launch counts, the kernel's prep tables. Built once a geometry
+        and kept, so a server that seals at 12+2+2 reads and rebuilds the
+        10+4 volumes it holds through one chip and one ``ec_codec`` of
+        /status."""
+        geometry = Geometry(
+            data_shards, parity_shards, local_parity_shards
+        ).checked()
         with self._views_lock:
             view = self._views.get(geometry)
             if view is None:
@@ -83,6 +191,10 @@ class Codec:
                 view._set_geometry(*geometry)
                 self._views[geometry] = view
         return view
+
+    def plan(self, wanted: Sequence[int], present: Sequence[int]) -> ReadPlan:
+        """`read_plan` at this codec's geometry."""
+        return read_plan(self.geometry, tuple(wanted), tuple(present))
 
     # -- backend hooks -------------------------------------------------------
     def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
@@ -120,56 +232,35 @@ class Codec:
         """data (k, N) → all shards (k+m, N) (data rows pass through)."""
         return np.concatenate([data, self.encode(data)], axis=0)
 
-    def _decode_matrix_for(self, present: Sequence[int]) -> np.ndarray:
-        """Inverse of the matrix rows for the first k present shards.
-
-        Mirrors klauspost's Reconstruct: collect valid shards in index order
-        until k are found; the decode matrix maps those k shards back to the
-        k data shards.
-        """
-        rows = list(present)[: self.data_shards]
-        if len(rows) < self.data_shards:
-            raise ValueError(
-                f"need {self.data_shards} shards to reconstruct, have {len(rows)}"
-            )
-        sub = self.matrix[rows]
-        return gf.mat_invert(sub)
-
     def reconstruct(
-        self, shards: list[Optional[np.ndarray]], data_only: bool = False
+        self,
+        shards: list[Optional[np.ndarray]],
+        data_only: bool = False,
+        wanted: Optional[Sequence[int]] = None,
     ) -> list[np.ndarray]:
-        """Fill in missing (None) shards in place; returns the full list.
+        """Fill in missing (None) shards in place and return the list: the
+        ``wanted`` ones (klauspost's ReconstructSome), or every missing
+        one, or with ``data_only`` every missing data shard — from the
+        fewest of the present ones that determine them (`read_plan`), in
+        one matmul. Raises `Undecodable` when the present ones do not.
 
         Bit-identical to klauspost Encoder.Reconstruct / ReconstructData.
         """
         if len(shards) != self.total_shards:
             raise ValueError(f"expected {self.total_shards} shards")
         present = [i for i, s in enumerate(shards) if s is not None]
-        missing = [i for i, s in enumerate(shards) if s is None]
-        if not missing:
+        if wanted is None:
+            limit = self.data_shards if data_only else self.total_shards
+            wanted = range(limit)
+        wanted = [i for i in wanted if shards[i] is None]
+        if not wanted:
             return shards  # nothing to do
-        if len(present) < self.data_shards:
-            raise ValueError("too few shards to reconstruct")
-
-        first_k = present[: self.data_shards]
-        sub_data = np.stack([shards[i] for i in first_k])
-        missing_data = [i for i in missing if i < self.data_shards]
-        missing_parity = [i for i in missing if i >= self.data_shards]
-
-        if missing_data:
-            decode = self._decode_matrix_for(first_k)
-            rows = decode[missing_data]  # (|md| × k)
-            rebuilt = self.matmul(rows, sub_data)
-            for j, i in enumerate(missing_data):
-                shards[i] = rebuilt[j]
-
-        if missing_parity and not data_only:
-            all_data = np.stack([shards[i] for i in range(self.data_shards)])
-            rows = self.matrix[missing_parity]
-            rebuilt = self.matmul(rows, all_data)
-            for j, i in enumerate(missing_parity):
-                shards[i] = rebuilt[j]
-
+        plan = self.plan(wanted, present)
+        rebuilt = self.matmul(
+            plan.matrix, np.stack([shards[i] for i in plan.read])
+        )
+        for j, i in enumerate(wanted):
+            shards[i] = rebuilt[j]
         return shards
 
     def reconstruct_data(self, shards: list[Optional[np.ndarray]]) -> list[np.ndarray]:

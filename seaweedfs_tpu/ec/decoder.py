@@ -8,8 +8,9 @@ and the .dat size is recovered from the highest .ecx entry end. Backing
 VolumeEcShardsToVolume rpc.
 
 Missing data shards are first regenerated from parity through the codec
-(`encoder.rebuild_ec_files`), so any k present shards decode. The geometry
-is the volume's own (its .vif, `encoder.volume_geometry`).
+(`encoder.rebuild_ec_files`), so whatever shards the volume's code decodes
+from will do (any k of an RS volume). The geometry is the volume's own (its
+.vif, `encoder.volume_geometry`).
 """
 
 from __future__ import annotations

@@ -813,9 +813,15 @@ def rebuild_ec_files(
     base_file_name: str,
     codec: Optional[Codec] = None,
     chunk_bytes: Optional[int] = None,
+    wanted: Optional[list[int]] = None,
 ) -> list[int]:
-    """Regenerate missing shard files from ≥k present ones
-    (RebuildEcFiles / generateMissingEcFiles, :61,95). Returns generated ids."""
+    """Regenerate missing shard files — all of them, or the ``wanted`` ones
+    among them — from the fewest present ones that determine them
+    (RebuildEcFiles / generateMissingEcFiles, :61,95; the read set is
+    `Codec.plan`'s: the first k present of an RS volume, the six others of
+    its local group for a shard an LRC(12,2,2) volume lost alone). Returns
+    the generated ids; a loss the code does not decode raises
+    `codec.Undecodable` and writes nothing."""
     codec = codec or get_codec()
     total = codec.total_shards
     chunk = chunk_bytes if chunk_bytes is not None else codec.chunk_bytes
@@ -827,89 +833,65 @@ def rebuild_ec_files(
         path = base_file_name + shard_ext(sid)
         if os.path.exists(path):
             present[sid] = path
-        else:
+        elif wanted is None or sid in wanted:
             missing.append(sid)
     if not missing:
         return []
-    if len(present) < codec.data_shards:
-        raise ValueError(
-            f"need {codec.data_shards} shards to rebuild, have {len(present)}"
-        )
+    # one record a rebuild: how many shards it reads, and whether the lost
+    # shards' own local groups sufficed
+    with trace.stage_span("ec.rebuild.plan", width=0, local=0) as span:
+        plan = codec.plan(missing, sorted(present))
+        if span is not None:
+            span.tags.update(width=len(plan.read), local=int(plan.local))
 
     sizes = {os.path.getsize(p) for p in present.values()}
     if len(sizes) != 1:
         raise ValueError(f"ec shard sizes disagree: {sizes}")
     shard_size = sizes.pop()
 
-    ins = {sid: open(p, "rb") for sid, p in present.items()}
+    ins = [open(present[sid], "rb") for sid in plan.read]
     outs = {sid: open(base_file_name + shard_ext(sid), "wb") for sid in missing}
     try:
         _rebuild_pipelined(
-            codec, ins, outs, missing, shard_size,
+            codec, ins, outs, plan.matrix, shard_size,
             _depth_chunk(chunk, shard_size, codec.alignment()),
         )
         for sid in missing:
             outs[sid].truncate(shard_size)
     finally:
-        for fh in ins.values():
+        for fh in ins:
             fh.close()
         for fh in outs.values():
             fh.close()
     return missing
 
 
-def _rebuild_rows(codec, present_ids: list[int], missing: list[int]) -> np.ndarray:
-    """One matrix rebuilding every missing shard from the first k present
-    shards. Missing data shards take their decode-matrix rows; missing
-    parity rows compose through the full decode matrix
-    (matrix[mp] · decode = parity-of-reconstructed-data), so a single
-    matmul per chunk covers both — bit-identical to the two-step
-    Codec.reconstruct, which tests assert."""
-    from . import gf
-
-    k = codec.data_shards
-    first_k = present_ids[:k]
-    decode_full = codec._decode_matrix_for(first_k)
-    missing_data = [i for i in missing if i < k]
-    missing_parity = [i for i in missing if i >= k]
-    blocks = []
-    if missing_data:
-        blocks.append(decode_full[missing_data])
-    if missing_parity:
-        blocks.append(gf.mat_mul(codec.matrix[missing_parity], decode_full))
-    # missing is sorted and data ids < parity ids, so this stacking order
-    # matches the outs iteration order
-    return np.vstack(blocks)
-
-
-def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
+def _rebuild_pipelined(codec, ins, outs, rows, shard_size, chunk) -> None:
     """`rebuild_ec_files` through the overlap pipeline: disk reads, H2D
     staging + device matmul, and shard writes of neighbouring chunks
-    overlap, as a seal's do.
+    overlap, as a seal's do. ``ins`` are the files of the plan's read set,
+    ``rows`` its matrix over them, ``outs`` the files it rebuilds, by id.
 
-    Row ``r`` of a chunk's ``(k, padded)`` buffer is read straight from the
-    ``r``-th of the first k present shards. The buffer is not carried past
+    Row ``r`` of a chunk's ``(len(ins), padded)`` buffer is read straight
+    from the ``r``-th file of the read set. The buffer is not carried past
     the device, so the fetch leg gives it back to the pool once the
     chunk's RESULT is ready and copied back, never at ``device_put``
     (and not before the copy back: a buffer given earlier is a third chunk
     staged on the device while the copy still runs): JAX keeps a host
     array immutable until it is transferred, and on the CPU platform the
     staged input may be the numpy memory itself."""
-    k = codec.data_shards
-    present_ids = sorted(ins)
-    first_k = present_ids[:k]
-    rows = _rebuild_rows(codec, present_ids, missing)
+    n_read = len(ins)
     align = codec.alignment()
     widest = min(chunk, shard_size)
-    buffers = _ChunkBuffers("ec.rebuild", k * -(-widest // align) * align)
+    buffers = _ChunkBuffers("ec.rebuild", n_read * -(-widest // align) * align)
 
     def read_chunk(pos, width, held, buf):
         if buf is None:
             return width, None
         buf[:, width:] = 0  # the alignment tail: zeros encode to zeros
-        for row, sid in enumerate(first_k):
+        for row, fh in enumerate(ins):
             if row in held:
-                _pread_into(ins[sid].fileno(), pos, [buf[row, :width]])
+                _pread_into(fh.fileno(), pos, [buf[row, :width]])
                 trace.add_stage_bytes(width)
             else:
                 buf[row, :width] = 0  # a hole: not read, and not left stale
@@ -919,9 +901,10 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
         pos = 0
         while pos < shard_size:
             width = min(chunk, shard_size - pos)
-            held = [row for row, sid in enumerate(first_k)
-                    if not _is_hole(ins[sid].fileno(), pos, width)]
-            buf = buffers.take(k, -(-width // align) * align) if held else None
+            held = [row for row, fh in enumerate(ins)
+                    if not _is_hole(fh.fileno(), pos, width)]
+            buf = (buffers.take(n_read, -(-width // align) * align)
+                   if held else None)
             yield functools.partial(read_chunk, pos, width, held, buf)
             pos += width
 
@@ -948,12 +931,12 @@ def _rebuild_pipelined(codec, ins, outs, missing, shard_size, chunk) -> None:
     def consume(got):
         width, out = got
         if out is None:
-            for sid in missing:
-                outs[sid].seek(width, 1)
+            for fh in outs.values():
+                fh.seek(width, 1)
             return
-        for j, sid in enumerate(missing):
-            outs[sid].write(out[j, :width].tobytes())
-        trace.add_stage_bytes(len(missing) * width)
+        for j, fh in enumerate(outs.values()):  # in the plan's wanted order
+            fh.write(out[j, :width].tobytes())
+        trace.add_stage_bytes(len(outs) * width)
 
     h2d = _StagedWatch("ec.rebuild")
     try:
@@ -999,15 +982,16 @@ def save_volume_info(
     the background scrub a ground truth for shard integrity: RS encoding is
     deterministic, so a rebuilt shard hashes identically and the sums stay
     valid across rebuilds and copies (the .vif travels with the shards).
-    ``geometry`` (``data_shards`` / ``parity_shards``) is the code the
-    volume was sealed at: with the shards it travels to every holder, and
-    every later read, rebuild, copy and decode takes it from here
+    ``geometry`` (``data_shards`` / ``parity_shards`` and, for a local
+    reconstruction code, ``local_parity_shards``) is the code the volume
+    was sealed at: with the shards it travels to every holder, and every
+    later read, rebuild, copy and decode takes it from here
     (`volume_geometry`)."""
     info = {"files": [], "version": version, "replication": replication}
     if shard_sums is not None:
         info["shard_sums"] = shard_sums
     if geometry is not None:
-        info["data_shards"], info["parity_shards"] = geometry
+        info.update(geometry.volume_info())
     with open(file_name, "w") as f:
         f.write(json.dumps(info, indent=2))
 

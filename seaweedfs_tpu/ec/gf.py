@@ -172,6 +172,39 @@ def build_matrix(data_shards: int, total_shards: int) -> np.ndarray:
     return mat_mul(vm, top_inv)
 
 
+def lrc_matrix(data_shards: int, local: int, global_: int) -> np.ndarray:
+    """The encode matrix of a Local Reconstruction Code (Huang et al.,
+    Erasure Coding in Windows Azure Storage, ATC'12, sec. 2.1-2.2) with two
+    local groups: identity on the top k rows; then a local parity a group,
+    the XOR of its k / 2 data shards (a 0/1 row); then the global parities,
+    row j the (j + 1)-th powers of one coefficient a data shard. The
+    coefficients carry the paper's rule from GF(2^4) to GF(2^8): the first
+    group's are the non-zero bytes whose low nibble is zero, ``(i + 1) <<
+    4``, the second's those whose high nibble is zero, ``i + 1`` — so no
+    two are equal, and no sum of two of one group equals a sum of two of
+    the other unless both are zero. That makes LRC(12,2,2) decode every
+    loss the code's structure allows (all of three shards, 1,568 of the
+    1,820 of four; tests/test_ec_lrc.py walks them). Which loss decodes is
+    never assumed from this: `codec.read_plan` solves over the rows."""
+    if local != 2 or data_shards % 2 or not 0 < data_shards // 2 <= 15:
+        raise ValueError(
+            f"bad lrc geometry k={data_shards} l={local} g={global_}"
+        )
+    size = data_shards // 2
+    coefficients = [(i + 1) << 4 for i in range(size)] + [
+        i + 1 for i in range(size)
+    ]
+    rows = [np.eye(data_shards, dtype=np.uint8)]
+    groups = np.zeros((2, data_shards), dtype=np.uint8)
+    groups[0, :size] = groups[1, size:] = 1
+    rows.append(groups)
+    rows.append(np.array(
+        [[gal_exp(c, j + 1) for c in coefficients] for j in range(global_)],
+        dtype=np.uint8,
+    ).reshape(global_, data_shards))
+    return np.concatenate(rows, axis=0)
+
+
 def parity_matrix(data_shards: int, parity_shards: int) -> np.ndarray:
     """Just the parity rows (m × k) of the encode matrix."""
     return build_matrix(data_shards, data_shards + parity_shards)[data_shards:]

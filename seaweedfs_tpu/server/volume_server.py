@@ -30,6 +30,7 @@ import time
 from typing import Optional
 
 from ..ec import encoder
+from ..ec.codec import Undecodable
 from ..ec.constants import DEFAULT_GEOMETRY, Geometry, shard_ext
 from ..ec.ec_volume import EcVolume
 from ..stats import trace
@@ -969,10 +970,18 @@ class VolumeServer:
         base = self._find_base(vid)
         if base is None:
             return 404, {"error": "ec volume not found"}
+        # ``shards``: the ones to regenerate (the shell names the volume's
+        # missing ones, so the read set is theirs alone); every shard that
+        # is not here when the caller names none (VolumeEcShardsRebuild)
+        wanted = [int(s) for s in q.get("shards", "").split(",") if s != ""]
         # at the volume's own geometry, whatever this server seals at
-        generated = encoder.rebuild_ec_files(
-            base, self.store.ec_codec.at(*encoder.volume_geometry(base))
-        )
+        try:
+            generated = encoder.rebuild_ec_files(
+                base, self.store.ec_codec.at(*encoder.volume_geometry(base)),
+                wanted=wanted or None,
+            )
+        except Undecodable as e:
+            return 409, {"error": str(e)}
         from ..ec.ec_volume import rebuild_ecx_file
 
         rebuild_ecx_file(base)

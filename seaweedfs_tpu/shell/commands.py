@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
+from ..ec.codec import Undecodable, read_plan
 from ..ec.constants import DEFAULT_GEOMETRY, Geometry
 from ..server.http_util import http_json
 
@@ -407,24 +408,32 @@ def ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
     missing = sorted(set(range(geometry.total_shards)) - present)
     if not missing:
         return {"volume": vid, "rebuilt": []}
-    if len(present) < geometry.data_shards:
-        raise RuntimeError(
-            f"volume {vid}: only {len(present)} shards survive, cannot rebuild"
-        )
 
     # rebuilder = node already holding the most shards (minimizes copying)
     holder_counts: dict[str, int] = {}
     for sid, urls in by_shard.items():
         for u in urls:
             holder_counts[u] = holder_counts.get(u, 0) + 1
-    rebuilder = max(holder_counts, key=holder_counts.get)
+    rebuilder = max(holder_counts, key=holder_counts.get, default=None)
 
+    # what the rebuild has to read is the plan's to say (the first k of an
+    # RS volume, a lost shard's local group of an LRC one), the rebuilder's
+    # own shards first: only the rest of the read set is copied in
     local = {sid for sid, urls in by_shard.items() if rebuilder in urls}
-    needed = [sid for sid in sorted(present - local)]
+    try:
+        plan = read_plan(
+            geometry, tuple(missing),
+            (*sorted(local), *sorted(present - local)),
+        )
+    except Undecodable as e:
+        raise RuntimeError(
+            f"volume {vid}: only {len(present)} shards survive, cannot "
+            f"rebuild ({e})"
+        ) from None
     copied_in = []
-    for sid in needed:
-        if len(local) + len(copied_in) >= geometry.data_shards:
-            break
+    for sid in plan.read:
+        if sid in local:
+            continue
         src = by_shard[sid][0]
         r = http_json(
             "POST",
@@ -437,16 +446,17 @@ def ec_rebuild(env: CommandEnv, vid: int, collection: str = "") -> dict:
         copied_in.append(sid)
 
     r = http_json(
-        "POST", f"http://{rebuilder}/admin/ec/rebuild?volume={vid}",
+        "POST",
+        f"http://{rebuilder}/admin/ec/rebuild?volume={vid}"
+        f"&shards={','.join(map(str, missing))}",
         timeout=bulk_rpc_timeout(),
     )
     if r.get("error"):
         raise RuntimeError(f"rebuild: {r['error']}")
     rebuilt = r.get("rebuilt_shards", [])
-    # the rebuild regenerates every locally-absent shard; keep only the
-    # truly-missing ones — drop copied-in temporaries AND regenerated
-    # duplicates of shards still live elsewhere (prepareDataToRecover
-    # cleanup, command_ec_rebuild.go:187)
+    # the rebuild regenerates the missing shards alone; the copied-in
+    # temporaries go (prepareDataToRecover cleanup,
+    # command_ec_rebuild.go:187)
     to_drop = sorted((set(copied_in) | set(rebuilt)) - set(missing))
     if to_drop:
         shards = ",".join(str(s) for s in to_drop)

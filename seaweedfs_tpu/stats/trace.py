@@ -254,11 +254,13 @@ class StageTable:
     ``ok_s`` (seconds inside the attempts that were answered) and
     ``absent`` (asks the shard-location table answered "nowhere"); one that
     sends several asks side by side sums ``width`` (asks started together)
-    and ``spares`` (asks made after one of them failed)."""
+    and ``spares`` (asks made after one of them failed). A read-set plan
+    (``ec.rebuild.plan``, ``ec.recover.plan``) sums ``width`` (shards it
+    reads) and ``local`` (1 where the lost shards' local groups sufficed)."""
 
     SUMMED_TAGS = (
         "bytes", "failed", "slept_s", "ok", "ok_s", "absent", "width",
-        "spares",
+        "spares", "local",
     )
 
     def __init__(self):

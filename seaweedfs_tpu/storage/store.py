@@ -681,18 +681,25 @@ class Store:
         size: int,
         believed: Optional[float] = None,
     ) -> bytes:
-        """Fetch the same byte range from k sibling shards and RS-decode
-        (recoverOneRemoteEcShardInterval, store_ec.go:322). The siblings
-        are PLANNED along the shard ids — local, listed on another server
-        by the volume's location table, or nowhere (asked at once, and
-        passed over) — until k are chosen; then the local ones are read on
-        this thread while the listed ones are fetched side by side on the
-        store's workers, k - local asks and no more. A sibling that fails
-        sends the plan on along the ids for a spare. ``believed``: when the
-        location table the read found in hand was taken. If that table is
-        still the one in hand when fewer than k siblings were reached, it
-        is taken anew once and the siblings it called "nowhere" are asked
-        for again: no read fails on an old answer."""
+        """Fetch the same byte range from the sibling shards that determine
+        the missing one and decode (recoverOneRemoteEcShardInterval,
+        store_ec.go:322). The siblings are PLANNED (`Codec.plan`: the
+        first k of an RS volume, the six others of its local group for a
+        shard an LRC(12,2,2) volume lost alone) over every shard not yet
+        known unreachable — local, listed on another server by the
+        volume's location table, or nowhere (asked at once, and counted
+        out) — then the local ones are read on this thread while the
+        listed ones are fetched side by side on the store's workers, as
+        many asks as the plan has listed shards and no more. A sibling
+        that fails is counted out and the plan made again over the rest
+        for a spare. ``believed``: when the location table the read found
+        in hand was taken. If that table is still the one in hand when the
+        shards reached do not determine the missing one, it is taken anew
+        once and the siblings it called "nowhere" are asked for again: no
+        read fails on an old answer. A loss the code does not decode is
+        refused (`EcNotFoundError`), never answered."""
+        from ..ec.codec import Undecodable
+
         codec = self.ec_codec.at(*ev.geometry)
         me = f"{self.ip}:{self.port}"
         # quiet, as the decode below: a slow recovery is named by the leaf
@@ -702,7 +709,7 @@ class Store:
             bytes=size,
         ):
             shards: list[Optional[np.ndarray]] = [None] * ev.total_shards
-            have = 0
+            unreachable = {missing_shard}
             local_s, local_bytes = 0.0, 0
             absent: list[int] = []
 
@@ -719,39 +726,90 @@ class Store:
                 return np.frombuffer(buf, dtype=np.uint8)
 
             def got(sid: int, range_: Optional[np.ndarray]) -> None:
-                nonlocal have
                 if range_ is None:
                     absent.append(sid)
+                    unreachable.add(sid)
                 else:
                     shards[sid] = range_
-                    have += 1
 
-            walk = (s for s in range(ev.total_shards) if s != missing_shard)
+            def nowhere(sid: int) -> bool:
+                """Neither local nor listed on another server by the table
+                in hand."""
+                return sid not in ev.shards and not any(
+                    u != me for u in ev.shard_holders(sid)
+                )
+
+            def read_set() -> Optional[tuple[int, ...]]:
+                """The plan over all that may still be reached; None when
+                those do not determine the missing shard."""
+                try:
+                    return codec.plan(
+                        (missing_shard,),
+                        [s for s in range(ev.total_shards)
+                         if s not in unreachable],
+                    ).read
+                except Undecodable:
+                    return None
+
+            def planned() -> Optional[list[int]]:
+                """The plan's shards that are not in hand yet — each local
+                or listed — over all that may still be reached; None when
+                those do not determine the missing one. A shard of the plan
+                that is nowhere by the table in hand is asked for at once
+                (the ask ends there, unless it found the table stale and
+                the new one lists the shard) and the plan made again."""
+                while True:
+                    read = read_set()
+                    if read is None:
+                        return None
+                    need = [s for s in read if shards[s] is None]
+                    gone = [s for s in need if nowhere(s)]
+                    if not gone:
+                        return need
+                    for sid in gone:
+                        got(sid, ask(sid))
+
             t_fan: Optional[float] = None  # when the first ask was sent
             width = spares = 0
             first = True
+            refreshed = False
             while True:
-                # the plan: on along the ids until what is in hand and what
-                # is chosen make k, where a walk whose every ask succeeds
-                # would stop
+                need = planned()
+                if need is None:
+                    if (
+                        not refreshed
+                        and believed is not None
+                        and ev.locations_taken() == believed
+                    ):
+                        # every "nowhere" came from a table older than this
+                        # read: one refresh, and the siblings it did not
+                        # list are asked for again, until enough are there
+                        refreshed = True
+                        for sid in tuple(absent):
+                            found = ask(sid, newer_than=believed)
+                            if found is not None:
+                                shards[sid] = found
+                                unreachable.discard(sid)
+                                if read_set() is not None:
+                                    break
+                        continue
+                    # the trace's spans end here too: nothing was decoded
+                    reachable = ev.total_shards - len(unreachable)
+                    raise EcNotFoundError(
+                        f"volume {ev.id} shard {missing_shard}: only "
+                        f"{reachable} shards reachable, which do not "
+                        "determine it"
+                    )
+                if not need:
+                    break
                 local: list[tuple[int, object]] = []
                 listed: list[int] = []
-                while have + len(local) + len(listed) < ev.data_shards:
-                    sid = next(walk, None)
-                    if sid is None:
-                        break
+                for sid in need:
                     shard = ev.shards.get(sid)
                     if shard is not None:
                         local.append((sid, shard))
-                    elif any(u != me for u in ev.shard_holders(sid)):
-                        listed.append(sid)
                     else:
-                        # nowhere by the table in hand: the ask ends at
-                        # once, unless it found the table stale and the
-                        # new one lists the shard
-                        got(sid, ask(sid))
-                if not local and not listed:
-                    break
+                        listed.append(sid)
                 # the listed side by side on the store's workers; one no
                 # worker is free for is made here, as all were before
                 flying: list[tuple[int, Future]] = []
@@ -780,6 +838,8 @@ class Store:
                     local_bytes += len(buf) if buf is not None else 0
                     if buf is not None and len(buf) == size:
                         got(sid, np.frombuffer(buf, dtype=np.uint8))
+                    else:
+                        unreachable.add(sid)
                 for sid in mine:
                     got(sid, ask(sid))
                 for sid, fut in flying:
@@ -793,27 +853,17 @@ class Store:
                     "ec.recover.fanout", time.perf_counter() - t_fan,
                     width=width, spares=spares,
                 )
-            if (
-                have < ev.data_shards
-                and believed is not None
-                and ev.locations_taken() == believed
-            ):
-                # every "nowhere" came from a table older than this read:
-                # one refresh, and the siblings it did not list are asked
-                # for again
-                for sid in tuple(absent):
-                    got(sid, ask(sid, newer_than=believed))
-                    if have >= ev.data_shards:
-                        break
-            if have < ev.data_shards:
-                raise EcNotFoundError(
-                    f"volume {ev.id} shard {missing_shard}: only {have} "
-                    "shards reachable"
-                )
+            have = [s for s, range_ in enumerate(shards) if range_ is not None]
+            # one record a recovery: the shards its decode reads, and
+            # whether the missing shard's own local group sufficed
+            with trace.stage_span("ec.recover.plan", width=0, local=0) as span:
+                plan = codec.plan((missing_shard,), have)
+                if span is not None:
+                    span.tags.update(
+                        width=len(plan.read), local=int(plan.local)
+                    )
             with trace.stage_span("ec.recover.decode", quiet=True):
-                rebuilt = codec.reconstruct(
-                    shards, data_only=missing_shard < ev.data_shards
-                )
+                rebuilt = codec.reconstruct(shards, wanted=(missing_shard,))
             return rebuilt[missing_shard].tobytes()
 
     # -- heartbeat (store.go:204-297) ----------------------------------------

@@ -102,7 +102,7 @@ def test_rebuild_worst_case_bit_identical(fixture_volume):
     ],
 )
 def test_rebuild_pipelined_combos_bit_identical(fixture_volume, gone):
-    """A rebuild's single combined matmul (`_rebuild_rows`) must give back
+    """A rebuild's single combined matmul (the plan's matrix, `Codec.plan`) must give back
     the sealed bytes for every missing-shard shape."""
     base, _ = fixture_volume
     codec = TpuCodec(chunk_bytes=8 * 1024, tile_bytes=1024)
