@@ -97,28 +97,74 @@ class _PoolClosed(Exception):
     (or waits for) a buffer after `_ChunkBuffers.close`."""
 
 
-# Chunk buffers one call may allocate: two, the one the reader fills and
-# the one the legs after it hold (double buffering). Of the up to eight
-# places of the overlap pipeline that can hold a chunk (four legs, four
-# queue slots) only these are ever occupied: the reader waits for a buffer
-# where it used to wait for a queue slot. Not more, by measurement (v5e
-# host, PERF.md §6 PR 25): a buffer's first touch costs as much as reading
-# it twice, every call, and a third chunk in flight is a third chunk
-# staged on the device (peak HBM 834 against 574 MiB) for no shorter seal.
+# Chunk buffers one call may have in flight, and idle ones the process keeps
+# between calls: two, the one the reader fills and the one the legs after
+# it hold (double buffering). Of the up to eight places of the overlap
+# pipeline that can hold a chunk (four legs, four queue slots) only these
+# are ever occupied: the reader waits for a buffer where it used to wait
+# for a queue slot. Not more, by measurement, twice. PR 25 found 4 and 8
+# slower when every buffer was a call's own and cost it a first touch.
+# PR 37 took the first touch away (`_KeptBuffers`) and swept 2, 3 and 4
+# again (v5e host, PERF.md §6 PR 37): a third chunk in flight is a third
+# chunk on the link, and the copy back of one chunk's parity falls from
+# 1.35-1.42 to 0.70-0.73 GB/s (0.54-0.62 at four) beside the staging of
+# the next ones: the fetch leg goes from 73-75% to 85-87% busy of a seal's
+# pipeline that comes out LONGER (0.82 s at two, 0.99 at three, 1.00 at
+# four), a rebuild is level (one shard lost: 1,212 / 1,223 / 1,195 MB/s
+# at the client, the median of three runs' medians), and the device holds
+# 834 MiB for 574 at its peak. What would make a deeper pool pay is a
+# copy back that is not slowed by the staging beside it, not more buffers.
 _POOL_BUFFERS = 2
 
 
+class _KeptBuffers:
+    """The chunk buffers the process keeps while no call uses them.
+
+    A 127 MiB ``np.empty`` lies above glibc's largest mmap threshold: it is
+    fresh pages every time, given back to the kernel when freed, and a read
+    into pages never touched runs at a third of the speed of one into
+    pages that were (v5e host, PERF.md §6 PR 25: 0.86 against 2.31 GB/s).
+    So a buffer a call has done with stays here, for the next seal or
+    rebuild of any geometry and backend: at most `_POOL_BUFFERS` of them,
+    whatever else comes back is freed. A daemon that has sealed holds up
+    to that many of its largest chunk while idle."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._idle: list[np.ndarray] = []
+
+    def take(self, nbytes: int) -> Optional[np.ndarray]:
+        """A kept buffer of at least ``nbytes``, or None: the caller
+        allocates. One too small for this call is dropped, not handed on:
+        the call's own takes its place when it comes back."""
+        with self._lock:
+            flat = self._idle.pop() if self._idle else None
+        return flat if flat is not None and flat.nbytes >= nbytes else None
+
+    def keep(self, flats: list) -> None:
+        with self._lock:
+            self._idle.extend(flats[: _POOL_BUFFERS - len(self._idle)])
+
+
+_KEPT = _KeptBuffers()
+
+
 class _ChunkBuffers:
-    """One call's bounded pool of host buffers for (k, width) chunks.
+    """One call's bounded share of the process's host buffers for
+    (k, width) chunks.
 
     `take` hands out a C-contiguous ``(k, width)`` view of a recycled
-    buffer, allocates while fewer than ``count`` exist, and otherwise
-    waits until `give` brings one back: the reader's backpressure, and the
-    bound on the host memory of a call's chunks. A buffer comes back
-    holding its last chunk and is NOT cleared, so whoever fills it writes
-    every byte. Each `take` leaves one stage in the tracer's table,
-    ``<op>.buf.new`` (allocated) or ``<op>.buf.wait`` (recycled;
-    ``busy_s`` is the wait), with the buffer's ``bytes``."""
+    buffer; while the call has had fewer than ``count`` it takes one the
+    process kept from an earlier call (`_KeptBuffers`) or allocates, and
+    otherwise it waits until `give` brings one back: the reader's
+    backpressure, and the bound on the host memory of a call's chunks.
+    When the call ends (`close`) its buffers go to the kept list. A buffer
+    comes back holding its last chunk — of this call or of another, of
+    another volume, geometry or number of rows — and is NOT cleared, so
+    whoever fills it writes every byte of the view. Each `take` leaves one
+    stage in the tracer's table, ``<op>.buf.new`` (allocated) or
+    ``<op>.buf.wait`` (recycled; ``busy_s`` is the wait, next to nothing
+    for a kept one), with the buffer's ``bytes``."""
 
     def __init__(self, op: str, nbytes: int, count: int = _POOL_BUFFERS):
         self._op = op
@@ -136,27 +182,37 @@ class _ChunkBuffers:
             if self._closed:
                 raise _PoolClosed()
             if self._free:
-                how, flat = "wait", self._free.pop()
+                flat = self._free.pop()
             else:
-                how, flat = "new", None
+                flat = None
                 self._unmade -= 1
+        how = "wait"
         if flat is None:
-            flat = np.empty(self._nbytes, dtype=np.uint8)
+            flat = _KEPT.take(self._nbytes)
+        if flat is None:
+            how, flat = "new", np.empty(self._nbytes, dtype=np.uint8)
         trace.record_stage(f"{self._op}.buf.{how}",
                            time.perf_counter() - t0, bytes=flat.nbytes)
         return flat[: k * width].reshape(k, width)
 
     def give(self, mat: np.ndarray) -> None:
-        """Nothing reads ``mat`` (a `take`) any more: recycle its buffer."""
+        """Nothing reads ``mat`` (a `take`) any more: recycle its buffer,
+        to this call's reader or, after `close`, to the process."""
         with self._cond:
-            self._free.append(mat.base)
-            self._cond.notify()
+            if not self._closed:
+                self._free.append(mat.base)
+                self._cond.notify()
+                return
+        _KEPT.keep([mat.base])
 
     def close(self) -> None:
-        """Release a reader that waits in `take`: the pipeline is ending."""
+        """The pipeline is ending: release a reader that waits in `take`,
+        and hand the idle buffers to the process."""
         with self._cond:
             self._closed = True
+            idle, self._free = self._free, []
             self._cond.notify_all()
+        _KEPT.keep(idle)
 
 
 def _work_items(
@@ -489,10 +545,11 @@ def write_ec_files(
 
     A chunk is read ONCE, each block of the .dat straight to its place in
     a ``(k, width)`` matrix (`_read_item`: row ``i`` is the chunk's columns
-    of shard ``i``), into a buffer of a bounded per-call pool
-    (`_ChunkBuffers`). A buffer belongs to the reader until a chunk is
+    of shard ``i``), into one of the call's bounded share of the process's
+    chunk buffers (`_ChunkBuffers`: kept from the seal or rebuild before
+    where there was one). A buffer belongs to the reader until a chunk is
     read, then travels with the chunk through dispatch and fetch to the
-    writer, which returns it to the pool once the ten data rows are in the
+    writer, which returns it to the pool once the data rows are in the
     shard files. A chunk of zeros (a hole, or past EOF) takes no buffer.
     """
     codec = codec or get_codec()

@@ -65,6 +65,16 @@ def time_limit(request):
         signal.signal(signal.SIGALRM, old)
 
 
+@pytest.fixture
+def kept(monkeypatch):
+    """The process keeps no chunk buffer yet (`ec/encoder.py` `_KEPT`): the
+    next seal or rebuild is its first. Returns the list it keeps them in."""
+    from seaweedfs_tpu.ec import encoder
+
+    monkeypatch.setattr(encoder, "_KEPT", encoder._KeptBuffers())
+    return encoder._KEPT._idle
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

@@ -275,23 +275,26 @@ def old_read_item(f, item, k, dat_size):
     return np.ascontiguousarray(mat.reshape(k, g * block_size)), True
 
 
-def reference_shards(dat: bytes, large: int, small: int) -> list[bytes]:
-    """All 14 shards from the .dat in memory: the striping rule spelt out,
-    parity by NumpyCodec over whole shards."""
-    size = encoder.ec_shard_base_size(len(dat), K, large, small)
-    data = np.zeros((K, size), dtype=np.uint8)
+def reference_shards(dat: bytes, large: int, small: int,
+                     geometry=(K, 4)) -> list[bytes]:
+    """All shards (14 unless ``geometry`` says otherwise) from the .dat in
+    memory: the striping rule spelt out, parity by NumpyCodec over whole
+    shards."""
+    k = geometry[0]
+    size = encoder.ec_shard_base_size(len(dat), k, large, small)
+    data = np.zeros((k, size), dtype=np.uint8)
     src = np.frombuffer(dat, dtype=np.uint8)
     pos = out = 0
-    while len(dat) - pos > large * K:
-        for i in range(K):
+    while len(dat) - pos > large * k:
+        for i in range(k):
             data[i, out : out + large] = src[pos + i * large :][:large]
-        pos, out = pos + large * K, out + large
+        pos, out = pos + large * k, out + large
     while pos < len(dat):
-        for i in range(K):
+        for i in range(k):
             seg = src[pos + i * small :][:small]
             data[i, out : out + len(seg)] = seg
-        pos, out = pos + small * K, out + small
-    parity = NumpyCodec().encode(data)
+        pos, out = pos + small * k, out + small
+    parity = NumpyCodec().at(*geometry).encode(data)
     return [bytes(r) for r in data] + [bytes(r) for r in parity]
 
 
@@ -508,6 +511,94 @@ def test_an_error_in_any_leg_ends_a_call_whose_reader_waits_for_a_buffer(leg):
     t.join(timeout=20)
     assert not t.is_alive(), f"pipeline hung on an error in {leg}"
     assert result == [f"injected in {leg}"]
+
+
+# -- the buffers outlive the call: another volume's, geometry's, call's bytes --
+def taken(before: dict, after: dict, op: str) -> tuple[int, int]:
+    """Buffers ``op`` allocated and recycled between two snapshots."""
+    return tuple(
+        after.get(f"{op}.buf.{how}", {}).get("n", 0)
+        - before.get(f"{op}.buf.{how}", {}).get("n", 0)
+        for how in ("new", "wait"))
+
+
+@pytest.mark.parametrize("kind", sorted(CODECS))
+def test_other_geometries_and_a_shorter_volume_through_the_same_buffers(
+        tmp_path, kept, kind):
+    """Seal at 12+4, rebuild at 12+2+2 from six rows, then seal a SHORTER,
+    sparse .dat at 10+4: every call after the first finds the first one's
+    buffers, full of another volume's bytes in another shape, and none of
+    them leaves."""
+    from seaweedfs_tpu.ec.constants import Geometry
+    from seaweedfs_tpu.stats.trace import STAGES
+
+    one = CODECS[kind]()
+    large, chunk = 64 * BLK, 4 * BLK
+    snaps = [STAGES.snapshot()]
+
+    def sealed(name, runs, geometry, seed):
+        base = str(tmp_path / name)
+        image = write_dat(base + ".dat", runs, seed=seed)
+        encoder.write_ec_files(base, one.at(*geometry), large, BLK,
+                               chunk_bytes=chunk)
+        snaps.append(STAGES.snapshot())
+        want = reference_shards(image, large, BLK, geometry)
+        for sid, shard in enumerate(want):
+            with open(base + shard_ext(sid), "rb") as f:
+                assert f.read() == shard, f"{name}: shard {sid} differs"
+        return base, want
+
+    sealed("a", [("data", 14 * 12 * BLK + 777)], Geometry(12, 4), seed=1)
+    assert 1 <= taken(*snaps[-2:], "ec.seal")[0] <= encoder._POOL_BUFFERS
+    lrc = Geometry(12, 4, 2)
+    base, want = sealed("b", [("data", 7 * 12 * BLK + 5)], lrc, seed=2)
+    assert taken(*snaps[-2:], "ec.seal")[0] == 0
+    os.remove(base + shard_ext(4))
+    # 5000 is no multiple of the launch's alignment, nor of a block
+    assert encoder.rebuild_ec_files(base, one.at(*lrc), chunk_bytes=5000) == [4]
+    snaps.append(STAGES.snapshot())
+    new, recycled = taken(*snaps[-2:], "ec.rebuild")
+    assert new == 0 and recycled >= 3
+    # the local group's others: a (6, width) chunk
+    assert (snaps[-1]["ec.rebuild.plan"]["width"]
+            - snaps[-2].get("ec.rebuild.plan", {}).get("width", 0)) == 6
+    with open(base + shard_ext(4), "rb") as f:
+        assert f.read() == want[4]
+    # four rows a chunk: the second is one hole and takes no buffer
+    sealed("c", [("data", ROW + 77), ("hole", 7 * ROW), ("data", 2 * ROW + 5),
+                 ("hole", 3 * BLK)], Geometry(10, 4), seed=3)
+    assert taken(*snaps[-2:], "ec.seal") == (0, 2)
+    assert 1 <= len(kept) <= encoder._POOL_BUFFERS
+    assert {flat.nbytes for flat in kept} == {12 * chunk}
+
+
+def test_a_call_that_fails_leaves_the_kept_buffers_usable(tmp_path, kept):
+    """An error closes the call's pool while chunks are in flight: what
+    comes back afterwards is kept or freed, never lost to a waiting reader,
+    and the next call seals right."""
+    class FailsOnItsThirdLaunch(NumpyCodec):
+        launches = 0
+
+        def matmul_device(self, matrix, data):
+            self.launches += 1
+            if self.launches == 3:
+                raise RuntimeError("injected in dispatch")
+            return super().matmul_device(matrix, data)
+
+    base = str(tmp_path / "v")
+    image = write_dat(base + ".dat", [("data", 12 * ROW + 99)])
+    with pytest.raises(RuntimeError, match="injected in dispatch"):
+        encoder.write_ec_files(base, FailsOnItsThirdLaunch(), 64 * BLK, BLK,
+                               chunk_bytes=2 * BLK)
+    assert len(kept) <= encoder._POOL_BUFFERS
+    assert len({id(flat) for flat in kept}) == len(kept)
+    encoder.write_ec_files(base, NumpyCodec(), 64 * BLK, BLK,
+                           chunk_bytes=2 * BLK)
+    assert 1 <= len(kept) <= encoder._POOL_BUFFERS
+    want = reference_shards(image, 64 * BLK, BLK)
+    for sid in range(14):
+        with open(base + shard_ext(sid), "rb") as f:
+            assert f.read() == want[sid], f"shard {sid} differs"
 
 
 # -- the interface the encoder drives, on every class get_codec can return ----

@@ -11,8 +11,10 @@ import os
 import socket
 import subprocess
 import sys
+import threading
 import time
 import urllib.request
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -70,6 +72,9 @@ def sealed(request, tmp_path_factory):
     loaded, sealed through the shell's ``ec.encode`` with the stage table
     snapshotted around it, then shards 0, 4, 9, 12 deleted."""
     tmp = tmp_path_factory.mktemp("stages")
+    # the seal below is this process's first call: it keeps no buffer yet
+    unkept = pytest.MonkeyPatch()
+    unkept.setattr(encoder, "_KEPT", encoder._KeptBuffers())
     master = MasterServer(port=free_port(), node_timeout=30).start()
     vs = VolumeServer([str(tmp)], port=free_port(), master_url=master.url,
                       max_volume_count=4, pulse_seconds=0.3).start()
@@ -116,6 +121,7 @@ def sealed(request, tmp_path_factory):
     finally:
         vs.stop()
         master.stop()
+        unkept.undo()
 
 
 def test_one_seal_in_the_stage_table(sealed):
@@ -241,20 +247,35 @@ def test_a_degraded_get_is_one_tree_down_to_the_launch(sealed):
 
 def test_the_kill_switch_records_nothing_and_status_has_no_stages(
         sealed, monkeypatch):
+    # only what a GET of this volume can record is compared: the table and
+    # the ring are the process's, and any other thread's span that began
+    # before the switch was thrown (a heartbeat, another fixture's daemon)
+    # may still close while the GETs below run
+    def of_a_get(name: str) -> bool:
+        return name == "GET /" or name.startswith(
+            ("ec.read.", "ec.recover", "ec.codec."))
+
+    def table() -> dict:
+        return {n: row for n, row in STAGES.snapshot().items() if of_a_get(n)}
+
+    recovering_get(sealed)
+    assert table()  # with the switch off these GETs do record
     monkeypatch.setenv("SWEED_TRACE", "0")
-    # a handler's span closes AFTER its reply is on the wire, and heartbeats
-    # are requests too: let what began before the switch was thrown end
-    table, ring = None, None
-    while (table, ring) != (STAGES.snapshot(), RING.stats()["added"]):
-        table, ring = STAGES.snapshot(), RING.stats()["added"]
+    # a handler's span closes AFTER its reply is on the wire: let the GETs
+    # that began before the switch was thrown end
+    before = None
+    while before != table():
+        before = table()
         time.sleep(0.05)
+    thrown = time.time()
     for fid, want in list(sealed["blobs"].items())[:6]:
         with urllib.request.urlopen(
                 f"http://{sealed['address']}/{fid}") as resp:
             assert resp.read() == want  # degraded reads go on, unrecorded
             assert resp.headers.get("X-Sweed-Trace-Id") is None
-    assert STAGES.snapshot() == table
-    assert RING.stats()["added"] == ring
+    assert table() == before
+    assert not [s["name"] for s in RING.snapshot(RING.stats()["capacity"])
+                if of_a_get(s["name"]) and s["start"] >= thrown]
     served = http_json("GET", f"http://{sealed['address']}/status")
     assert "stages" not in served["ec_codec"]
     assert served["ec_codec"]["resolved"] is True
@@ -471,6 +492,7 @@ def test_a_seal_takes_one_buffer_a_chunk_that_carries_data(sealed):
     taken = (delta(b, a, "ec.seal.buf.new", "n")
              + delta(b, a, "ec.seal.buf.wait", "n"))
     assert taken == len(items)  # a dense volume: every chunk carries data
+    # the process's first call (the fixture saw to that): at most the depth
     assert 1 <= delta(b, a, "ec.seal.buf.new", "n") <= encoder._POOL_BUFFERS
     assert taken == delta(b, a, "ec.seal.read", "n")
     # the stages hang beside the read spans, not inside them
@@ -482,60 +504,82 @@ def test_a_seal_takes_one_buffer_a_chunk_that_carries_data(sealed):
                    for read in named(pipeline, "ec.seal.read"))
 
 
-def pooled_seal(tmp_path, monkeypatch, chunks: int, hole_chunks: int,
-                write_s: float = 0.0):
-    """Seal a volume of ``chunks`` chunks (the last ``hole_chunks`` of them
-    one hole) through the pipeline with a host codec; the stage table's
-    delta and the most buffers ever out."""
-    blk = 4096
-    base = str(tmp_path / "1")
-    rng = np.random.default_rng(25)
+BLK = 4096
+
+
+def sparse_dat(base: str, chunks: int, hole_chunks: int, seed: int = 25) -> None:
+    """A .dat of ``chunks`` chunks of two rows of ten 4 KiB blocks, the
+    last ``hole_chunks`` of them one hole."""
+    rng = np.random.default_rng(seed)
     with open(base + ".dat", "wb") as f:
-        f.write(rng.integers(1, 256, (chunks - hole_chunks) * 20 * blk,
+        f.write(rng.integers(1, 256, (chunks - hole_chunks) * 20 * BLK,
                              dtype=np.uint8).tobytes())
-        f.truncate(chunks * 20 * blk)
-    out = {"live": 0, "peak": 0}
+        f.truncate(chunks * 20 * BLK)
+
+
+@pytest.fixture()
+def out_of_the_pool(monkeypatch):
+    """Counts the buffers each call has out of its pool: ``pools`` is
+    ``{pool: [now, most ever]}``; ``write_s`` makes whoever gives one back
+    slow."""
+    out = SimpleNamespace(write_s=0.0, pools={})
     take, give = encoder._ChunkBuffers.take, encoder._ChunkBuffers.give
+    lock = threading.Lock()
 
     def counted_take(self, k, width):
         mat = take(self, k, width)
-        out["live"] += 1
-        out["peak"] = max(out["peak"], out["live"])
+        with lock:
+            live = out.pools.setdefault(self, [0, 0])
+            live[0] += 1
+            live[1] = max(live)
         return mat
 
     def counted_give(self, mat):
-        time.sleep(write_s)  # a slow writer: the reader runs out of buffers
-        out["live"] -= 1
+        time.sleep(out.write_s)  # a slow writer: the reader runs out
+        with lock:
+            out.pools[self][0] -= 1
         give(self, mat)
 
     monkeypatch.setattr(encoder._ChunkBuffers, "take", counted_take)
     monkeypatch.setattr(encoder._ChunkBuffers, "give", counted_give)
+    return out
+
+
+def pooled_seal(base: str, chunks: int, hole_chunks: int, rows: int = 2):
+    """Seal a volume of ``chunks`` two-row chunks (`sparse_dat`), ``rows``
+    rows a chunk, through the pipeline with a host codec; the stage
+    table's delta."""
+    sparse_dat(base, chunks, hole_chunks)
     codec = NumpyCodec()
-    _, items = encoder.plan_encode(codec, chunks * 20 * blk, 1 << 30, blk,
-                                   2 * blk)
-    assert len(items) == chunks and {it[0] for it in items} == {"rows"}
+    _, items = encoder.plan_encode(codec, chunks * 20 * BLK, 1 << 30, BLK,
+                                   rows * BLK)
+    assert len(items) == chunks * 2 // rows
+    assert {it[0] for it in items} == {"rows"}
     before = STAGES.snapshot()
-    encoder.write_ec_files(base, codec, 1 << 30, blk, chunk_bytes=2 * blk)
+    encoder.write_ec_files(base, codec, 1 << 30, BLK, chunk_bytes=rows * BLK)
     after = STAGES.snapshot()
-    return out, lambda stage, field: delta(before, after, stage, field)
+    return lambda stage, field: delta(before, after, stage, field)
 
 
 def test_the_pool_never_holds_more_than_its_size_and_recycles_the_rest(
-        tmp_path, monkeypatch):
-    out, d = pooled_seal(tmp_path, monkeypatch, chunks=14, hole_chunks=3)
+        tmp_path, kept, out_of_the_pool):
+    d = pooled_seal(str(tmp_path / "1"), chunks=14, hole_chunks=3)
     assert d("ec.seal.read", "n") == 14
     # a chunk that is one hole takes no buffer: 11 carry data
     assert d("ec.seal.buf.new", "n") + d("ec.seal.buf.wait", "n") == 11
     assert 1 <= d("ec.seal.buf.new", "n") <= encoder._POOL_BUFFERS
-    assert out["peak"] <= encoder._POOL_BUFFERS and out["live"] == 0
+    ((live, peak),) = out_of_the_pool.pools.values()
+    assert peak <= encoder._POOL_BUFFERS and live == 0
     assert d("ec.seal.buf.wait", "bytes") == (
         d("ec.seal.buf.wait", "n") * 10 * 2 * 4096)
+    # the call is over: what it allocated the process keeps
+    assert len(kept) == d("ec.seal.buf.new", "n")
 
 
-def test_the_wait_for_a_buffer_is_outside_the_read_stage(tmp_path, monkeypatch):
-    write_s = 0.03
-    out, d = pooled_seal(tmp_path, monkeypatch, chunks=12, hole_chunks=0,
-                         write_s=write_s)
+def test_the_wait_for_a_buffer_is_outside_the_read_stage(
+        tmp_path, kept, out_of_the_pool):
+    write_s = out_of_the_pool.write_s = 0.03
+    d = pooled_seal(str(tmp_path / "1"), chunks=12, hole_chunks=0)
     waits = d("ec.seal.buf.wait", "n")
     assert d("ec.seal.buf.new", "n") == encoder._POOL_BUFFERS
     assert waits == 12 - encoder._POOL_BUFFERS
@@ -543,24 +587,83 @@ def test_the_wait_for_a_buffer_is_outside_the_read_stage(tmp_path, monkeypatch):
     # about that long for each, and none of it is in ec.seal.read
     assert d("ec.seal.buf.wait", "busy_s") >= 0.7 * write_s * waits
     assert d("ec.seal.read", "busy_s") < 0.5 * d("ec.seal.buf.wait", "busy_s")
-    assert out["peak"] == encoder._POOL_BUFFERS
+    ((_, peak),) = out_of_the_pool.pools.values()
+    assert peak == encoder._POOL_BUFFERS
 
 
-def test_a_rebuild_recycles_its_buffers_at_the_fetch_leg(tmp_path):
-    blk = 4096
+def test_a_rebuild_recycles_its_buffers_at_the_fetch_leg(tmp_path, kept):
     base = str(tmp_path / "1")
-    rng = np.random.default_rng(26)
-    with open(base + ".dat", "wb") as f:
-        f.write(rng.integers(1, 256, 240 * blk, dtype=np.uint8).tobytes())
+    sparse_dat(base, 12, 0, seed=26)
     codec = NumpyCodec()
-    encoder.write_ec_files(base, codec, 1 << 30, blk, chunk_bytes=2 * blk)
+    first = STAGES.snapshot()
+    encoder.write_ec_files(base, codec, 1 << 30, BLK, chunk_bytes=2 * BLK)
     for sid in LOST:
         os.remove(base + shard_ext(sid))
     before = STAGES.snapshot()
-    encoder.rebuild_ec_files(base, codec, chunk_bytes=2 * blk)
+    encoder.rebuild_ec_files(base, codec, chunk_bytes=2 * BLK)
     after = STAGES.snapshot()
     chunks = delta(before, after, "ec.rebuild.read", "n")
     assert chunks == 12
-    new = delta(before, after, "ec.rebuild.buf.new", "n")
-    assert 1 <= new <= encoder._POOL_BUFFERS
-    assert new + delta(before, after, "ec.rebuild.buf.wait", "n") == chunks
+    # the process's first call allocates at most the depth; the next call
+    # of that size — a rebuild after a seal — allocates none
+    assert 1 <= delta(first, before, "ec.seal.buf.new", "n") <= (
+        encoder._POOL_BUFFERS)
+    assert delta(before, after, "ec.rebuild.buf.new", "n") == 0
+    assert delta(before, after, "ec.rebuild.buf.wait", "n") == chunks
+
+
+def test_a_second_seal_allocates_nothing(tmp_path, kept):
+    d = pooled_seal(str(tmp_path / "1"), chunks=12, hole_chunks=0)
+    made = d("ec.seal.buf.new", "n")
+    assert 1 <= made <= encoder._POOL_BUFFERS and len(kept) == made
+    buffers = {id(flat) for flat in kept}
+    d = pooled_seal(str(tmp_path / "2"), chunks=9, hole_chunks=2)
+    assert d("ec.seal.buf.new", "n") == 0
+    assert d("ec.seal.buf.wait", "n") == 7
+    assert {id(flat) for flat in kept} <= buffers  # the same, back again
+    # a kept buffer waited for nobody
+    assert d("ec.seal.buf.wait", "busy_s") < 0.5
+
+
+def test_a_kept_buffer_too_small_for_the_call_is_replaced(tmp_path, kept):
+    d = pooled_seal(str(tmp_path / "1"), chunks=12, hole_chunks=0)
+    small = {id(flat) for flat in kept}
+    assert small and {flat.nbytes for flat in kept} == {10 * 2 * BLK}
+    # chunks of four rows: no kept buffer holds one
+    d = pooled_seal(str(tmp_path / "2"), chunks=12, hole_chunks=0, rows=4)
+    assert 1 <= d("ec.seal.buf.new", "n") <= encoder._POOL_BUFFERS
+    assert d("ec.seal.buf.new", "bytes") == (
+        d("ec.seal.buf.new", "n") * 10 * 4 * BLK)
+    assert 1 <= len(kept) <= encoder._POOL_BUFFERS
+    assert {flat.nbytes for flat in kept} == {10 * 4 * BLK}
+    # and a smaller call fits what is kept now
+    d = pooled_seal(str(tmp_path / "3"), chunks=12, hole_chunks=0)
+    assert d("ec.seal.buf.new", "n") == 0
+    assert d("ec.seal.buf.wait", "bytes") == 12 * 10 * 4 * BLK
+
+
+def test_two_calls_at_once_are_each_bounded_and_share_the_kept_list(
+        tmp_path, kept, out_of_the_pool):
+    out_of_the_pool.write_s = 0.005
+    errors = []
+
+    def seal(name):
+        try:
+            pooled_seal(str(tmp_path / name), chunks=12, hole_chunks=0)
+        except BaseException as e:
+            errors.append(e)
+
+    for _ in range(2):  # the second pair finds kept buffers, and too few
+        threads = [threading.Thread(target=seal, args=(str(i),))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads) and not errors
+        assert 1 <= len(kept) <= encoder._POOL_BUFFERS
+    pools = list(out_of_the_pool.pools.values())
+    assert len(pools) == 6
+    assert all(live == 0 and 1 <= peak <= encoder._POOL_BUFFERS
+               for live, peak in pools)
+    assert len({id(flat) for flat in kept}) == len(kept)
