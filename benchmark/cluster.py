@@ -15,43 +15,48 @@ trace it), and ``keep_trace`` moves its directory to where ``run.py`` reduces.
 
 Ports: a daemon binds its own port, many seconds after it was started (a
 volume server opens its chip first), so a port picked here can be gone by
-then. They are drawn below the kernel's ephemeral range, where no outgoing
-connection lands, and a daemon that exits on ``Address already in use`` is
-started again on another port. Every process is a session of its own and
+then. They are drawn below every ephemeral range the benchmark has met
+(``daemon.pick_port``), where no outgoing connection and no ``bind`` to
+port 0 lands. A daemon that exits
+before it serves, on ``Address already in use`` or on anything else, is
+started again on other ports, ``START_TRIES`` times in all, and each such
+start says so on a ``[retry]`` line: a machine without its chips ends the
+run with no result all the same, four starts later. What this side asks of
+a daemon that is up (``/status``, ``/dir/status``) is asked again where the
+answer does not come (``asked``). Every process is a session of its own and
 is killed by group when the fixture leaves, however it leaves.
 """
 
 from __future__ import annotations
 
 import contextlib
+import http.client
 import os
-import random
 import signal
-import socket
 import subprocess
 import sys
 import time
 from concurrent.futures import ThreadPoolExecutor
 
-from .daemon import HERE, ROOT, get_json, post_json
+from .daemon import PORTS  # noqa: F401  (the range, under this module's name too)
+from .daemon import HERE, ROOT, get_json, pick_port, post_json
 
-PORTS = (20000, 32000)  # under net.ipv4.ip_local_port_range's 32768
 START_TRIES = 4
-_rng = random.Random()  # seeded by the OS: two harnesses draw apart
+ASK_TRIES = 3
 
 
-def pick_port() -> int:
-    """A port of ``PORTS`` nothing listens on right now."""
-    for _ in range(200):
-        port = _rng.randrange(*PORTS)
-        with socket.socket() as s:
-            s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-            try:
-                s.bind(("127.0.0.1", port))
-            except OSError:
-                continue
-            return port
-    raise SystemExit(f"no free port in {PORTS}")
+def asked(what: str, ask):
+    """``ask()``, asked again (``ASK_TRIES`` in all, a second apart) where
+    no answer comes: a daemon that stood still for some seconds with its
+    machine is late, not gone. The last failure is the caller's."""
+    for left in range(ASK_TRIES - 1, -1, -1):
+        try:
+            return ask()
+        except (OSError, http.client.HTTPException, ValueError) as e:
+            if not left:
+                raise
+            print(f"[retry] {what}: {e!r}; asked again", flush=True)
+            time.sleep(1.0)
 
 
 def answers(path: str):
@@ -79,7 +84,7 @@ class Process:
 
     def start(self, env: dict, timeout: float = 240.0) -> "Process":
         t0 = time.monotonic()
-        for _ in range(START_TRIES):
+        for tried in range(START_TRIES):
             self.port = pick_port()
             cmd = self._command(self.port)
             with open(self.log_path, "ab") as log:
@@ -92,8 +97,9 @@ class Process:
             if self._wait(t0 + timeout):
                 self.start_wall_s = time.monotonic() - t0
                 return self
-            if "Address already in use" not in self.log_tail(40):
-                break
+            print(f"[retry] {self.name} exited with {self.proc.returncode} "
+                  f"before it served, start {tried + 1} of {START_TRIES}: "
+                  + " | ".join(self.log_tail(3).splitlines()), flush=True)
         raise SystemExit(
             f"{self.name} exited with {self.proc.returncode} before it "
             f"served:\n{self.log_tail()}"
@@ -152,8 +158,7 @@ class Cluster:
         self.control, self.rehearsal = control, rehearsal
         self.trace_dir = trace_dir  # "" = an untraced run
         self.n = cluster_cfg["volume_servers"]
-        self.control_ports = [pick_port() if trace_dir else 0
-                              for _ in range(self.n)]
+        self.control_ports = [0] * self.n  # drawn with each start
         self.dirs = [os.path.join(data_root, f"srv{i}") for i in range(self.n)]
         for d in (*self.dirs, out_dir):
             os.makedirs(d, exist_ok=True)
@@ -190,6 +195,7 @@ class Cluster:
             return [sys.executable, "-m", "seaweedfs_tpu", *server]
         wrap = [sys.executable, os.path.join(HERE, "daemon_main.py")]
         if self.trace_dir:
+            self.control_ports[i] = pick_port()
             wrap += ["--trace-dir", f"{self.trace_dir}-srv{i}",
                      "--control-port", str(self.control_ports[i])]
         if self.control:
@@ -228,7 +234,8 @@ class Cluster:
 
     def registered(self) -> set[str]:
         """The volume servers the master's topology lists now."""
-        topo = get_json(f"http://{self.master}/dir/status", timeout=5.0)["topology"]
+        topo = asked("the master's /dir/status", lambda: get_json(
+            f"http://{self.master}/dir/status", timeout=5.0))["topology"]
         return {
             node["url"]
             for dc in topo.get("data_centers", [])
@@ -246,9 +253,9 @@ class Cluster:
 
     # -- one server ---------------------------------------------------------------
     def codec(self, i: int) -> dict:
-        return get_json(
+        return asked(f"volume server {i}'s /status", lambda: get_json(
             f"http://{self.servers[i].url}/status", timeout=10.0
-        )["ec_codec"]
+        ))["ec_codec"]
 
     def profiler(self, i: int, verb: str) -> dict:
         """start / stop the profiler inside server ``i``."""

@@ -11,10 +11,12 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import random
 import signal
 import socket
 import subprocess
 import sys
+import threading
 import time
 import urllib.request
 
@@ -22,10 +24,34 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 
-def free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+# A daemon binds its ports many seconds after they were drawn (it opens its
+# chip first), so they are drawn where nothing else lands meanwhile: under
+# every ephemeral range the benchmark has met (Linux's begins at 32768, the
+# chip machines' gVisor's at 16000: outgoing connections and binds to port
+# 0), clear of libtpu's 8476..8479 (``-ec.chip``)
+PORTS = (10000, 15900)
+_rng = random.Random()  # seeded by the OS: two harnesses draw apart
+_drawn: set[int] = set()
+_drawing = threading.Lock()  # a cluster's servers are started side by side
+
+
+def pick_port() -> int:
+    """A port of ``PORTS`` nothing listens on right now and this run has
+    not drawn before: a port drawn is bound only many seconds later."""
+    with _drawing:
+        for _ in range(200):
+            port = _rng.randrange(*PORTS)
+            if port in _drawn:
+                continue
+            with socket.socket() as s:
+                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+                try:
+                    s.bind(("127.0.0.1", port))
+                except OSError:
+                    continue
+            _drawn.add(port)
+            return port
+    raise SystemExit(f"no free port in {PORTS}")
 
 
 def get_json(url: str, timeout: float = 30.0, method: str = "GET") -> dict:
@@ -47,9 +73,9 @@ class Daemon:
         self.cfg = daemon_cfg
         self.trace, self.control, self.rehearsal = trace, control, rehearsal
         self.trace_dir = trace_dir
-        self.master = f"127.0.0.1:{free_port()}"
-        self.volume = f"127.0.0.1:{free_port()}"
-        self.control_port = free_port() if trace else 0
+        self.master = f"127.0.0.1:{pick_port()}"
+        self.volume = f"127.0.0.1:{pick_port()}"
+        self.control_port = pick_port() if trace else 0
         self.proc: subprocess.Popen | None = None
         self.start_wall_s = 0.0
 

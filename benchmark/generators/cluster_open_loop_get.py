@@ -14,6 +14,13 @@ The window is ``open_loop_get``'s — Poisson arrivals at the mix's fixed
 rate, the same stratified sizes for every seed, each GET clocked from when
 it was DUE — with one more draw from the seed: the surviving server each
 GET is sent to, a client that looked the volume up and picked a holder.
+
+A set-up is thirty operations over five processes and takes a minute; where
+one of them fails — a wait that ran out, a daemon that did not answer, a
+step of the seal that raised — the whole set-up is made once more, from an
+empty cluster, and the run says so (``[retry]``; ``setup_s`` counts both).
+Nothing of the window is ever made twice, and a machine without its chips
+(``SystemExit``) ends the run at once.
 """
 
 from __future__ import annotations
@@ -22,6 +29,7 @@ import hashlib
 import http.client
 import json
 import os
+import shutil
 import threading
 import time
 import urllib.error
@@ -381,20 +389,43 @@ def compare(run: Run, state: dict, failed: int, before: list[dict],
                 int(remote_ok <= 0))
 
 
+SETUP_TRIES = 2
+
+
 def run_cell(run: Run) -> dict:
-    with cluster_of(run) as cluster:
-        return measure(run, prepare(run, cluster))
+    for left in range(SETUP_TRIES - 1, -1, -1):
+        with cluster_of(run) as cluster:
+            try:
+                state = prepare(run, cluster)
+                state["requests"] = requests(run, state)
+            except Exception as e:  # not SystemExit: no chip, no result
+                if not left:
+                    raise
+                say(f"[retry] the set-up failed after "
+                    f"{run.setup_seconds():.1f} s: {e!r}; made once more "
+                    "from an empty cluster")
+            else:
+                return measure(run, state)
+        shutil.rmtree(run.data_dir, ignore_errors=True)
+        os.makedirs(run.data_dir)
+
+
+def requests(run: Run, state: dict) -> tuple:
+    """The window's requests (needle, due time, survivor: the seed's), and
+    every survivor warmed for them: set-up's last step."""
+    n = max(1, round(run.mix["rate_get_per_s"] * run.args.seconds))
+    picked = request_list(run.loaded, n, run.seed)
+    due = arrivals(n, run.args.seconds, run.seed)
+    to = targets(n, len(state["survivors"]), run.seed)
+    return (picked, due, to, *warm(run, state, picked))
 
 
 def measure(run: Run, state: dict) -> dict:
     mix, seconds = run.mix, run.args.seconds
     cluster: Cluster = state["cluster"]
     survivors = state["survivors"]
-    n = max(1, round(mix["rate_get_per_s"] * seconds))
-    picked = request_list(run.loaded, n, run.seed)
-    due = arrivals(n, seconds, run.seed)
-    to = targets(n, len(survivors), run.seed)
-    shapes, warm_failed = warm(run, state, picked)
+    picked, due, to, shapes, warm_failed = state["requests"]
+    n = len(picked)
     before = [cluster.codec(i) for i in survivors]
     require_chips(run, before)
     setup_s = run.setup_seconds()

@@ -18,6 +18,30 @@ def bench() -> dict:
         return json.load(f)
 
 
+def maintain_cells() -> list[str]:
+    """Every cell whose traffic file's ``kind`` starts with
+    ``maintain-cycle``, in BENCHMARK.json's order: the cells that seal and
+    rebuild, whatever later PRs add."""
+    cells = []
+    for cell in bench()["workloads"]:
+        with open(os.path.join(ROOT, "benchmark", "traffic",
+                               cell["traffic"] + ".json")) as f:
+            if json.load(f)["kind"].startswith("maintain-cycle"):
+                cells.append(cell["name"])
+    return cells
+
+
+def stage_ctx(before: dict, after: dict, gets: int = 40) -> dict:
+    """A reader's context around two hand-built ``/status`` snapshots (each
+    ``{"stages": {...}}``, or ``{}`` for a daemon that serves no table)."""
+    codec = {"compiles": {"requests": 0}, "launches": {}}
+    return {
+        "trace": None, "cell": "x.y", "device_kind": "TPU v5 lite",
+        "client": {"gets": [{}] * gets},
+        "status": {"before": dict(codec, **before), "after": dict(codec, **after)},
+    }
+
+
 def run_cell(workload: str, seed: int, *extra: str, root: str = ROOT,
              seconds: float = 1.5, trace: int = 0, rehearsal: bool = True):
     """(exit code, the last line of stdout parsed or None, all output)."""

@@ -6,7 +6,7 @@ rehearsed against what a degraded read does now are cases of
 
 import pytest
 
-from bench_util import bench
+from bench_util import bench, stage_ctx as ctx_with
 
 from benchmark import layers
 
@@ -19,25 +19,18 @@ AFTER = {"ec.read.remote": {"n": 150, "busy_s": 1.5, "failed": 0, "absent": 76,
          "ec.recover": {"n": 20, "busy_s": 1.4}}
 
 
-def ctx_with(before, after, gets=40):
-    codec = {"compiles": {"requests": 0}, "launches": {}}
-    return {
-        "trace": None, "cell": "x.y", "device_kind": "TPU v5 lite",
-        "client": {"gets": [{}] * gets},
-        "status": {"before": dict(codec, **before), "after": dict(codec, **after)},
-    }
-
-
 def test_the_metric_is_declared_as_the_issue_names_it():
     entry = [m for m in bench()["per_layer"] if m["name"] == NAME]
-    assert entry == [bench()["per_layer"][-1]]  # appended, nothing moved
+    assert len(entry) == 1  # declared once, wherever later entries stand
     # since ISSUE 31 the read cells' end-to-end tail is the 90th percentile,
     # and warm1.read-degraded reports none: a metric read in all three read
     # cells names the latency all three report
+    # the three that were there; a later read cell joins by being appended
+    assert entry[0].pop("workloads")[:3] == READ_CELLS
     assert entry[0] == {
         "name": NAME, "unit": "count", "better": "higher",
         "source": "program_span", "layer": "store / commit",
-        "moves": "get_p50_ms", "workloads": READ_CELLS,
+        "moves": "get_p50_ms",
     }
     reader = layers.load_reader(NAME)
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (

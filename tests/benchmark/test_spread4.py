@@ -84,22 +84,37 @@ def test_payload_reads_a_version_3_record():
 
 # -- the cluster fixture ----------------------------------------------------------------
 def test_ports_are_drawn_below_the_ephemeral_range():
-    ports = {cluster.pick_port() for _ in range(20)}
+    drawn = [cluster.pick_port() for _ in range(300)]
+    ports = set(drawn)
     assert all(cluster.PORTS[0] <= p < cluster.PORTS[1] for p in ports)
+    # nine ports a traced run, each bound many seconds after it was drawn:
+    # none is drawn twice (300 of 5,900 would else meet with p > 0.999)
+    assert len(ports) == len(drawn)
     with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
         assert cluster.PORTS[1] <= int(f.read().split()[0])
+    # the chip machines' kernel (gVisor) hands out 16000..65535: a volume
+    # server's inner port read 16429 there (PERF.md, PR 38, third session)
+    assert cluster.PORTS[1] <= 16000
+    # and libtpu's own, one a claimed chip (jaxenv: 8476 + chip)
+    assert cluster.PORTS[0] > 8476 + 8
 
 
-def test_a_daemon_that_lost_its_port_is_started_again_on_another(tmp_path):
-    """The first start prints what a lost port prints and exits; the fixture
-    picks another port instead of losing the run."""
+@pytest.mark.parametrize("last_words", [
+    "OSError: [Errno 98] Address already in use",
+    "RuntimeError: the chip did not open",
+])
+def test_a_daemon_that_exited_before_it_served_is_started_again_on_another_port(
+        tmp_path, last_words, capsys):
+    """The first start prints what a lost port (or anything else) prints
+    and exits; the fixture picks another port instead of losing the run,
+    and says so."""
     script = tmp_path / "daemon.py"
     script.write_text(textwrap.dedent("""
         import http.server, os, sys
         port, mark = int(sys.argv[1]), sys.argv[2]
         if not os.path.exists(mark):
             open(mark, "w").write(str(port))
-            print("OSError: [Errno 98] Address already in use", flush=True)
+            print(sys.argv[3], flush=True)
             sys.exit(1)
         class H(http.server.BaseHTTPRequestHandler):
             def do_GET(self):
@@ -111,7 +126,7 @@ def test_a_daemon_that_lost_its_port_is_started_again_on_another(tmp_path):
     mark = str(tmp_path / "first")
     p = cluster.Process(
         "toy daemon", str(tmp_path / "toy.log"),
-        lambda port: [sys.executable, str(script), str(port), mark],
+        lambda port: [sys.executable, str(script), str(port), mark, last_words],
         lambda p: cluster.get_json(f"http://{p.url}/", timeout=2.0) == {},
     )
     try:
@@ -122,6 +137,9 @@ def test_a_daemon_that_lost_its_port_is_started_again_on_another(tmp_path):
     finally:
         p.stop(grace_s=0.5)
     assert not p.alive()
+    said = capsys.readouterr().out
+    assert said.count("[retry] toy daemon exited with 1") == 1
+    assert last_words in said and "start 1 of 4" in said
     broken = cluster.Process(
         "broken daemon", str(tmp_path / "broken.log"),
         lambda port: [sys.executable, "-c", "import sys; sys.exit(3)"],
@@ -129,6 +147,99 @@ def test_a_daemon_that_lost_its_port_is_started_again_on_another(tmp_path):
     )
     with pytest.raises(SystemExit, match="exited with 3"):
         broken.start(dict(os.environ), timeout=30)
+    # a machine without its chips: every start was made, none served
+    assert capsys.readouterr().out.count("[retry]") == cluster.START_TRIES
+
+
+def test_a_traced_server_draws_its_control_port_with_each_start(tmp_path):
+    """A control port is bound twenty seconds after it was drawn, as the
+    server's own is: a start made again asks for another."""
+    cfg = {"volume_servers": 2, "master": {}, "volume": {}}
+    c = cluster.Cluster(str(tmp_path / "data"), str(tmp_path / "out"), cfg,
+                        trace_dir=str(tmp_path / "trace"))
+    first = c._volume_command(1, 12345)
+    at = first.index("--control-port") + 1
+    assert cluster.PORTS[0] <= c.control_ports[1] < cluster.PORTS[1]
+    assert first[at] == str(c.control_ports[1]) and c.control_ports[0] == 0
+    assert len({c._volume_command(1, 12345)[at] for _ in range(6)}) > 1
+    plain = cluster.Cluster(str(tmp_path / "data"), str(tmp_path / "out"), cfg)
+    assert "--control-port" not in plain._volume_command(0, 12345)
+
+
+@pytest.mark.parametrize("failures,want", [(0, "answer"), (2, "answer"), (3, None)])
+def test_what_is_asked_of_a_daemon_is_asked_again_twice_and_no_more(
+        failures, want, monkeypatch, capsys):
+    monkeypatch.setattr(cluster.time, "sleep", lambda s: None)
+    calls = []
+
+    def ask():
+        calls.append(1)
+        if len(calls) <= failures:
+            raise TimeoutError("timed out")
+        return "answer"
+
+    if want is None:
+        with pytest.raises(TimeoutError):
+            cluster.asked("server 0's /status", ask)
+    else:
+        assert cluster.asked("server 0's /status", ask) == want
+    assert len(calls) == min(failures + 1, cluster.ASK_TRIES)
+    assert capsys.readouterr().out.count("[retry] server 0's /status") == min(
+        failures, cluster.ASK_TRIES - 1)
+
+
+class _NoCluster:
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("faults,ends", [
+    ([], "measured"),
+    ([RuntimeError("the master never listed 10 shards")], "measured"),
+    ([OSError("timed out"), RuntimeError("again")], RuntimeError),
+    ([SystemExit("server 2: asked for tpu on a TPU, got cpu")], SystemExit),
+])
+def test_a_set_up_that_failed_is_made_once_more_and_a_window_never_twice(
+        faults, ends, tmp_path, monkeypatch, capsys):
+    from types import SimpleNamespace
+
+    from benchmark.generators import cluster_open_loop_get as gen
+
+    data = tmp_path / "data"
+    data.mkdir()
+    run = SimpleNamespace(data_dir=str(data), setup_seconds=lambda: 1.0)
+    left, made = list(faults), {"set-ups": 0, "windows": 0}
+
+    def prepare(run, cluster):
+        made["set-ups"] += 1
+        assert os.listdir(run.data_dir) == []  # from an empty cluster
+        (data / "srv0").mkdir()
+        if left:
+            raise left.pop(0)
+        return {}
+
+    def measure(run, state):
+        made["windows"] += 1
+        assert state["requests"] == "the seed's"
+        return "measured"
+
+    monkeypatch.setattr(gen, "cluster_of", lambda run: _NoCluster())
+    monkeypatch.setattr(gen, "prepare", prepare)
+    monkeypatch.setattr(gen, "requests", lambda run, state: "the seed's")
+    monkeypatch.setattr(gen, "measure", measure)
+    if isinstance(ends, str):
+        assert gen.run_cell(run) == ends
+    else:
+        with pytest.raises(ends):
+            gen.run_cell(run)
+    retried = capsys.readouterr().out.count("[retry] the set-up failed")
+    assert made["windows"] == (1 if ends == "measured" else 0)
+    assert made["set-ups"] == 1 + retried
+    assert retried == min(sum(isinstance(f, Exception) for f in faults),
+                          gen.SETUP_TRIES - 1)
 
 
 # -- the cell's command on the CPU --------------------------------------------------------
@@ -181,9 +292,18 @@ def test_traced_rehearsal_reads_the_remote_path(held, traced_rehearsal):
         assert re.search(r"^\[trace\] server \d .* is the one traced", out, re.M)
         assert m["store.remote_ok_per_get"] > 0  # the remote path did the work
         assert 6 <= m["store.recover_remote_siblings"] <= 7
+        # the asks a recovery started side by side (both are counts, so a
+        # rehearsal prints both): every sibling, as on the chip and on an
+        # idle CPU (1.00 of them); 0.85-0.91 where the machine is so loaded
+        # that recoveries overlap under the interpreted decode and one makes
+        # some asks itself; never more. A program fallen back to one ask at
+        # a time reads 1 of 6.7 or less, 0.15 (PERF.md section 6, PR 38)
+        siblings = m["store.recover_remote_siblings"]
+        assert 0.75 * siblings <= m["store.recover_fanout_width"] <= (
+            siblings * (1 + 1e-9))
         assert m["codec.compiled_in_window.reads"] == 0
         for name in ("store.remote_read_ms", "peer.shard_serve_ms",
-                     "cluster.get_share_max", "store.degraded_remote_ms",
+                     "cluster.get_share_max", "store.recover_fanout_ms",
                      "store.recovering_get_p50_ms", "codec.launch_ms"):
             assert f"[layer] {name}: read" in out, out[-3000:]
             assert name not in line["metrics"]  # a rehearsal prints counts only

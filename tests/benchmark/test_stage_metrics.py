@@ -6,6 +6,7 @@ serves no table, and the readers on a rehearsal of the cells that list them."""
 import pytest
 
 from bench_util import assert_contract_line, bench, run_cell
+from bench_util import stage_ctx as ctx_with
 
 from benchmark import layers, stages
 
@@ -49,21 +50,11 @@ WANT = {
     "link.d2h_rate": 2.0 / 0.5,
     "store.seal_hash_share": 100 * 2.5 / 10.0,
     "store.seal_commit_share": 100 * 1.5 / 10.0,
-    "store.degraded_remote_ms": 1000 * 2.0 / 10,
     "store.degraded_decode_ms": 1000 * 0.05 / 10,
     "codec.launch_ms": 1000 * 0.04 / 10,
     "store.remote_failed_per_get": 120 / 40,
 }
 NEW = [m for m in bench()["per_layer"] if m["name"] in WANT]
-
-
-def ctx_with(before, after):
-    codec = {"compiles": {"requests": 0}, "launches": {}}
-    return {
-        "trace": None, "cell": "x.y", "device_kind": "TPU v5 lite",
-        "client": {"gets": [{}] * 40},
-        "status": {"before": dict(codec, **before), "after": dict(codec, **after)},
-    }
 
 
 def test_every_metric_of_the_stage_table_is_in_benchmark_json():
@@ -146,8 +137,7 @@ def test_rehearsed_read_cell_counts_asks_for_shards_nobody_holds(
     absent = line["metrics"]["store.remote_absent_per_get"]
     assert absent["unit"] == "count"
     assert absent["value"] == pytest.approx(asks * launches)
-    for name in ("store.degraded_remote_ms", "store.degraded_decode_ms",
-                 "codec.launch_ms"):  # a number still, with no failed ask in it
+    for name in ("store.degraded_decode_ms", "codec.launch_ms"):  # numbers
         assert f"[layer] {name}: read" in out, out[-3000:]
         assert name not in line["metrics"]  # a rehearsal prints counts only
 
@@ -168,3 +158,14 @@ def test_rehearsed_maintain_cell_reads_the_pipelines_legs_and_the_link():
         assert name not in line["metrics"]
     assert line["metrics"]["client.stalled_ops"]["unit"] == "count"
     assert "client.seal_rate_total" not in out
+    # the spans ISSUE 38 gave their readers: a rebuild at RS(10,4) reads ten
+    # shards (a count, so a rehearsal prints it), and its two other legs, its
+    # link and the seal's pipeline and commit are read from the same table
+    assert line["metrics"]["encoder.rebuild_shards_read"] == {
+        "value": 10, "unit": "count"}
+    for name in ("encoder.rebuild_stage_busy.dispatch",
+                 "encoder.rebuild_stage_busy.fetch", "link.rebuild_h2d_rate",
+                 "link.rebuild_d2h_rate", "encoder.seal_pipeline_rate",
+                 "store.seal_commit_ms"):
+        assert f"[layer] {name}: read" in out, out[-3000:]
+        assert name not in line["metrics"]

@@ -13,7 +13,7 @@ import sys
 import numpy as np
 import pytest
 
-from bench_util import ROOT, bench
+from bench_util import ROOT, bench, maintain_cells
 
 from benchmark import fixture, kernel_model, layers, reference, stats, trace_reduce
 from benchmark.generators import open_loop_get
@@ -305,7 +305,8 @@ def test_every_tail_is_read_and_one_with_fewer_than_ten_beyond_is_left_out():
     assert p90.read({"client": {"gets": gets}}) == pytest.approx(180.0)
     assert p90.read({"client": {"gets": gets[:90]}}) is None  # 9 beyond
     entry = next(m for m in BENCH["per_layer"] if m["name"] == "client.get_p90_ms")
-    assert entry["workloads"] == ["warm1.read-degraded"]
+    assert entry["workloads"][0] == "warm1.read-degraded"
+    assert not set(entry["workloads"]) & set(tail["workloads"])
     assert reader.MOVES == p90.MOVES == entry["moves"] == "get_p50_ms"
 
 
@@ -533,12 +534,17 @@ def test_end_to_end_metric_has_a_bound_and_cells_that_report_it(metric):
         assert "workloads" not in metric and metric["bound"] == 0.25
         return
     assert metric["workloads"] and set(metric["workloads"]) <= set(cells)
-    # the rates are the maintain cells', the latencies the read cells';
-    # the tail only where its runs hold half a bound (PERF.md section 2)
-    kind = "maintain" if metric["name"].endswith("_rate") else "read-"
-    want = [c for c in cells if "." + kind in c]
+    # the rates are the maintain cells', the latencies the read cells' (one
+    # rule, the traffic file's kind, decides it here and per layer); the
+    # tail only where its runs hold half a bound (PERF.md section 2): a
+    # read cell without it carries the same percentile per layer
+    want = maintain_cells()
+    if not metric["name"].endswith("_rate"):
+        want = [c for c in cells if c not in want]
     if metric["name"] == "get_p90_ms":
-        want.remove("warm1.read-degraded")
+        (beside,) = [m for m in BENCH["per_layer"]
+                     if m["name"] == "client.get_p90_ms"]
+        want = [c for c in want if c not in beside["workloads"]]
     assert metric["workloads"] == want
 
 
@@ -548,7 +554,8 @@ def test_the_medians_and_the_stalled_count_stand_beside_the_rates():
     assert names[at:at + 3] == ["client.seal_rate_p50",
                                 "client.rebuild_rate_p50", "client.stalled_ops"]
     for m in BENCH["per_layer"][at:at + 3]:
-        assert m["workloads"] == ["warm1.maintain", "mesh4.maintain"]
+        # every cell that seals and rebuilds has the client's three readings
+        assert m["workloads"] == maintain_cells()
         assert layers.load_reader(m["name"]).MOVES == m["moves"]
     # a rate is over all of a window's operations: no reader repeats it
     for name in ("client.seal_rate_total", "client.rebuild_rate_total"):
