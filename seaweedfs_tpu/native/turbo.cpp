@@ -512,6 +512,10 @@ struct Engine {
 
   // counters for /metrics merge
   std::atomic<uint64_t> n_get{0}, n_post{0}, n_delete{0}, n_proxy{0};
+  // the proxied requests' wall, from the H_PROXY decision to the last byte
+  // sent to the client (or the failure), and the connects' share of it:
+  // the serve.proxy row of /status (docs/OBSERVABILITY.md)
+  std::atomic<uint64_t> proxy_ns{0}, proxy_connect_ns{0};
 
   std::shared_ptr<Vol> get_vol(uint32_t vid) {
     std::shared_lock<std::shared_mutex> lk(reg_mu);
@@ -940,25 +944,54 @@ static bool send_502(int cfd, const char* msg) {
   return send_all_blocking(cfd, b, blen);
 }
 
-// Blocking proxy, runs in its own detached thread with its own backend
-// connection.  Returns true if the client connection is still usable.
-static bool proxy_blocking(Engine* e, int cfd, const std::string& raw,
-                           bool is_head) {
-  e->n_proxy++;
+static uint64_t mono_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (uint64_t)ts.tv_sec * 1000000000ull + ts.tv_nsec;
+}
+
+// One proxied request over its own backend connection.  Returns true if
+// the client connection is still usable.
+static bool proxy_forward(Engine* e, int cfd, const std::string& raw,
+                          bool is_head, uint64_t t0_ns) {
+  uint64_t c0 = mono_ns();
   int bfd = backend_connect(e);
+  e->proxy_connect_ns += mono_ns() - c0;
   if (bfd < 0) return send_502(cfd, "{\"error\": \"backend unreachable\"}");
   bool client_ok = true;
   bool done = false;
-  // forward raw request bytes
-  size_t off = 0;
-  while (off < raw.size()) {
-    ssize_t n = send(bfd, raw.data() + off, raw.size() - off, MSG_NOSIGNAL);
+  // forward the raw request bytes, unchanged apart from one header after
+  // the request line: X-Sweed-Proxy-T0, this thread's clock at t0 (the
+  // Python core is a thread of this process and reads the same clock)
+  size_t eol = raw.find("\r\n");
+  size_t cut = eol == std::string::npos ? 0 : eol + 2;
+  std::string head = raw.substr(0, cut);
+  if (cut) {
+    char stamp[64];
+    snprintf(stamp, sizeof(stamp), "X-Sweed-Proxy-T0: %llu\r\n",
+             (unsigned long long)t0_ns);
+    head += stamp;
+  }
+  // one sendmsg, so the backend's loop wakes once with the whole head
+  struct iovec iov[2] = {
+      {(void*)head.data(), head.size()},
+      {(void*)(raw.data() + cut), raw.size() - cut}};
+  for (int at = 0; !done && at < 2;) {
+    struct msghdr m {};
+    m.msg_iov = iov + at;
+    m.msg_iovlen = 2 - at;
+    ssize_t n = sendmsg(bfd, &m, MSG_NOSIGNAL);
     if (n <= 0) {
       client_ok = send_502(cfd, "{\"error\": \"backend send failed\"}");
       done = true;
       break;
     }
-    off += n;
+    size_t sent = n;
+    while (at < 2 && sent >= iov[at].iov_len) sent -= iov[at++].iov_len;
+    if (at < 2) {
+      iov[at].iov_base = (char*)iov[at].iov_base + sent;
+      iov[at].iov_len -= sent;
+    }
   }
   std::string rh;
   char buf[65536];
@@ -1024,6 +1057,17 @@ static bool proxy_blocking(Engine* e, int cfd, const std::string& raw,
     }
   }
   close(bfd);
+  return client_ok;
+}
+
+// Blocking proxy, runs in its own detached thread.  ``t0_ns`` is
+// CLOCK_MONOTONIC at the H_PROXY decision: the start of the request's time
+// in the proxy, and the stamp the backend is sent.
+static bool proxy_blocking(Engine* e, int cfd, const std::string& raw,
+                           bool is_head, uint64_t t0_ns) {
+  e->n_proxy++;
+  bool client_ok = proxy_forward(e, cfd, raw, is_head, t0_ns);
+  e->proxy_ns += mono_ns() - t0_ns;
   return client_ok;
 }
 
@@ -1570,6 +1614,7 @@ static bool process_requests(Worker* w, Conn* c) {
     HandleResult hr = handle_one(w, c, r2, raw);
     if (hr == H_DROP) return false;
     if (hr == H_PROXY) {
+      uint64_t t0_ns = mono_ns();
       // hand the connection to a proxy thread; the epoll loop forgets the
       // fd until the completion queue returns it (re-entrant backend
       // requests to this port keep being served meanwhile)
@@ -1578,8 +1623,8 @@ static bool process_requests(Worker* w, Conn* c) {
       w->inflight++;
       Engine* e = w->eng;
       bool is_head = ieq(r2.method, r2.method_len, "HEAD");
-      std::thread([w, e, c, raw, is_head] {
-        bool ok = proxy_blocking(e, c->fd, raw, is_head);
+      std::thread([w, e, c, raw, is_head, t0_ns] {
+        bool ok = proxy_blocking(e, c->fd, raw, is_head, t0_ns);
         {
           std::lock_guard<std::mutex> lk(w->done_mu);
           w->done.emplace_back(c, ok && !c->close_after);
@@ -2013,7 +2058,8 @@ int turbo_sync(long long handle, unsigned vid) {
   return 0;
 }
 
-// out[4]: native gets, posts, deletes, proxied
+// out[6]: native gets, posts, deletes, proxied, then the proxied requests'
+// summed wall and the summed wall of their backend connects, in ns
 void turbo_counters(long long handle, unsigned long long* out) {
   Engine* e = (Engine*)(intptr_t)handle;
   if (!e) return;
@@ -2021,6 +2067,8 @@ void turbo_counters(long long handle, unsigned long long* out) {
   out[1] = e->n_post.load();
   out[2] = e->n_delete.load();
   out[3] = e->n_proxy.load();
+  out[4] = e->proxy_ns.load();
+  out[5] = e->proxy_connect_ns.load();
 }
 
 }  // extern "C"

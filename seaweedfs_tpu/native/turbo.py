@@ -174,10 +174,15 @@ class TurboEngine:
         self._lib.turbo_sync(self._h, vid)
 
     def counters(self) -> dict:
-        buf = (ctypes.c_ulonglong * 4)()
+        """Requests served natively by kind, requests proxied to the Python
+        core, and the proxied requests' summed wall inside the engine
+        (``proxy_ns``: from the decision to proxy to the last byte sent to
+        the client) with the backend connects' share of it."""
+        buf = (ctypes.c_ulonglong * 6)()
         self._lib.turbo_counters(self._h, buf)
         return {"gets": buf[0], "posts": buf[1], "deletes": buf[2],
-                "proxied": buf[3]}
+                "proxied": buf[3], "proxy_ns": buf[4],
+                "proxy_connect_ns": buf[5]}
 
 
 class TurboNeedleMap(NeedleMapper):

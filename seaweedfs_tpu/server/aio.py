@@ -55,9 +55,9 @@ from seaweedfs_tpu.util.throttler import GOVERNOR
 
 from ..stats import trace as _trace
 from ..util import deadline as _deadline
+from . import http_util
 from .http_util import (
     NATIVE_FALLBACK,
-    SERVING,
     AsyncStreamBody,
     SendfileBody,
     admission_reject_response,
@@ -249,6 +249,31 @@ class _ShimConn:
     def __init__(self, rfile: _RfileBridge, flume: ThreadFlume):
         self._rfile = rfile
         self._flume = flume
+        # the serving path's stamps of the request in flight, on
+        # time.monotonic()'s clock (0.0: not passed). The loop writes them
+        # before it hands the request to a worker; the worker reads them
+        # once the request's span is open (serve_legs). No lock: the
+        # executor's queue orders the writes before the reads
+        self.t_head = 0.0  # the loop has the request's head
+        self.t_native = 0.0  # _maybe_native entered
+        self.t_native_miss = 0.0  # a native route ran and fell back
+        self.t_submit = 0.0  # handed to the pool
+        self.t_worker = 0.0  # first line of _run_request
+
+    def serve_legs(self, proxy_t0: Optional[str]):
+        """What the request in flight passed before its span opened, as
+        (span name, start, end) in time order: turbo's way in (only where
+        its ``X-Sweed-Proxy-T0`` is a CLOCK_MONOTONIC stamp of the last
+        minute), a native attempt that fell back, the wait for a worker.
+        The caller closes the last leg, ``serve.parse``, which began at
+        ``t_worker``."""
+        if proxy_t0 and proxy_t0.isascii() and proxy_t0.isdigit():
+            t0 = int(proxy_t0) / 1e9
+            if 0.0 <= self.t_head - t0 <= http_util.PROXY_T0_MAX_AGE_S:
+                yield "serve.proxy.in", t0, self.t_head
+        if self.t_native_miss:
+            yield "serve.native.miss", self.t_native, self.t_native_miss
+        yield "serve.queue", self.t_submit, self.t_worker
 
     def settimeout(self, t) -> None:
         # sweedlint: ok cross-domain-race per-connection shim; only the one worker serving this connection writes it
@@ -369,6 +394,7 @@ def _run_request(handler_cls, server, conn, rfile, wfile,
     the handler instance is built bare (__new__) against the bridges, so
     every subclass behavior — routing, parsers, error bytes, logging —
     is the threads-mode code verbatim."""
+    conn.t_worker = time.monotonic()
     h = handler_cls.__new__(handler_cls)
     h.server = server
     h.client_address = client_address
@@ -443,7 +469,7 @@ class AioHTTPServer:
         self._native_list = list(
             getattr(handler_cls, "native_routes", [])
         )
-        SERVING.register_server(self)
+        http_util.SERVING.register_server(self)
 
     # -- socketserver-compatible surface ------------------------------------
     def start(self) -> "AioHTTPServer":
@@ -549,7 +575,7 @@ class AioHTTPServer:
                 if deadline is None or now <= deadline:
                     continue
                 self._conn_meta.pop(writer, None)
-                SERVING.note_reaped(
+                http_util.SERVING.note_reaped(
                     "idle" if phase == "idle" else "deadline"
                 )
                 glog.V(1).info("reaping %s connection past deadline",
@@ -564,7 +590,7 @@ class AioHTTPServer:
         while True:
             t0 = self._loop.time()
             await asyncio.sleep(interval)
-            SERVING.note_loop_lag(self._loop.time() - t0 - interval)
+            http_util.SERVING.note_loop_lag(self._loop.time() - t0 - interval)
 
     async def _pump(self, flume: ThreadFlume, writer) -> None:
         """Drain the response flume to the transport; on client death,
@@ -612,7 +638,7 @@ class AioHTTPServer:
                 pass
         wm = serving_watermark()
         if wm > 0 and len(self._conns) >= wm:
-            SERVING.note_rejected()
+            http_util.SERVING.note_rejected()
             try:
                 writer.write(admission_reject_response())
                 await writer.drain()
@@ -651,6 +677,8 @@ class AioHTTPServer:
                     break
                 except (ConnectionError, OSError):
                     break
+                conn.t_head = time.monotonic()
+                conn.t_native_miss = 0.0
                 meta[0] = "handler"
                 meta[1] = (self._loop.time() + hdl_to) if hdl_to > 0 \
                     else None
@@ -660,7 +688,7 @@ class AioHTTPServer:
                 try:
                     native_close = await self._maybe_native(
                         raw_requestline, head[idx + 2:], client_address,
-                        flume, pump,
+                        flume, pump, conn,
                     )
                 except (ConnectionError, OSError):
                     break  # peer tore the socket mid-reply (RST): done
@@ -675,6 +703,7 @@ class AioHTTPServer:
                     # bridge, the same guarantee the threads core gets for
                     # free from running handlers on the request thread
                     ctx = contextvars.copy_context()
+                    conn.t_submit = time.monotonic()
                     close = await self._loop.run_in_executor(
                         self._pool, ctx.run, _run_request,
                         self.handler_cls, self, conn, rfile, wfile,
@@ -729,12 +758,13 @@ class AioHTTPServer:
 
     async def _maybe_native(self, raw_requestline: bytes,
                             head_rest: bytes, client_address: tuple,
-                            flume, pump):
+                            flume, pump, conn: _ShimConn):
         """Serve the request natively on the loop when a native route
         matches and the request is plain (no body, no Expect, clean
         HTTP/1.1). Returns NATIVE_FALLBACK to run the bridged path —
         which re-parses from the untouched head buffer, so falling back
         costs nothing and cannot drift — else close_connection."""
+        conn.t_native = time.monotonic()
         if not self._native_map and not self._native_list:
             return NATIVE_FALLBACK
         if faultpoints.active():
@@ -762,12 +792,12 @@ class AioHTTPServer:
             return NATIVE_FALLBACK
         return await self._native_dispatch(
             hit[0], hit[1], method, parsed, headers, client_address,
-            flume, pump,
+            flume, pump, conn,
         )
 
     async def _native_dispatch(self, fn, prefix: str, method: str,
                                parsed, headers, client_address: tuple,
-                               flume, pump):
+                               flume, pump, conn: _ShimConn):
         tenant = request_tenant(headers, client_address[0])
         decision, wait = GOVERNOR.admit(tenant)
         if decision == "shed":
@@ -804,17 +834,20 @@ class AioHTTPServer:
             headers.get(_deadline.DEADLINE_HEADER))
             if _deadline.enabled() else None)
         if ddl is not None and ddl <= time.time():
-            SERVING.note_native_fallback()
-            return NATIVE_FALLBACK
+            return self._native_miss(conn)
         req = NativeRequest(method, parsed.path, headers,
                             client_address, self)
-        # the span CM is task-scoped contextvars — safe in a coroutine
-        with _trace.start_span(
+        # the span CM is task-scoped contextvars — safe in a coroutine. A
+        # route that hands the request back drops it: the bridged path
+        # opens the request's one span, and this attempt is a child of
+        # that one (serve.native.miss)
+        scope = _trace.start_span(
             f"{method} {prefix}",
             service=getattr(self.handler_cls, "trace_service", "http"),
             parent_header=headers.get(_trace.TRACE_HEADER),
             path=parsed.path,
-        ) as span:
+        )
+        with scope as span:
             try:
                 with _deadline.scope(ddl):
                     result = await fn(req, parsed.path, query)
@@ -825,11 +858,11 @@ class AioHTTPServer:
                 # the request and produces its canonical error bytes
                 glog.exception("native %s %s failed; bridging",
                                method, parsed.path)
-                SERVING.note_native_fallback()
-                return NATIVE_FALLBACK
+                scope.drop()
+                return self._native_miss(conn)
             if result is NATIVE_FALLBACK:
-                SERVING.note_native_fallback()
-                return NATIVE_FALLBACK
+                scope.drop()
+                return self._native_miss(conn)
             status, payload = result[0], result[1]
             extra = dict(req.extra_headers or {})
             if len(result) > 2 and result[2]:
@@ -849,12 +882,19 @@ class AioHTTPServer:
                 head_only=(method == "HEAD"), close=close,
             )
         dt = time.monotonic() - t0
-        SERVING.note_native()
-        SERVING.note_request_seconds(dt)
+        http_util.SERVING.note_native()
+        http_util.SERVING.note_request_seconds(dt)
         observe_tenant_request(tenant, dt)
         glog.V(2).info("%s %s → %d (native)", method, parsed.path,
                        status)
         return close
+
+    def _native_miss(self, conn: _ShimConn):
+        """A native route ran and handed the request back to the bridged
+        path: counted, and stamped for the request's serve.native.miss."""
+        http_util.SERVING.note_native_fallback()
+        conn.t_native_miss = time.monotonic()
+        return NATIVE_FALLBACK
 
     async def _write_native(self, status: int, payload, extra: dict,
                             flume, pump, head_only: bool,
@@ -888,7 +928,7 @@ class AioHTTPServer:
         if self.overloaded():
             hdr_list.append(("Connection", "close"))
             close = True
-            SERVING.note_keepalive_shed()
+            http_util.SERVING.note_keepalive_shed()
         head = _native_response_head(self.handler_cls, status, hdr_list)
         try:
             if isinstance(payload, SendfileBody):

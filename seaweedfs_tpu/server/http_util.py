@@ -472,6 +472,14 @@ def has_dot_segments(path: str) -> bool:
     return any(seg in (".", "..") for seg in path.split("/"))
 
 
+# Turbo's stamp on a request it proxies: CLOCK_MONOTONIC nanoseconds at the
+# moment it had the whole request (native/turbo.cpp). The engine is a thread
+# of this process, so it is time.monotonic_ns()'s clock; a value in the
+# future or older than this is some other clock's and is not trusted
+PROXY_T0_HEADER = "X-Sweed-Proxy-T0"
+PROXY_T0_MAX_AGE_S = 60.0
+
+
 def parse_content_length(headers) -> int:
     """Content-Length as a non-negative int, or -1 when garbage/negative.
 
@@ -514,6 +522,28 @@ class JsonHandler(BaseHTTPRequestHandler):
 
     def log_message(self, fmt, *args):  # stdlib chatter → V(3)
         glog.V(3).info("http: " + fmt, *args)
+
+    def parse_request(self) -> bool:
+        # the threads core's first stamp of a request: its line is read
+        self._t_line = time.monotonic()
+        return super().parse_request()
+
+    def _record_serve_legs(self) -> None:
+        """What this request passed before its span opened, written in
+        hindsight as children of the span (which is the context's active
+        one): ``serve.proxy.in``, ``serve.native.miss`` and ``serve.queue``
+        from the aio core's stamps, then ``serve.parse`` up to now. The
+        threads core has no loop and no pool: ``serve.parse`` from the
+        request line read, nothing else."""
+        now = time.monotonic()
+        legs = getattr(self.connection, "serve_legs", None)
+        if legs is None:
+            parse_from = self._t_line
+        else:
+            parse_from = self.connection.t_worker
+            for name, start, end in legs(self.headers.get(PROXY_T0_HEADER)):
+                _trace.record_stage(name, end - start, ended_ago_s=now - end)
+        _trace.record_stage("serve.parse", now - parse_from)
 
     @staticmethod
     def mark_streaming(fn):
@@ -578,6 +608,8 @@ class JsonHandler(BaseHTTPRequestHandler):
                     parent_header=self.headers.get(_trace.TRACE_HEADER),
                     path=parsed.path,
                 ) as span:
+                    if span is not None:
+                        self._record_serve_legs()
                     cancelled = False
                     try:
                         with _deadline.scope(ddl):
@@ -628,6 +660,8 @@ class JsonHandler(BaseHTTPRequestHandler):
                             self.close_connection = True
                     if span is not None:
                         span.tags["status"] = status
+                        if status >= 500:
+                            span.tags["failed"] = 1  # summed in its row
                         if cancelled:
                             # the trace tree shows WHERE the budget died
                             span.status = "cancelled"
@@ -643,10 +677,21 @@ class JsonHandler(BaseHTTPRequestHandler):
                                 _trace.TRACE_ID_HEADER, span.trace_id
                             )
                     glog.V(2).info("%s %s → %d", method, parsed.path, status)
-                    self._reply(status, payload, head_only=(method == "HEAD"))
+                    # the reply is a stage of its own: a slow client's
+                    # back-pressure (the aio core's flume) is in it, and
+                    # not in the handler. Quiet: a long body's slow line is
+                    # the request's own
+                    with _trace.stage_span("serve.reply", quiet=True):
+                        self._reply(status, payload,
+                                    head_only=(method == "HEAD"))
                     dt = time.monotonic() - t0
                     SERVING.note_request_seconds(dt)
                     observe_tenant_request(tenant, dt)
+                if span is not None:
+                    # the closed request is a row of the stage table too,
+                    # under its ROUTE's name: /status says what a route's
+                    # handler costs whole, beside the legs around it
+                    _trace.STAGES.add(span)
                 return
         if body is None and length:
             # drain in bounded pieces for keep-alive correctness — a multi-GB
@@ -698,6 +743,7 @@ class JsonHandler(BaseHTTPRequestHandler):
         self._shed_keepalive_if_overloaded()
         self.end_headers()
         if not head_only:  # HEAD: headers only, or keep-alive framing breaks
+            _trace.add_stage_bytes(len(data))  # serve.reply's body
             try:
                 self.wfile.write(data)
             except (BrokenPipeError, ConnectionResetError):
@@ -745,6 +791,7 @@ class JsonHandler(BaseHTTPRequestHandler):
             return
         finally:
             body.close()
+        _trace.add_stage_bytes(sent)
         if sent != body.count:
             glog.error("sendfile reply produced %d of %d bytes", sent,
                        body.count)
@@ -782,6 +829,7 @@ class JsonHandler(BaseHTTPRequestHandler):
                            sent, body.length)
             self.close_connection = True
             return
+        _trace.add_stage_bytes(sent)
         if sent != body.length:
             glog.error("stream reply produced %d of %d bytes", sent,
                        body.length)

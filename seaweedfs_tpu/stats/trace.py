@@ -25,7 +25,10 @@ master → volume hops. This module is the divergence (PARITY: tracing):
   an outlier is discoverable from the daemon's own log.
 - stage spans    — ``stage_span`` / ``record_stage``: the same spans for
   the steps of a long operation (a seal's pipeline legs, a recovery's
-  shard fetches). At close each also adds itself to the process-wide
+  shard fetches) and for what a bridged request passes around its handler
+  (``serve.*``: the proxy's way in, the wait for a worker, the parse, the
+  reply — children of the request's span, written in hindsight where they
+  ended before it opened). At close each also adds itself to the process-wide
   ``STAGES`` table by name (the ``ec_codec.stages`` object of a volume
   server's /status) and, where JAX is already loaded, is written into the
   profiler's trace as a ``TraceAnnotation`` of the same name. This module
@@ -256,11 +259,14 @@ class StageTable:
     sends several asks side by side sums ``width`` (asks started together)
     and ``spares`` (asks made after one of them failed). A read-set plan
     (``ec.rebuild.plan``, ``ec.recover.plan``) sums ``width`` (shards it
-    reads) and ``local`` (1 where the lost shards' local groups sufficed)."""
+    reads) and ``local`` (1 where the lost shards' local groups sufficed).
+    A stage that commits staged files (``ec.seal.commit``) sums ``fsyncs``
+    (files it fsync'd) and ``slow_fsyncs`` (those that took
+    ``storage.commit.SLOW_FSYNC_S`` or more)."""
 
     SUMMED_TAGS = (
         "bytes", "failed", "slept_s", "ok", "ok_s", "absent", "width",
-        "spares", "local",
+        "spares", "local", "fsyncs", "slow_fsyncs",
     )
 
     def __init__(self):
@@ -290,12 +296,19 @@ class _SpanScope:
     """Context manager that owns one span's contextvar window. ``span``
     is None when tracing is disabled — callers guard tag writes on it."""
 
-    __slots__ = ("span", "_token", "_t0")
+    __slots__ = ("span", "_token", "_t0", "_dropped")
 
     def __init__(self, span: Optional[Span]):
         self.span = span
         self._token = None
         self._t0 = 0.0
+        self._dropped = False
+
+    def drop(self) -> None:
+        """Leave no record of this span at exit: what it timed turned out
+        not to be the thing it names (a native attempt that fell back to
+        the bridged path, which opens the request's one real span)."""
+        self._dropped = True
 
     def __enter__(self) -> Optional[Span]:
         if self.span is not None:
@@ -311,7 +324,8 @@ class _SpanScope:
         if exc_type is not None:
             self.span.status = "error"
             self.span.tags.setdefault("error", exc_type.__name__)
-        self._finish()
+        if not self._dropped:
+            self._finish()
 
     def _finish(self) -> None:
         _finish(self.span, "request")
@@ -425,26 +439,36 @@ def stage_span(name: str, quiet: bool = False, **tags) -> _StageScope:
     return _StageScope(_stage(name, tags), quiet)
 
 
-def record_stage(name: str, busy_s: float, **tags) -> None:
-    """A stage that ends now and took ``busy_s``, for time that is not one
-    ``with`` block on this thread: a transfer begun on another thread, the
-    reads of one loop taken together. Ring, table and slow line as
-    ``stage_span``; the profiler's trace cannot be written in hindsight."""
+def record_stage(name: str, busy_s: float, ended_ago_s: float = 0.0,
+                 **tags) -> None:
+    """A stage that took ``busy_s`` and ended ``ended_ago_s`` ago (now,
+    unless said), for time that is not one ``with`` block on this thread: a
+    transfer begun on another thread, the reads of one loop taken together,
+    a request's wait for its worker written once the request's span is
+    open. Ring, table and slow line as ``stage_span``; the profiler's trace
+    cannot be written in hindsight."""
     if not enabled():
         return
     span = _stage(name, tags)
     # sweedlint: ok cross-domain-race a span made here, finished here: in no ring and no table until _finish_stage below
-    span.start -= busy_s
+    span.start -= ended_ago_s + busy_s
     # sweedlint: ok cross-domain-race as above
     span.duration = busy_s
     _finish_stage(span, quiet=False)
 
 
-def add_stage_bytes(n: int) -> None:
-    """Count ``n`` bytes moved against the stage span this code runs in."""
+def add_stage_count(key: str, n=1) -> None:
+    """Add ``n`` to the tag ``key`` of the span this code runs in, if any:
+    one of ``StageTable.SUMMED_TAGS`` is summed into a stage's row. The
+    code that counts learns no span name."""
     span = _current.get()
     if span is not None:
-        span.tags["bytes"] = span.tags.get("bytes", 0) + n
+        span.tags[key] = span.tags.get(key, 0) + n
+
+
+def add_stage_bytes(n: int) -> None:
+    """Count ``n`` bytes moved against the stage span this code runs in."""
+    add_stage_count("bytes", n)
 
 
 def h_debug_traces(handler, path, query, body):

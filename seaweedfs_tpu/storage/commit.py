@@ -9,7 +9,8 @@ encode-and-retire as an atomic recoverable state change:
 
 1. **stage** — every output is written to a sibling staging name
    (``<final>.tmp``; vacuum keeps its reference ``.cpd``/``.cpx`` names);
-2. **harden** — each staged file is fsync'd;
+2. **harden** — each staged file is fsync'd (the stage span the commit
+   runs in counts them, and those of ``SLOW_FSYNC_S`` or more);
 3. **commit point** — a manifest (``<base>.commit``, JSON: staged files +
    their exact sizes + post-rename deletions) is written atomically
    (tmp + rename) and the directory is fsync'd;
@@ -34,8 +35,10 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Optional
 
+from ..stats import trace
 from ..util import faultpoints, glog
 
 COMMIT_EXT = ".commit"
@@ -44,6 +47,12 @@ STAGING_SUFFIX = ".tmp"
 # staging names recovery may garbage-collect when no manifest claims them:
 # generic ``.tmp`` plus vacuum's reference-parity ``.cpd``/``.cpx`` pair
 _ORPHAN_EXTS = (STAGING_SUFFIX, ".cpd", ".cpx")
+
+
+# a staged file's fsync this long or longer is counted as slow by the stage
+# a commit runs in (``slow_fsyncs``): a stalled disk, not a dear one. Not a
+# knob: the rows of two machines are compared by it
+SLOW_FSYNC_S = 0.2
 
 
 def fsync_file(path: str) -> None:
@@ -129,7 +138,13 @@ class StagedCommit:
         faultpoints.fire(self.tag + ".staged", path=first_staged)
         entries = {}
         for final, tmp in self._files.items():
+            # the stage span this commit runs in, if any, counts its fsyncs
+            # and the slow ones among them
+            t = time.perf_counter()
             fsync_file(tmp)
+            slow = time.perf_counter() - t >= SLOW_FSYNC_S
+            trace.add_stage_count("fsyncs")
+            trace.add_stage_count("slow_fsyncs", int(slow))
             entries[os.path.basename(final)] = {
                 "tmp": os.path.basename(tmp),
                 "size": os.path.getsize(tmp),

@@ -204,6 +204,16 @@ class Store:
         # reader subtracts two snapshots. Absent while tracing is off
         if trace.enabled():
             status["stages"] = trace.STAGES.snapshot()
+            turbo = self.turbo_engine
+            if turbo is not None:
+                # the one row a span cannot write: the native engine's own
+                # sums over the requests it proxied to this process
+                c = turbo.counters()
+                status["stages"]["serve.proxy"] = {
+                    "n": c["proxied"],
+                    "busy_s": c["proxy_ns"] / 1e9,
+                    "connect_s": c["proxy_connect_ns"] / 1e9,
+                }
         return status
 
     # -- volume management (store.go:120-200) --------------------------------

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import re
 import signal
 import socket
 import subprocess
@@ -277,6 +278,51 @@ def test_a_recovery_fetches_its_live_siblings_remotely(spread):
     assert seen.count("ec.recover.remote") == siblings
     assert seen.count("ec.read.remote") == 4 + siblings + need["remote"]
     assert "ec.recover.decode" in seen
+
+
+def test_a_holders_handler_carries_its_serving_legs_in_the_askers_trace(spread):
+    """``weed shell trace <id>`` of a recovering GET: under each holder's
+    ``GET /admin/ec/shard_read`` its own way through the serving core, and
+    under the asker's ``GET /`` the same before the recovery."""
+    url = spread.survivors[2]
+    i, _ = pick(spread, url, lost=1, remote_min=0)
+    _, trace_id = get(url, spread.loaded.fids[i])
+    shown = commands.trace_collect(
+        commands.CommandEnv(spread.servers[0].master_url), trace_id)["tree"]
+    # the printed tree, as the shell shows it: (depth, name) a line
+    lines = []
+    for line in shown.splitlines():
+        words = line.split()
+        last = next(k for k, w in enumerate(words) if re.fullmatch(r"[\d.]+ms", w))
+        lines.append(((len(line) - len(line.lstrip())) // 2, " ".join(words[1:last])))
+    assert lines[0] == (0, "GET /")
+
+    def children(at):
+        depth = lines[at][0]
+        out = []
+        for d, name in lines[at + 1:]:
+            if d <= depth:
+                break
+            if d == depth + 1:
+                out.append(name)
+        return out
+
+    holders = [k for k, (_, name) in enumerate(lines)
+               if name == "GET /admin/ec/shard_read"]
+    assert len(holders) >= 6
+    # a server's native engine stamps what it proxies (the dead one has none)
+    engine = spread.servers[spread.urls.index(url)].turbo is not None
+    for at in holders:
+        # in time order: the way in, the wait, the parse, the read, the reply
+        assert children(at) == (["serve.proxy.in"] if engine else []) + [
+            "serve.queue", "serve.parse", "ec.shard.serve", "serve.reply"], shown
+    mine = children(0)
+    assert mine.index("serve.queue") < mine.index("serve.parse") < mine.index(
+        "ec.recover") < mine.index("serve.reply")
+    # an EC GET that reached the native route and fell back: ONE request span
+    assert [name for _, name in lines].count("GET /") == 1
+    if engine:
+        assert mine[0] == "serve.proxy.in" and "serve.native.miss" in mine
 
 
 def test_lookups_at_the_master_fall_and_answered_remote_reads_do_not(spread):

@@ -155,7 +155,14 @@ def test_status_serves_the_table_under_ec_codec(sealed):
     stages = served["ec_codec"]["stages"]
     assert stages["ec.seal"]["n"] >= 1
     assert set(stages["ec.seal"]) == {"n", "busy_s", "bytes"}
-    assert set(stages["ec.seal.commit"]) == {"n", "busy_s"}
+    # the commit counts the staged files it fsyncs, and the slow ones
+    assert set(stages["ec.seal.commit"]) == {"n", "busy_s", "fsyncs",
+                                             "slow_fsyncs"}
+    # this seal's own (the table is the process's: other seals, other codes):
+    # fourteen shards, the .ecx and the .vif
+    b, a = sealed["before"], sealed["after"]
+    assert delta(b, a, "ec.seal.commit", "fsyncs") == 16
+    assert 0 <= delta(b, a, "ec.seal.commit", "slow_fsyncs") <= 16
 
 
 def test_a_seal_is_one_tree_under_its_admin_request(sealed):
@@ -429,7 +436,10 @@ def test_a_chipless_volume_server_polled_on_status_never_imports_jax(tmp_path):
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["jax"] is False
     assert out["codec"]["resolved"] is False
-    assert out["codec"]["stages"] == {}  # tracing on, no stage run yet
+    # tracing on, no EC stage run yet: the polls' own serving rows only
+    assert out["codec"]["stages"]
+    assert not [name for name in out["codec"]["stages"]
+                if name.startswith("ec.")]
     assert out["codec"]["jax_platforms"] is None
 
 

@@ -319,7 +319,10 @@ def _span_tree_shape(mode):
         tid = {k.lower(): v for k, v in hdrs.items()}["x-sweed-trace-id"]
         # the fan span finishes with the reply, but give the ring a beat
         deadline = time.monotonic() + 5
-        while len(RING.for_trace(tid)) < 2 and time.monotonic() < deadline:
+        def closed(trace_id, name):  # a request span closes after its legs
+            return any(s["name"] == name for s in RING.for_trace(trace_id))
+
+        while not closed(tid, "GET /fan") and time.monotonic() < deadline:
             time.sleep(0.01)
         spans = RING.for_trace(tid)
         # streamed replies stay inside the server span in both cores
@@ -329,7 +332,7 @@ def _span_tree_shape(mode):
         assert st2 == 200
         tid2 = {k.lower(): v for k, v in hdrs2.items()}["x-sweed-trace-id"]
         deadline = time.monotonic() + 5
-        while not RING.for_trace(tid2) and time.monotonic() < deadline:
+        while not closed(tid2, "GET /stream") and time.monotonic() < deadline:
             time.sleep(0.01)
         stream_spans = RING.for_trace(tid2)
     finally:
@@ -345,9 +348,10 @@ def _span_tree_shape(mode):
 
     for root in assemble_tree(spans):
         walk(root, 0)
-    assert [(s["service"], s["name"]) for s in stream_spans] == [
-        ("svc", "GET /stream")
-    ]
+    # the request span closes last: its serving legs (serve.*) before it
+    assert [(s["service"], s["name"]) for s in stream_spans
+            if not s["name"].startswith("serve.")] == [("svc", "GET /stream")]
+    assert stream_spans[-1]["name"] == "GET /stream"
     return shape
 
 
@@ -362,8 +366,22 @@ def test_threads_and_aio_emit_identical_span_trees(monkeypatch):
         RING.clear()
         shapes[mode] = _span_tree_shape(mode)
     expected = [("svc", "GET /fan", 0), ("svc", "GET /ping", 1)]
-    assert shapes["threads"] == expected
-    assert shapes["aio"] == expected
+
+    def hops(shape):
+        return [s for s in shape if not s[1].startswith("serve.")]
+
+    assert hops(shapes["threads"]) == expected
+    assert hops(shapes["aio"]) == expected
+    # each hop's serving legs are its own children, and differ by core as
+    # the cores do: the threads core has no loop and no pool to wait for
+    legs = {mode: sorted({(name, depth) for _, name, depth in shape
+                          if name.startswith("serve.")})
+            for mode, shape in shapes.items()}
+    assert legs["threads"] == [
+        ("serve.parse", 1), ("serve.parse", 2),
+        ("serve.reply", 1), ("serve.reply", 2)]
+    assert legs["aio"] == sorted(
+        legs["threads"] + [("serve.queue", 1), ("serve.queue", 2)])
 
 
 # ------------------------------------------------- cluster end-to-end
@@ -431,7 +449,12 @@ def test_cluster_trace_tree_filer_master_volume(tmp_path, monkeypatch, mode):
             deadline = time.monotonic() + 10
             while time.monotonic() < deadline:
                 spans = RING.for_trace(tid)
-                if want_services <= {s["service"] for s in spans}:
+                ids = {s["span_id"] for s in spans}
+                # a hop's serving legs land before the hop's own span
+                # closes: settled is every span's parent in hand, but one
+                orphans = [s for s in spans if s["parent_id"] not in ids]
+                if (want_services <= {s["service"] for s in spans}
+                        and len(orphans) == 1):
                     return spans
                 time.sleep(0.05)
             return RING.for_trace(tid)
