@@ -114,6 +114,8 @@ class _PoolClosed(Exception):
 # at the client, the median of three runs' medians), and the device holds
 # 834 MiB for 574 at its peak. What would make a deeper pool pay is a
 # copy back that is not slowed by the staging beside it, not more buffers.
+# (Since PR 40 a chunk's copy back is 10-38 ms for 58-101, `_copy_back`;
+# the depths have not been swept again: ROADMAP B3.)
 _POOL_BUFFERS = 2
 
 
@@ -158,7 +160,9 @@ class _ChunkBuffers:
     process kept from an earlier call (`_KeptBuffers`) or allocates, and
     otherwise it waits until `give` brings one back: the reader's
     backpressure, and the bound on the host memory of a call's chunks.
-    When the call ends (`close`) its buffers go to the kept list. A buffer
+    When the call ends (`close`) its buffers go to the kept list: the idle
+    ones at once, and — once every leg has ended — the ones a failed
+    pipeline dropped on its way out. A buffer
     comes back holding its last chunk — of this call or of another, of
     another volume, geometry or number of rows — and is NOT cleared, so
     whoever fills it writes every byte of the view. Each `take` leaves one
@@ -171,6 +175,7 @@ class _ChunkBuffers:
         self._nbytes = nbytes  # of the largest chunk: any buffer fits any
         self._unmade = count
         self._free: list[np.ndarray] = []
+        self._out: list[np.ndarray] = []  # taken and not given back yet
         self._cond = threading.Condition()
         self._closed = False
 
@@ -193,24 +198,33 @@ class _ChunkBuffers:
             how, flat = "new", np.empty(self._nbytes, dtype=np.uint8)
         trace.record_stage(f"{self._op}.buf.{how}",
                            time.perf_counter() - t0, bytes=flat.nbytes)
+        with self._cond:
+            self._out.append(flat)
         return flat[: k * width].reshape(k, width)
 
     def give(self, mat: np.ndarray) -> None:
         """Nothing reads ``mat`` (a `take`) any more: recycle its buffer,
         to this call's reader or, after `close`, to the process."""
+        flat = mat.base
         with self._cond:
+            self._out = [b for b in self._out if b is not flat]
             if not self._closed:
-                self._free.append(mat.base)
+                self._free.append(flat)
                 self._cond.notify()
                 return
-        _KEPT.keep([mat.base])
+        _KEPT.keep([flat])
 
-    def close(self) -> None:
+    def close(self, ended: bool = False) -> None:
         """The pipeline is ending: release a reader that waits in `take`,
-        and hand the idle buffers to the process."""
+        and hand the idle buffers to the process. ``ended``: every leg has
+        returned, so a buffer still out belongs to a chunk that a failed
+        pipeline dropped (a leg that failed gives nothing back), and goes
+        with the idle ones."""
         with self._cond:
             self._closed = True
             idle, self._free = self._free, []
+            if ended:
+                idle, self._out = idle + self._out, []
             self._cond.notify_all()
         _KEPT.keep(idle)
 
@@ -602,7 +616,8 @@ def _overlap_pipeline(produce, compute, consume, fetch,
     until the job has filled it; from then on it belongs to the chunk, and
     the caller's stage that is last to read it gives it back. The pipeline
     itself only closes the pool, on the first error of any leg and when it
-    ends: a leg that failed gives nothing back, and a reader waiting for a
+    ends: a leg that failed gives nothing back — the pool takes what such a
+    chunk held once every leg has returned — and a reader waiting for a
     buffer must end as a reader waiting for a queue slot does.
 
     Every chunk passes each leg inside a stage span (stats/trace.py):
@@ -715,14 +730,20 @@ def _overlap_pipeline(produce, compute, consume, fetch,
                 except queue.Empty:
                     rt.join(timeout=0.05)
             rt.join()
+            if buffers is not None:
+                buffers.close(ended=True)
         if errors:
             raise errors[0]
 
 
-def _await(on_device) -> None:
+def _await(on_device) -> bool:
+    """Wait until ``on_device`` is ready. False for a host codec's array:
+    it is ready as it is, and on the host already."""
     ready = getattr(on_device, "block_until_ready", None)
-    if ready is not None:  # a host codec's array is ready as it is
-        ready()
+    if ready is None:
+        return False
+    ready()
+    return True
 
 
 class _StagedWatch:
@@ -773,12 +794,62 @@ class _StagedWatch:
         self._thread.join()
 
 
-def _copy_back(op: str, out_dev, copy):
-    """The fetch thread's part of one chunk: wait for the result, then the
-    device-to-host leg as a stage of its own, ``<op>.d2h``: ``copy`` alone."""
-    _await(out_dev)
-    with trace.stage_span(f"{op}.d2h", bytes=out_dev.nbytes):
-        out = copy(out_dev)
+# Transfers of one chunk's result the copy back puts side by side: its
+# only parameter. A row of a chunk's result is 8-13 MiB at the sizes the
+# planner makes; four of them in turn take 28-71 ms on a v5e host and four
+# side by side 9-11 (13 beside the next chunk's staging), and cutting a
+# row into column pieces gains nothing (tools/d2h_probe.py, PERF.md §6 PR 40).
+_COPY_BACK_TRANSFERS = 4
+
+
+@functools.cache
+def _copy_back_workers() -> ThreadPoolExecutor:
+    """The threads that copy rows back, kept by the process from the first
+    device result on, as `_KeptBuffers` keeps the chunk buffers: a seal or
+    a rebuild starts none and ends none."""
+    return ThreadPoolExecutor(max_workers=_COPY_BACK_TRANSFERS - 1,
+                              thread_name_prefix="ec-copy-back")
+
+
+def _side_by_side(pieces: list) -> list:
+    """``np.asarray`` of every device array in ``pieces``, at most
+    `_COPY_BACK_TRANSFERS` at a time: the last on this thread, the others
+    on the kept workers (one fewer than that), so one transfer alone hops
+    to no thread. Returns when all have ended, also when one raised: no
+    transfer in flight."""
+    tasks = [_copy_back_workers().submit(np.asarray, p) for p in pieces[:-1]]
+    try:
+        last = np.asarray(pieces[-1])
+    finally:
+        wait_all(tasks)
+    return [*(task.result() for task in tasks), last]
+
+
+def _copy_back(op: str, out_dev) -> list:
+    """The only place where a chunk's result leaves the device: the fetch
+    thread's part of a seal and of a rebuild. Waits for the
+    ``(rows, width)`` result, then brings it to the host as a stage of its
+    own, ``<op>.d2h`` (``bytes`` the result's, ``transfers`` the
+    device-to-host copies it took), and returns the rows as host arrays.
+
+    A row comes back as a 1-D array of its own, the rows side by side
+    (`_side_by_side`), never the 2-D result as one: on the chip a
+    ``uint8[R, width]`` lies in tiles of four rows, so with one row the
+    transfer moves four, and either way one transfer fills one fresh
+    allocation on one thread, at the price of pages never touched —
+    51-75 ms a chunk of 126.9 MiB whatever R, where a row is 4-5 ms and
+    four side by side 11 (v5e host, PERF.md §6 PR 40). It adapts to what
+    it is handed and to nothing else: the rows are in ``out_dev.shape``; a
+    host codec's result has no ``block_until_ready``, is on the host
+    already and is handed on as it is, no thread and no copy; a row of a
+    result sharded over several devices is gathered from them by the same
+    ``np.asarray`` (a device's piece at a time instead reads 7-11 ms a
+    chunk for the rows' 14-16 when probed alone and three times the rows'
+    inside the pipeline: PERF.md §6 PR 40, four chips)."""
+    rows = out_dev.shape[0] if _await(out_dev) else 0  # to transfer
+    with trace.stage_span(f"{op}.d2h", bytes=out_dev.nbytes, transfers=rows):
+        out = (_side_by_side([out_dev[j] for j in range(rows)])
+               if rows else list(out_dev))
     trace.add_stage_bytes(out_dev.nbytes)
     return out
 
@@ -822,25 +893,12 @@ def _encode_pipelined(dat, items, codec, shards: _HashedShards,
         trace.add_stage_bytes(piece.nbytes)
         return width, data, codec.matmul_device(codec.parity_rows, staged)
 
-    # the D2H leg dominates end-to-end at large chunk sizes; pulling the m
-    # parity rows as m concurrent row-sized transfers instead of one
-    # array-sized one overlaps them on runtimes with per-transfer setup
-    # cost (and degrades to the same bytes moved on those without)
-    fetch_pool = ThreadPoolExecutor(
-        max_workers=max(1, min(m, 4)), thread_name_prefix="ec-d2h"
-    )
-
-    def parity_rows(parity_dev):
-        return list(
-            fetch_pool.map(np.asarray, (parity_dev[j] for j in range(m)))
-        )
-
     def fetch(got):
         width, data, parity_dev = got
         if parity_dev is None:
             return width, data, None
         # the blocking D2H leg: overlaps the next chunk's H2D + dispatch
-        return width, data, _copy_back("ec.seal", parity_dev, parity_rows)
+        return width, data, _copy_back("ec.seal", parity_dev)
 
     def consume(got):
         faultpoints.fire("ec.encode.chunk", path=shards.name)
@@ -848,11 +906,7 @@ def _encode_pipelined(dat, items, codec, shards: _HashedShards,
         if parity is None:
             shards.skip(width)  # keep sparse regions sparse (holes)
         else:
-            # parity[j] indexing (not parity[j, ...]) so both a 2-D array
-            # and the row list from the parallel fetch work here
-            shards.append(
-                [*data, *(parity[j][:width] for j in range(m))]
-            )
+            shards.append([*data, *(row[:width] for row in parity)])
             trace.add_stage_bytes((k + m) * width)
         if data is not None:
             buffers.give(data)
@@ -863,7 +917,6 @@ def _encode_pipelined(dat, items, codec, shards: _HashedShards,
                           op="ec.seal", buffers=buffers)
     finally:
         h2d.close()
-        fetch_pool.shutdown(wait=True)
 
 
 def rebuild_ec_files(
@@ -980,7 +1033,7 @@ def _rebuild_pipelined(codec, ins, outs, rows, shard_size, chunk) -> None:
         out = None
         if out_dev is not None:
             # blocking D2H leg
-            out = _copy_back("ec.rebuild", out_dev, np.asarray)
+            out = _copy_back("ec.rebuild", out_dev)
         if buf is not None:
             buffers.give(buf)
         return width, out
@@ -991,8 +1044,8 @@ def _rebuild_pipelined(codec, ins, outs, rows, shard_size, chunk) -> None:
             for fh in outs.values():
                 fh.seek(width, 1)
             return
-        for j, fh in enumerate(outs.values()):  # in the plan's wanted order
-            fh.write(out[j, :width].tobytes())
+        for row, fh in zip(out, outs.values()):  # in the plan's wanted order
+            fh.write(row[:width])
         trace.add_stage_bytes(len(outs) * width)
 
     h2d = _StagedWatch("ec.rebuild")

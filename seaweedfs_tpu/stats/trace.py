@@ -262,11 +262,13 @@ class StageTable:
     reads) and ``local`` (1 where the lost shards' local groups sufficed).
     A stage that commits staged files (``ec.seal.commit``) sums ``fsyncs``
     (files it fsync'd) and ``slow_fsyncs`` (those that took
-    ``storage.commit.SLOW_FSYNC_S`` or more)."""
+    ``storage.commit.SLOW_FSYNC_S`` or more). A copy back of a chunk's
+    result (``ec.seal.d2h``, ``ec.rebuild.d2h``) sums ``transfers`` (the
+    device-to-host copies it took: 0 where the result was on the host)."""
 
     SUMMED_TAGS = (
         "bytes", "failed", "slept_s", "ok", "ok_s", "absent", "width",
-        "spares", "local", "fsyncs", "slow_fsyncs",
+        "spares", "local", "fsyncs", "slow_fsyncs", "transfers",
     )
 
     def __init__(self):

@@ -81,8 +81,10 @@ def load(tmp_path, shape: str, codec) -> tuple[Store, str]:
 
 
 def seal_threads() -> list[str]:
+    """A seal's own threads still alive. The copy back's workers
+    (``ec-copy-back``) are not among them: the process keeps those."""
     return [t.name for t in threading.enumerate()
-            if t.name.startswith(("ec-hash", "ec-d2h", "ec-h2d"))]
+            if t.name.startswith(("ec-hash", "ec-h2d"))]
 
 
 def keeps_holes(directory) -> bool:

@@ -166,6 +166,74 @@ def test_fetch_leg_error_propagates():
         _overlap_pipeline(produce, compute, consume, fetch=fetch)
 
 
+class _RowNeverComes:
+    """A device result's row whose transfer fails."""
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("row 1 of chunk 3 never came")
+
+
+class _OnAFakeDevice:
+    """A host array behind what the copy back asks of a device result:
+    it can be awaited, and hands out its rows."""
+
+    def __init__(self, host: np.ndarray, broken: bool):
+        self._host, self._broken = host, broken
+        self.shape, self.dtype, self.nbytes = host.shape, host.dtype, host.nbytes
+
+    def block_until_ready(self):
+        return self
+
+    def __getitem__(self, j):
+        return _RowNeverComes() if self._broken and j == 1 else self._host[j]
+
+
+def test_a_rows_transfer_that_raises_fails_the_seal_and_nothing_is_left(
+        tmp_path, kept):
+    """One row of one chunk fails on its way back: the seal raises that
+    error, every thread of the pipeline has ended, and both chunk buffers
+    — the failed chunk's and the one the pipeline dropped behind it — are
+    back with the process."""
+    import threading
+
+    import pytest as _pytest
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ec.codec import NumpyCodec
+
+    class OneRowFails(NumpyCodec):
+        launched = 0
+
+        def matmul_device(self, matrix, data):
+            OneRowFails.launched += 1
+            return _OnAFakeDevice(self.matmul(matrix, np.asarray(data)),
+                                  broken=OneRowFails.launched == 3)
+
+    base = str(tmp_path / "1")
+    rng = np.random.default_rng(5)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, 300_000, dtype=np.uint8).tobytes())
+    codec = OneRowFails()
+    _, items = encoder.plan_encode(codec, 300_000, 8192, 1024)
+    assert len(items) >= 5
+    threads = set(threading.enumerate())
+    with _pytest.raises(RuntimeError, match="row 1 of chunk 3 never came"):
+        encoder.write_ec_files(
+            base, codec, large_block_size=8192, small_block_size=1024)
+    # the kept workers of the copy back are the process's, idle now
+    left = [t.name for t in set(threading.enumerate()) - threads
+            if not t.name.startswith("ec-copy-back")]
+    assert left == []
+    assert OneRowFails.launched >= 3
+    assert len(kept) == encoder._POOL_BUFFERS
+    # and the next seal, of the same process, is whole
+    OneRowFails.launched = 3
+    sums = encoder.write_ec_files(
+        base, codec, large_block_size=8192, small_block_size=1024)
+    assert len(sums) == 14
+    assert len(kept) == encoder._POOL_BUFFERS
+
+
 def test_depth_chunk_splits_small_volumes():
     """A 128 MB volume under a 32 MB budget previously collapsed to one
     work item — nothing to overlap (r4 efficiency pinned at ~0.65). The

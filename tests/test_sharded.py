@@ -120,3 +120,51 @@ def test_write_ec_files_is_the_same_bytes_from_every_codec(tmp_path, kind):
     for pa in sorted(glob.glob(base_a + ".ec[0-9][0-9]")):
         pb = base_b + pa[-5:]
         assert open(pa, "rb").read() == open(pb, "rb").read(), os.path.basename(pa)
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("rows", [1, 4])
+def test_a_mesh_sharded_result_comes_back_whole(rows, tp):
+    """The encoder's one copy back, handed a result spread by columns over
+    four devices (or over two, each piece held twice): ``rows`` whole host
+    rows, every byte in its place, each gathered by its own transfer."""
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.stats.trace import STAGES
+
+    mc = sharded.MeshCodec(mesh=sharded.build_mesh(4, tp=tp), chunk_bytes=4096)
+    rng = np.random.default_rng(40)
+    data = rng.integers(0, 256, (10, 4 * mc.alignment()), dtype=np.uint8)
+    matrix = mc.parity_rows[:rows]
+    out_dev = mc.matmul_device(matrix, mc.device_put(data))
+    assert len(out_dev.sharding.device_set) == 4
+    op = f"ec.test-mesh{rows}-{tp}"
+    back = encoder._copy_back(op, out_dev)
+    assert len(back) == rows
+    assert np.array_equal(np.stack(back), NumpyCodec().matmul(matrix, data))
+    stage = STAGES.snapshot()[f"{op}.d2h"]
+    assert (stage["n"], stage["bytes"]) == (1, rows * data.shape[1])
+    assert stage["transfers"] == rows
+
+
+def test_a_rebuild_on_the_mesh_is_the_numpy_codecs(tmp_path):
+    import os
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ec.constants import shard_ext
+
+    base = str(tmp_path / "1")
+    rng = np.random.default_rng(9)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, 200_001, dtype=np.uint8).tobytes())
+    encoder.write_ec_files(base, NumpyCodec(), large_block_size=8192,
+                           small_block_size=512)
+    want = {}
+    for sid in (0, 4, 9, 12):
+        with open(base + shard_ext(sid), "rb") as f:
+            want[sid] = f.read()
+        os.remove(base + shard_ext(sid))
+    mc = sharded.MeshCodec(n_devices=4, chunk_bytes=4096)
+    assert encoder.rebuild_ec_files(base, mc, chunk_bytes=4096) == [0, 4, 9, 12]
+    for sid, data in want.items():
+        with open(base + shard_ext(sid), "rb") as f:
+            assert f.read() == data, sid

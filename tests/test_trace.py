@@ -322,7 +322,10 @@ def _span_tree_shape(mode):
         def closed(trace_id, name):  # a request span closes after its legs
             return any(s["name"] == name for s in RING.for_trace(trace_id))
 
-        while not closed(tid, "GET /fan") and time.monotonic() < deadline:
+        # ... and the hop's own span closes on ITS handler's thread, after
+        # the reply that lets /fan finish: wait for both
+        while (not (closed(tid, "GET /fan") and closed(tid, "GET /ping"))
+               and time.monotonic() < deadline):
             time.sleep(0.01)
         spans = RING.for_trace(tid)
         # streamed replies stay inside the server span in both cores
