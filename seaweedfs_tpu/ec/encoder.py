@@ -18,6 +18,7 @@ function of the .dat contents.
 
 from __future__ import annotations
 
+import collections
 import functools
 import hashlib
 import json
@@ -90,6 +91,108 @@ def _pread_into(fd: int, offset: int, views: list) -> None:
             views[i] = views[i][got:]
     for v in views[i:]:
         v[:] = 0
+
+
+def _side_by_side(workers, fn, jobs: list, width: int) -> list:
+    """``fn(job)`` for every one of ``jobs``, at most ``width`` of them at
+    a time, and the results in the jobs' order. This thread takes jobs
+    beside up to ``width - 1`` threads of ``workers()``, a pool the process
+    keeps (asked for only when a job hops), each taking the next job not
+    yet begun: a job alone hops to no thread. Returns when every job begun
+    has ended, also when one raised — nothing is in flight; then no more
+    are begun and the call raises that error."""
+    todo = collections.deque(enumerate(jobs))
+    done = [None] * len(todo)
+
+    def take():
+        while True:
+            try:
+                i, job = todo.popleft()
+            except IndexError:  # none left: the others took them
+                return
+            try:
+                done[i] = fn(job)
+            except BaseException:
+                todo.clear()
+                raise
+
+    helpers = [workers().submit(take)
+               for _ in range(min(width, len(todo)) - 1)]
+    try:
+        take()
+    finally:
+        wait_all(helpers)
+    for helper in helpers:
+        helper.result()
+    return done
+
+
+# Reads of one chunk the reader puts side by side: its only parameters.
+# One thread copies a chunk out of the page cache at 2.0-2.6 GB/s on a v5e
+# host (13 cores, gVisor over 9p) and threads scale: a rebuild's ten rows
+# of 12.75 MiB take 51 ms on one thread, 26 / 22 / 16 / 12 / 12 on
+# 2 / 3 / 4 / 6 / 10 alone and 53 -> 35 / 23 / 25 / 20 / 18 beside the
+# next chunk's staging and the last one's copy back; a seal's 130 MiB run
+# 67 -> 34 / 18 in 2 / 4 pieces alone, 69 -> 53 / 32-39 beside. Inside the
+# pipeline (every width through `write_ec_files` / `rebuild_ec_files`, the
+# column that decides) six is where it stops paying: a one-shard rebuild's
+# pipeline 0.550 s at 1, 0.483 / 0.420 / 0.395 / 0.400 / 0.358 at
+# 2 / 3 / 4 / 6 / 10 (0.280 -> 0.193 / 0.197 at 6 / 10 with six rows,
+# 0.556 -> 0.353 / 0.352 with twelve), a seal's 0.849 -> 0.663 / 0.655 /
+# 0.617 / 0.581 / 0.605 (0.776 -> 0.510 / 0.603 and 0.756 -> 0.534 / 0.561
+# at 12+4 and 12+2+2): at ten the seal's readers, its fourteen digests and
+# the copy back's four threads are more than the host's cores and its read
+# leg is slower than at six. (tools/read_probe.py, PERF.md §6 PR 43.)
+_CHUNK_READS = 6
+# The least bytes of a chunk a thread of them is worth: a hop to a kept
+# worker costs 0.05-0.1 ms, so a run of 1 MiB is slower in two pieces
+# (0.24 ms for 0.18), one of 4 MiB level (0.50 / 0.46) and one of 16 MiB
+# faster (1.40 in two, 1.22 in four, for 2.22): same probe.
+_LEAST_READ = 4 << 20
+
+
+@functools.cache
+def _read_workers() -> ThreadPoolExecutor:
+    """The threads that read beside the reader thread, kept by the process
+    from the first chunk wide enough on, as `_copy_back_workers` are: not
+    those, which work at the same moments on the fetch thread's behalf."""
+    return ThreadPoolExecutor(max_workers=_CHUNK_READS - 1,
+                              thread_name_prefix="ec-read")
+
+
+def _cut_at_views(fd: int, offset: int, views: list, piece: int):
+    """The read job ``(fd, offset, views)`` as consecutive jobs of whole
+    views, each of ``piece`` bytes or the fewest views that reach them."""
+    part, size = [], 0
+    for v in views:
+        part.append(v)
+        size += len(v)
+        if size >= piece:
+            yield fd, offset, part
+            offset, part, size = offset + size, [], 0
+    if part:
+        yield fd, offset, part
+
+
+def _read_side_by_side(jobs: list) -> None:
+    """The reads of ONE chunk, for a seal and a rebuild alike: every job
+    ``(fd, offset, views)`` is a `_pread_into`, up to `_CHUNK_READS` of them
+    at a time (`_side_by_side`), and all have ended when this returns. It
+    adapts to what it is handed and to nothing else: as many threads as
+    the jobs' bytes give `_LEAST_READ` each — so a small chunk is read in
+    turn on this thread, as every chunk was — and a job of several views
+    (a seal's run of neighbouring blocks) is cut at view boundaries into
+    about equal pieces, one a thread, each with its own offset; a job of
+    one view (a rebuild's row) is never cut. Counts the jobs it made
+    against the stage span it runs in (``reads``); their bytes are the
+    caller's to count, here on the reader thread: a worker has no span."""
+    total = sum(len(v) for _, _, views in jobs for v in views)
+    width = min(_CHUNK_READS, total // _LEAST_READ)
+    if width > 1:
+        piece = -(-total // width)
+        jobs = [cut for job in jobs for cut in _cut_at_views(*job, piece)]
+    trace.add_stage_count("reads", len(jobs))
+    _side_by_side(_read_workers, lambda job: _pread_into(*job), jobs, width)
 
 
 class _PoolClosed(Exception):
@@ -356,9 +459,10 @@ def _read_item(fd: int, item, segments: list, mat: np.ndarray) -> None:
 
     A "rows" item's segment ``r * k + i`` — block ``i`` of row ``r``,
     ``block`` bytes of the .dat — lands in ``mat[i, r*block:(r+1)*block]``;
-    a "cols" item's segment ``i`` in ``mat[i]``. Neighbours in the file go
-    into one scatter read (`_pread_into`), so a dense region is still
-    walked once and in order. ``mat`` is a recycled buffer that holds an
+    a "cols" item's segment ``i`` in ``mat[i]``. Neighbours in the file are
+    one run, and the chunk's runs are read side by side
+    (`_read_side_by_side`: a long run in pieces), every byte once. ``mat``
+    is a recycled buffer that holds an
     older chunk: hole segments, segments past EOF and the tail of the one
     that EOF cuts are zeroed here; nothing else is."""
     k = mat.shape[0]
@@ -377,8 +481,8 @@ def _read_item(fd: int, item, segments: list, mat: np.ndarray) -> None:
             runs.append((offset, []))
         runs[-1][1].append(dst[:n])
         end = offset + n
-    for offset, views in runs:
-        _pread_into(fd, offset, views)
+    if runs:
+        _read_side_by_side([(fd, offset, views) for offset, views in runs])
 
 
 def _depth_chunk(chunk: int, total_width: int, floor: int, depth: int = 8) -> int:
@@ -811,20 +915,6 @@ def _copy_back_workers() -> ThreadPoolExecutor:
                               thread_name_prefix="ec-copy-back")
 
 
-def _side_by_side(pieces: list) -> list:
-    """``np.asarray`` of every device array in ``pieces``, at most
-    `_COPY_BACK_TRANSFERS` at a time: the last on this thread, the others
-    on the kept workers (one fewer than that), so one transfer alone hops
-    to no thread. Returns when all have ended, also when one raised: no
-    transfer in flight."""
-    tasks = [_copy_back_workers().submit(np.asarray, p) for p in pieces[:-1]]
-    try:
-        last = np.asarray(pieces[-1])
-    finally:
-        wait_all(tasks)
-    return [*(task.result() for task in tasks), last]
-
-
 def _copy_back(op: str, out_dev) -> list:
     """The only place where a chunk's result leaves the device: the fetch
     thread's part of a seal and of a rebuild. Waits for the
@@ -832,8 +922,9 @@ def _copy_back(op: str, out_dev) -> list:
     own, ``<op>.d2h`` (``bytes`` the result's, ``transfers`` the
     device-to-host copies it took), and returns the rows as host arrays.
 
-    A row comes back as a 1-D array of its own, the rows side by side
-    (`_side_by_side`), never the 2-D result as one: on the chip a
+    A row comes back as a 1-D array of its own (``np.asarray``), the rows
+    side by side (`_side_by_side`: the last few on this thread, the others
+    on the kept workers), never the 2-D result as one: on the chip a
     ``uint8[R, width]`` lies in tiles of four rows, so with one row the
     transfer moves four, and either way one transfer fills one fresh
     allocation on one thread, at the price of pages never touched —
@@ -848,7 +939,9 @@ def _copy_back(op: str, out_dev) -> list:
     inside the pipeline: PERF.md §6 PR 40, four chips)."""
     rows = out_dev.shape[0] if _await(out_dev) else 0  # to transfer
     with trace.stage_span(f"{op}.d2h", bytes=out_dev.nbytes, transfers=rows):
-        out = (_side_by_side([out_dev[j] for j in range(rows)])
+        out = (_side_by_side(_copy_back_workers, np.asarray,
+                             [out_dev[j] for j in range(rows)],
+                             _COPY_BACK_TRANSFERS)
                if rows else list(out_dev))
     trace.add_stage_bytes(out_dev.nbytes)
     return out
@@ -983,7 +1076,8 @@ def _rebuild_pipelined(codec, ins, outs, rows, shard_size, chunk) -> None:
     ``rows`` its matrix over them, ``outs`` the files it rebuilds, by id.
 
     Row ``r`` of a chunk's ``(len(ins), padded)`` buffer is read straight
-    from the ``r``-th file of the read set. The buffer is not carried past
+    from the ``r``-th file of the read set, the rows side by side
+    (`_read_side_by_side`, a row a job). The buffer is not carried past
     the device, so the fetch leg gives it back to the pool once the
     chunk's RESULT is ready and copied back, never at ``device_put``
     (and not before the copy back: a buffer given earlier is a third chunk
@@ -999,12 +1093,12 @@ def _rebuild_pipelined(codec, ins, outs, rows, shard_size, chunk) -> None:
         if buf is None:
             return width, None
         buf[:, width:] = 0  # the alignment tail: zeros encode to zeros
-        for row, fh in enumerate(ins):
-            if row in held:
-                _pread_into(fh.fileno(), pos, [buf[row, :width]])
-                trace.add_stage_bytes(width)
-            else:
+        for row in range(n_read):
+            if row not in held:
                 buf[row, :width] = 0  # a hole: not read, and not left stale
+        _read_side_by_side([(ins[row].fileno(), pos, [buf[row, :width]])
+                            for row in held])
+        trace.add_stage_bytes(width * len(held))
         return width, buf
 
     def produce():

@@ -264,11 +264,14 @@ class StageTable:
     (files it fsync'd) and ``slow_fsyncs`` (those that took
     ``storage.commit.SLOW_FSYNC_S`` or more). A copy back of a chunk's
     result (``ec.seal.d2h``, ``ec.rebuild.d2h``) sums ``transfers`` (the
-    device-to-host copies it took: 0 where the result was on the host)."""
+    device-to-host copies it took: 0 where the result was on the host). A
+    chunk's read (``ec.seal.read``, ``ec.rebuild.read``) sums ``reads`` (the
+    ``preadv`` jobs it took: a rebuild's held rows, the pieces of a seal's
+    runs)."""
 
     SUMMED_TAGS = (
         "bytes", "failed", "slept_s", "ok", "ok_s", "absent", "width",
-        "spares", "local", "fsyncs", "slow_fsyncs", "transfers",
+        "spares", "local", "fsyncs", "slow_fsyncs", "transfers", "reads",
     )
 
     def __init__(self):

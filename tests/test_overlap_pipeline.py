@@ -234,6 +234,66 @@ def test_a_rows_transfer_that_raises_fails_the_seal_and_nothing_is_left(
     assert len(kept) == encoder._POOL_BUFFERS
 
 
+def test_a_read_that_raises_on_a_worker_fails_the_rebuild_and_nothing_is_left(
+        tmp_path, kept, monkeypatch):
+    """One row's read of one chunk fails on a kept worker, beside the
+    reader thread's own: the rebuild raises that error, every thread of the
+    pipeline has ended, no read is left in flight into a buffer — both are
+    back with the process — and the next rebuild is whole."""
+    import threading
+
+    import pytest as _pytest
+
+    from seaweedfs_tpu.ec import encoder
+    from seaweedfs_tpu.ec.codec import NumpyCodec
+    from seaweedfs_tpu.ec.constants import shard_ext
+
+    monkeypatch.setattr(encoder, "_LEAST_READ", 512)
+    base = str(tmp_path / "1")
+    rng = np.random.default_rng(6)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.integers(0, 256, 300_000, dtype=np.uint8).tobytes())
+    codec = NumpyCodec()
+    sums = encoder.write_ec_files(
+        base, codec, large_block_size=8192, small_block_size=1024)
+    with open(base + shard_ext(4), "rb") as f:
+        lost = f.read()
+    os.remove(base + shard_ext(4))
+
+    real, in_flight, state = encoder._pread_into, [0], {"armed": True}
+    lock = threading.Lock()
+
+    def pread(fd, offset, views):
+        with lock:
+            in_flight[0] += 1
+        try:
+            time.sleep(0.002)  # long enough for the workers to take a share
+            on_worker = threading.current_thread().name.startswith("ec-read")
+            if state["armed"] and on_worker and offset >= 8192:
+                raise OSError("a row of the third chunk cannot be read")
+            real(fd, offset, views)
+        finally:
+            with lock:
+                in_flight[0] -= 1
+
+    monkeypatch.setattr(encoder, "_pread_into", pread)
+    threads = set(threading.enumerate())
+    with _pytest.raises(OSError, match="third chunk cannot be read"):
+        encoder.rebuild_ec_files(base, codec, chunk_bytes=4096)
+    assert in_flight[0] == 0
+    # the kept workers of the reads and of the copy back are the process's
+    left = [t.name for t in set(threading.enumerate()) - threads
+            if not t.name.startswith(("ec-read", "ec-copy-back"))]
+    assert left == []
+    assert len(kept) == encoder._POOL_BUFFERS
+    state["armed"] = False
+    os.remove(base + shard_ext(4))  # what the failed rebuild left of it
+    assert encoder.rebuild_ec_files(base, codec, chunk_bytes=4096) == [4]
+    with open(base + shard_ext(4), "rb") as f:
+        assert f.read() == lost
+    assert len(sums) == 14 and len(kept) == encoder._POOL_BUFFERS
+
+
 def test_depth_chunk_splits_small_volumes():
     """A 128 MB volume under a 32 MB budget previously collapsed to one
     work item — nothing to overlap (r4 efficiency pinned at ~0.65). The
