@@ -578,7 +578,10 @@ class JaxCodec(Codec):
                 padded = align * -(-width // align)
                 piece = np.pad(piece, ((0, 0), (0, padded - width)))
             # one synchronous round trip: stage, launch, copy back
-            with trace.stage_span("ec.codec.launch", bytes=piece.nbytes):
+            with trace.stage_span(
+                "ec.codec.launch", bytes=piece.nbytes,
+                geometry=str(self.geometry),
+            ):
                 res = np.asarray(
                     self.matmul_device(matrix, self.device_put(piece))
                 )

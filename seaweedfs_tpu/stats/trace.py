@@ -267,7 +267,11 @@ class StageTable:
     device-to-host copies it took: 0 where the result was on the host). A
     chunk's read (``ec.seal.read``, ``ec.rebuild.read``) sums ``reads`` (the
     ``preadv`` jobs it took: a rebuild's held rows, the pieces of a seal's
-    runs)."""
+    runs). A span that says which code it worked at (a ``geometry`` tag:
+    ``ec.recover``, ``ec.recover.plan``, ``ec.codec.launch``) is summed
+    twice: into its name's row, and into ``<name>@<geometry>``
+    (``ec.recover@10+4``), so the rows of a name's geometries add up to the
+    name's own."""
 
     SUMMED_TAGS = (
         "bytes", "failed", "slept_s", "ok", "ok_s", "absent", "width",
@@ -279,15 +283,21 @@ class StageTable:
         self._rows: dict[str, dict] = {}
 
     def add(self, span: Span) -> None:
+        geometry = span.tags.get("geometry")
         with self._lock:
-            row = self._rows.get(span.name)
-            if row is None:
-                row = self._rows[span.name] = {"n": 0, "busy_s": 0.0}
-            row["n"] += 1
-            row["busy_s"] += span.duration
-            for key in self.SUMMED_TAGS:
-                if key in span.tags:
-                    row[key] = row.get(key, 0) + span.tags[key]
+            self._sum(span.name, span)
+            if geometry:
+                self._sum(f"{span.name}@{geometry}", span)
+
+    def _sum(self, name: str, span: Span) -> None:
+        row = self._rows.get(name)
+        if row is None:
+            row = self._rows[name] = {"n": 0, "busy_s": 0.0}
+        row["n"] += 1
+        row["busy_s"] += span.duration
+        for key in self.SUMMED_TAGS:
+            if key in span.tags:
+                row[key] = row.get(key, 0) + span.tags[key]
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -417,7 +427,12 @@ class _StageScope(_SpanScope):
         if self.span is not None:
             annotation = _trace_annotation()
             if annotation is not None:
-                self._annotated = annotation(self.span.name)
+                # the code a recovery decodes at goes into the export too
+                geometry = self.span.tags.get("geometry")
+                self._annotated = (
+                    annotation(self.span.name, geometry=geometry)
+                    if geometry else annotation(self.span.name)
+                )
                 self._annotated.__enter__()
         return super().__enter__()
 

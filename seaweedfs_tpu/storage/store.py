@@ -711,12 +711,13 @@ class Store:
         from ..ec.codec import Undecodable
 
         codec = self.ec_codec.at(*ev.geometry)
+        geometry = str(ev.geometry)  # the code this recovery decodes at
         me = f"{self.ip}:{self.port}"
         # quiet, as the decode below: a slow recovery is named by the leaf
         # stage that was slow (an ask, the local reads, the launch)
         with trace.stage_span(
             "ec.recover", quiet=True, missing=missing_shard, size=size,
-            bytes=size,
+            bytes=size, geometry=geometry,
         ):
             shards: list[Optional[np.ndarray]] = [None] * ev.total_shards
             unreachable = {missing_shard}
@@ -866,7 +867,9 @@ class Store:
             have = [s for s, range_ in enumerate(shards) if range_ is not None]
             # one record a recovery: the shards its decode reads, and
             # whether the missing shard's own local group sufficed
-            with trace.stage_span("ec.recover.plan", width=0, local=0) as span:
+            with trace.stage_span(
+                "ec.recover.plan", width=0, local=0, geometry=geometry
+            ) as span:
                 plan = codec.plan((missing_shard,), have)
                 if span is not None:
                     span.tags.update(
