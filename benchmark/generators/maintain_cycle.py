@@ -1,19 +1,33 @@
 """Traffic kind ``maintain-cycle``: whole cycles of seal + rebuild on one
-sealed-size volume, for as long as the window lasts.
+sealed-size volume, on a schedule: the mix's ``cycles`` a window, one every
+``seconds / cycles`` (16 and 3.19 s at ``run_seconds`` 51).
 
 One cycle: ``ec.encode`` keeping the plain volume (timed) -> the mix's lost
 shards deleted -> ``ec.rebuild`` (timed) -> rebuilt shards hashed -> all
 shards, ``.ecx`` and ``.vif`` dropped -> ``os.sync()``, so that one cycle's
-write-back does not land in the next cycle's clock. A cycle that has begun
-when the window runs out is finished and counted. The run's rates are taken
-over ALL of the window's seals and ALL of its rebuilds: bytes sealed over the
-seconds spent sealing, bytes rebuilt over the seconds spent rebuilding, so a
-stall in any operation shows. Beside them, on every run's ``[readings]`` line
+write-back does not land in the next cycle's clock. Cycle ``i`` begins at
+``i * seconds / cycles`` from the window's start: the generator sleeps until
+it is due, outside both clocks (``paused_s``), so a window makes the same
+number of seals and rebuilds, and writes the same bytes to the machine's
+disk, however fast the program is. A cycle that ends after the next was due
+starts the next at once and is counted (``client.late_cycles``); the window
+ends after ``cycles`` cycles or with its seconds, whichever is first, and a
+cycle that has begun when the seconds run out is finished and counted: a
+stall long enough costs the window its last cycle or cycles. A rehearsal
+(tiny window, CPU) has every cycle late and so runs its cycles back to back
+until the seconds are over, as every window did until PR 47. The run's rates
+are taken over ALL of the window's seals and ALL of its rebuilds: bytes sealed
+over the seconds spent sealing, bytes rebuilt over the seconds spent
+rebuilding, so a stall in any operation shows. ``seal_rate`` is an end-to-end
+metric in the cells ``BENCHMARK.json`` lists under it; in the others the same
+number is the per-layer ``client.seal_rate`` (PERF.md section 2), and it is
+on every run's ``[readings]`` line and in its ``readings`` under that name.
+Beside them, on every run's ``[readings]`` line
 and as per-layer metrics, stand the medians of the per-operation readings
 (``client.seal_rate_p50``, ``client.rebuild_rate_p50``), the operations that
 took more than twice the median of their kind (``client.stalled_ops``: what
 tells a stalled run from a slow one) and the share of the window outside both
-clocks (``client.untimed_share``).
+clocks and outside the pause (``client.untimed_share``).
 """
 
 from __future__ import annotations
@@ -95,6 +109,36 @@ def beside(dat_bytes: int, seal_s: list[float], rebuild_s: list[float]) -> dict:
     }
 
 
+def due_s(i: int, seconds: float, cycles: int) -> float:
+    """When cycle ``i`` of a scheduled window is due, from its start."""
+    return i * seconds / cycles
+
+
+def window(seconds: float, cycles: int, one_cycle,
+           clock=time.monotonic, sleep=time.sleep) -> dict:
+    """One window's cycles at their pace. ``one_cycle(i)`` makes cycle ``i``
+    and says whether the window goes on. Cycle ``i`` waits until
+    ``due_s(i, seconds, cycles)`` from the window's start (the seconds slept:
+    ``paused_s``); one whose turn comes after it was due begins at once and
+    is counted (``late_cycles``), and the window makes at most ``cycles``.
+    No cycle begins once the seconds are over, and one that has begun is
+    finished."""
+    paused_s, late, begun, goes_on = 0.0, 0, 0, True
+    t0 = clock()
+    while goes_on and clock() - t0 < seconds and begun < cycles:
+        wait = due_s(begun, seconds, cycles) - (clock() - t0)
+        if wait > 0:
+            t = clock()
+            sleep(wait)
+            paused_s += clock() - t
+        elif begun:  # the first is due as the window opens
+            late += 1
+        goes_on = one_cycle(begun)
+        begun += 1
+    return {"window_s": clock() - t0, "paused_s": paused_s,
+            "late_cycles": late, "begun": begun}
+
+
 def run_cell(run: Run) -> dict:
     mix = run.mix
     lost = list(mix["lost_shards"])
@@ -113,18 +157,18 @@ def run_cell(run: Run) -> dict:
     log: list[dict] = []
     failed = 0
     traced = False
-    t_end = time.monotonic() + run.args.seconds
-    window_t0 = time.monotonic()
-    while time.monotonic() < t_end:
+
+    def one_cycle(_i: int) -> bool:
+        nonlocal failed, traced
         tracing = run.trace and not traced
-        if tracing:
+        if tracing:  # after the pause: the traced window is the cycle alone
             d.profiler("start")
         try:
             cycle(run, lost, log)
         except Exception as e:  # counted, reported, and the run is not correct
             say(f"[cycle {len(log)}] FAILED: {e!r}\n{d.log_tail(12)}")
             failed += 1
-            break
+            return False
         finally:
             if tracing:
                 d.profiler("stop")
@@ -132,7 +176,11 @@ def run_cell(run: Run) -> dict:
         r = log[-1]
         say(f"[cycle {len(log)}] seal {r['seal_s']:.4f} s, "
             f"rebuild {r['rebuild_s']:.4f} s")
-    window_s = time.monotonic() - window_t0
+        return True
+
+    paced = window(run.args.seconds, mix["cycles"], one_cycle)
+    window_s, paused_s = paced["window_s"], paced["paused_s"]
+    late_cycles = paced["late_cycles"]
     after = d.codec()
     run.stop_daemon()
 
@@ -156,14 +204,16 @@ def run_cell(run: Run) -> dict:
     seal_s = [r["seal_s"] for r in done]
     rebuild_s = [r["rebuild_s"] for r in done]
     end_to_end = rates(run.dat_bytes, seal_s, rebuild_s)
-    summary = beside(run.dat_bytes, seal_s, rebuild_s)
+    summary = {"client.seal_rate": end_to_end["seal_rate"],
+               **beside(run.dat_bytes, seal_s, rebuild_s),
+               "client.late_cycles": late_cycles, "paused_s": paused_s}
     if done and not run.rehearsal:  # a rehearsal prints no rate
         say(f"[readings] seal_rate {end_to_end['seal_rate']:.2f} MB/s over "
             f"{len(done)} seals in {sum(seal_s):.3f} s; rebuild_rate "
             f"{end_to_end['rebuild_rate']:.2f} MB/s over {len(done)} rebuilds "
             f"in {sum(rebuild_s):.3f} s; " + "; ".join(
                 f"{name} {value:.6g}" for name, value in summary.items()
-            ) + f"; window {window_s:.3f} s")
+            ) + f"; window {window_s:.3f} s")  # the pause is inside it
     return {
         "attempted": 2 * len(done) + failed,
         "failed": failed,
@@ -173,10 +223,12 @@ def run_cell(run: Run) -> dict:
         "summary": {} if run.rehearsal else summary,
         "counts": {"seals": len(done), "rebuilds": len(done)},
         "readings": {"seal_s": seal_s, "rebuild_s": rebuild_s,
-                     "dat_bytes": run.dat_bytes},
+                     "dat_bytes": run.dat_bytes, "paused_s": paused_s,
+                     "late_cycles": late_cycles},
         "status": {"before": before, "after": after},
         "client": {"dat_bytes": run.dat_bytes, "seals": len(done),
                    "rebuilds": len(done), "traced_cycles": int(traced),
                    "seal_s": seal_s, "rebuild_s": rebuild_s,
-                   "window_s": window_s},
+                   "window_s": window_s, "paused_s": paused_s,
+                   "late_cycles": late_cycles},
     }

@@ -4,7 +4,7 @@ seals and so carries every stall. The two apart say a stall was there, and
 ``client.stalled_ops`` counts them."""
 LAYER = "client"
 UNIT = "MB/s"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "host_clock"
 
 

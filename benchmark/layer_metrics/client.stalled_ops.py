@@ -5,7 +5,7 @@ one stalled seal of 4.3 s among sixteen of 1.6 s takes 9% off it; this count
 beside it says that the loss was one operation's and not every one's."""
 LAYER = "client"
 UNIT = "count"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "host_clock"
 
 

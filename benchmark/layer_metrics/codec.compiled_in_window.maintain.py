@@ -2,7 +2,7 @@
 warm window makes none, whether the compiler or the cache would answer."""
 LAYER = "codec"
 UNIT = "count"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_counter"
 
 

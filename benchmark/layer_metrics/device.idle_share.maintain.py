@@ -1,7 +1,7 @@
 """Share of the traced slice in which no operation ran on the device."""
 LAYER = "device"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "device_trace"
 
 

@@ -1,7 +1,7 @@
 """Peak bytes in use on the fullest device (``/status``)."""
 LAYER = "device"
 UNIT = "MiB"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_counter"
 
 

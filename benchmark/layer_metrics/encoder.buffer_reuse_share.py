@@ -4,7 +4,7 @@ allocated one (``ec.<op>.buf.new``): whether the pool engages. Nothing to
 read from a program whose reader takes no buffers from a pool."""
 LAYER = "encoder pipeline"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 OPS = ("ec.seal", "ec.rebuild")

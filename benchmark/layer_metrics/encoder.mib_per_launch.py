@@ -1,7 +1,7 @@
 """``.dat`` MiB a seal's encoder pipeline moves per device launch."""
 LAYER = "encoder pipeline"
 UNIT = "MiB"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 OUTER = "write_ec_files"
 

@@ -4,7 +4,7 @@ a buffer or a queue slot outside it): what the reader gets from the file
 system, to hold beside the file-read floor of the host."""
 LAYER = "encoder pipeline"
 UNIT = "GB/s"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -4,7 +4,7 @@ without the verb around it. What a reader's, a link's or a writer's gain is
 judged on where the machine's fsyncs swing ``seal_rate``."""
 LAYER = "encoder pipeline"
 UNIT = "MB/s"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

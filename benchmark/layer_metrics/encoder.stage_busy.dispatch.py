@@ -4,7 +4,7 @@ pad, ``device_put`` call and kernel launch). The legs overlap, so the shares
 do not sum to 100; the highest is the leg that bounds a seal."""
 LAYER = "encoder pipeline"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -1,7 +1,7 @@
 """Input bytes through the kernel over its device time, per chip."""
 LAYER = "kernel"
 UNIT = "GB/s"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "device_trace"
 
 

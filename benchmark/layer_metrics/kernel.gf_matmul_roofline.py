@@ -4,7 +4,7 @@ shapes, ``kernel_model.py``) over the kernel's device time. The bound that
 binds is printed on an earlier line."""
 LAYER = "kernel"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "device_trace"
 
 

@@ -2,7 +2,7 @@
 result was ready (``ec.seal.d2h``)."""
 LAYER = "host-device link"
 UNIT = "GB/s"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -3,7 +3,7 @@ its staged input was ready (``ec.seal.h2d``, timed by a watch thread of its
 own: the link's host-to-device rate as a seal drives it)."""
 LAYER = "host-device link"
 UNIT = "GB/s"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

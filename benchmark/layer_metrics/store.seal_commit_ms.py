@@ -4,7 +4,7 @@ machine in this run, in time and not as a share of a seal that itself got
 faster or slower."""
 LAYER = "store / commit"
 UNIT = "ms"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

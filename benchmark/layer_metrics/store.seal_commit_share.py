@@ -3,7 +3,7 @@
 itself, which no change may take out."""
 LAYER = "store / commit"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -7,7 +7,7 @@ it costs in cores. The serial tail is ``store.seal_tail_share``. (Before
 PR 27: the share of a seal spent reading the staged shards back to hash them.)"""
 LAYER = "store / commit"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -4,7 +4,7 @@ of ``ec.seal.commit``): a stalled disk under a seal, which the mean of
 window, not a ratio; nothing from a program whose commit counts no fsyncs."""
 LAYER = "store / commit"
 UNIT = "count"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -5,7 +5,7 @@ they are written, inside the pipeline. Before, it held the read back and
 SHA-256 of the 14 shards too."""
 LAYER = "store / commit"
 UNIT = "%"
-MOVES = "seal_rate"
+MOVES = "rebuild_rate"  # the rate every maintain cell reports (PERF.md section 2)
 SOURCE = "program_span"
 
 

@@ -182,11 +182,14 @@ def test_the_cell_and_the_configuration_come_after_those_that_were_there():
     assert lrc["reduced"] == ["volume.dat_target_bytes", "servers"]
     # the configuration's first cell; a later one comes after it
     assert [w["name"] for w in b["workloads"] if w["config"] == "lrc1222"][0] == CELL
-    for m in b["end_to_end"]:
-        if m["name"] in ("seal_rate", "rebuild_rate"):
-            assert m["workloads"][:5] == [
-                "warm1.maintain", "mesh4.maintain", "geom124.maintain",
-                "warm1.maintain-1lost", CELL]
+    listed = {m["name"]: m.get("workloads") for m in b["end_to_end"]}
+    assert listed["rebuild_rate"][:5] == [
+        "warm1.maintain", "mesh4.maintain", "geom124.maintain",
+        "warm1.maintain-1lost", CELL]
+    # seal_rate where the cell's sets held it, else the same rate per layer
+    # (PR 47; PERF.md section 2)
+    (beside,) = [m for m in b["per_layer"] if m["name"] == "client.seal_rate"]
+    assert (CELL in listed["seal_rate"]) != (CELL in beside["workloads"])
 
 
 def test_lrc1222_is_geom124_but_for_the_code():
